@@ -14,6 +14,7 @@ import os
 import threading
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 _ENV_PREFIX = "DL4J_TPU_"
@@ -27,11 +28,7 @@ _ENV_PREFIX = "DL4J_TPU_"
 # partitionable implementation computes each element as a pure function
 # of (key, index), so every layout draws identical bits.  Set once,
 # process-wide, before any program traces.
-try:
-    import jax as _jax
-    _jax.config.update("jax_threefry_partitionable", True)
-except Exception:          # very old jax without the flag
-    pass
+jax.config.update("jax_threefry_partitionable", True)
 
 
 @dataclasses.dataclass
@@ -52,7 +49,7 @@ class DTypePolicy:
         """Mixed-precision speed policy: f32 params, bf16 MXU compute AND
         bf16 layer outputs.  Keeping activations bf16 end-to-end halves
         HBM traffic — ResNet-50 training on v5e is HBM-bound, and an f32
-        output dtype was measured to cost ~35% throughput (bench/PROFILE.md).
+        output dtype was measured to cost ~35% throughput (before PR 1).
         Loss/score math stays f32 (OutputLayer casts before the loss)."""
         return cls(param_dtype=jnp.float32, compute_dtype=jnp.bfloat16, output_dtype=jnp.bfloat16)
 
@@ -87,8 +84,6 @@ class Config:
       (``DL4J_TPU_FUSED_CONV=0`` reverts to the unfused per-layer
       graph); an explicit ``fused=`` argument to a zoo factory always
       wins.
-    - ``compile_cache_dir``: when set, enables jax's persistent
-      compilation cache there (XLA programs survive process restarts).
     - ``artifact_store``: honor the compiled-artifact store
       (``train.artifact_store``): warm-load serialized executables from
       checkpoint zips at deploy/resume/respawn time and dispatch
@@ -114,8 +109,9 @@ class Config:
       The step path itself only pays dict lookups, but the analysis is
       an AOT *duplicate* of the program's XLA compile, run once per
       program on a background worker (host CPU seconds-to-minutes for
-      big models; a persistent-cache hit when ``compile_cache_dir`` is
-      set).  On by default; ``DL4J_TPU_COSTMODEL=0`` disables.
+      big models; a persistent-cache hit once the entry point has
+      called ``place_compile_cache``).  On by default;
+      ``DL4J_TPU_COSTMODEL=0`` disables.
     """
 
     debug: bool = False
@@ -128,7 +124,6 @@ class Config:
     device_feed: bool = True
     shape_bucketing: bool = True
     fused_conv: bool = True
-    compile_cache_dir: str = ""
     artifact_store: bool = True
     artifact_bake: bool = False
     profiling: bool = False
@@ -185,8 +180,6 @@ ENV_KNOBS: dict[str, str] = {
                                 "batches to static bucket shapes",
     "DL4J_TPU_FUSED_CONV": "config.fused_conv: Pallas fused conv+BN "
                            "bottleneck lowering",
-    "DL4J_TPU_COMPILE_CACHE_DIR": "config.compile_cache_dir: persistent "
-                                  "XLA compilation cache location",
     "DL4J_TPU_ARTIFACT_STORE": "config.artifact_store: warm compiled "
                                "programs from checkpoint zips",
     "DL4J_TPU_ARTIFACT_BAKE": "config.artifact_bake: background "
@@ -219,26 +212,33 @@ ENV_KNOBS: dict[str, str] = {
 _lock = threading.Lock()
 _config: Config | None = None
 _policy = DTypePolicy()
-_compile_cache_applied: str | None = None
 
 
-def _apply_compile_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at ``path`` (idempotent;
-    XLA executables then survive process restarts — a pod-scale re-fit
-    skips straight to execution).  An empty path reverts a previously
-    applied dir (back to the in-memory-only cache).  Failures are
-    non-fatal: an old jax without the flag just keeps the in-memory
-    cache."""
-    global _compile_cache_applied
-    target = path or None
-    if target == _compile_cache_applied:
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", target)
-        _compile_cache_applied = target
-    except Exception:
-        pass
+def place_compile_cache() -> str:
+    """Give jax's persistent compilation cache a directory and return
+    it.  Entry points (``chip_smoke.py``, ``bench.py``, ``bench/*.py``)
+    call this before their first compile; nothing calls it at import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, whoever runs the program
+    has placed the cache and jax reads the variable itself: nothing is
+    set here.  Otherwise the cache lives at ``<checkout>/.jax_cache`` —
+    the directory is part of every entry's key, so it is a fixed path
+    and never a temporary one."""
+    # A Mosaic kernel rides inside its custom call as bytecode WITH its
+    # MLIR locations, and jax's cache key strips debug info only from the
+    # module around it.  With full tracebacks in those locations the key
+    # of every program that holds a Pallas kernel changes with the Python
+    # call stack that first traced it — the train step of ``net.fit``
+    # never hit the entry ``fit_batch`` wrote (seen on the chip, PR 22).
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def get_config() -> Config:
@@ -246,7 +246,6 @@ def get_config() -> Config:
     with _lock:
         if _config is None:
             _config = Config.from_env()
-            _apply_compile_cache(_config.compile_cache_dir)
         return _config
 
 
@@ -256,8 +255,6 @@ def set_config(**kwargs: Any) -> Config:
         if not hasattr(cfg, k):
             raise AttributeError(f"unknown config key: {k}")
         setattr(cfg, k, v)
-    if "compile_cache_dir" in kwargs:
-        _apply_compile_cache(cfg.compile_cache_dir)
     return cfg
 
 

@@ -45,8 +45,8 @@ class BertConfig:
     initializer_range: float = 0.02
     # long-sequence path: Pallas flash kernel (fwd + bwd) instead of the
     # materialized [T,T] einsum chain.  None = auto (the promoted
-    # default): flash at seq >= 1024 — the measured crossover on v5e
-    # (1.29x at seq 4096, bench/PROFILE.md); explicit False always wins
+    # default): flash at seq >= 1024 — the crossover measured on a v5e
+    # before PR 1 (1.29x at seq 4096); explicit False always wins
     use_flash: Optional[bool] = None
     flash_block: int = 0      # 0 = tuned default (1024×1024 blocks)
     # MLM head scope: decode only `max_predictions` gathered positions

@@ -5,10 +5,10 @@ The bench rows used to carry hand-derived FLOP/byte constants (the
 truth.  This module pulls FLOPs and bytes-accessed from compiled XLA
 programs via ``jitted.lower(*abstract_args).compile().cost_analysis()``.
 That AOT compile is a REAL duplicate XLA compilation under the default
-config (set ``config.compile_cache_dir`` to make it a persistent-cache
-hit), so instrumented hot paths enqueue it on a background worker
-(:func:`schedule_analysis`) — the step/dispatch path itself only ever
-pays dict lookups and gauge sets.  The facts become the roofline
+config (a persistent-cache hit once the entry point has called
+``config.place_compile_cache``), so instrumented hot paths enqueue it
+on a background worker (:func:`schedule_analysis`) — the step/dispatch
+path itself only ever pays dict lookups and gauge sets.  The facts become the roofline
 quantities ("Tensor Processing Primitives", PAPERS.md):
 
 - **arithmetic intensity** — FLOPs per byte of memory traffic,
@@ -55,13 +55,12 @@ _PEAK_TABLE = (
     ("v5 lite", 197e12, 819e9),          # v5e: device_kind "TPU v5 lite"
     ("v5e", 197e12, 819e9),
     ("v5p", 459e12, 2765e9),
-    ("v5", 197e12, 819e9),
+    ("v5", 459e12, 2765e9),              # v5p: device_kind "TPU v5"
     ("v6", 918e12, 1640e9),
     ("v4", 275e12, 1228e9),
     ("v3", 123e12, 900e9),
     ("v2", 45e12, 700e9),
 )
-_DEFAULT_TPU = (197e12, 819e9)           # unknown TPU: assume v5e-class
 _CPU_FALLBACK = (0.5e12, 50e9)           # synthetic; marked estimated
 
 
@@ -83,25 +82,22 @@ class BackendPeaks:
 def backend_peaks(device=None) -> BackendPeaks:
     """Peak table entry for ``device`` (default: local device 0), with
     ``DL4J_TPU_PEAK_TFLOPS`` / ``DL4J_TPU_PEAK_HBM_GBPS`` env overrides
-    (set them when the silicon's measured ceiling differs from nominal —
-    see bench/PROFILE.md "measured matmul ceiling")."""
-    platform, kind = "cpu", "cpu"
-    try:
-        import jax
-        dev = device if device is not None else jax.local_devices()[0]
-        platform = getattr(dev, "platform", "cpu") or "cpu"
-        kind = (getattr(dev, "device_kind", "") or platform).lower()
-    except Exception:
-        pass
-    if platform == "cpu":
+    (set them when the silicon's measured ceiling differs from nominal).
+    An accelerator the table does not know raises unless both overrides
+    name its peaks: a roofline against another chip's peaks is a wrong
+    number, not an estimate."""
+    import jax
+    dev = device if device is not None else jax.local_devices()[0]
+    platform = dev.platform
+    kind = (dev.device_kind or platform).lower()
+    flops = bw = None
+    estimated = platform == "cpu"
+    if estimated:
         flops, bw = _CPU_FALLBACK
-        estimated = True
     else:
-        flops, bw = _DEFAULT_TPU
-        estimated = True
         for marker, f, b in _PEAK_TABLE:
             if marker in kind:
-                flops, bw, estimated = f, b, False
+                flops, bw = f, b
                 break
     # `estimated` clears only when BOTH axes are real (table hit or
     # override) — one override must not launder the other, still-
@@ -128,6 +124,12 @@ def backend_peaks(device=None) -> BackendPeaks:
         flops, flops_est = env_f * 1e12, False
     if env_b is not None:
         bw, bw_est = env_b * 1e9, False
+    if flops is None or bw is None:
+        raise ValueError(
+            f"no peak FLOP/s and HBM bandwidth known for device kind "
+            f"{kind!r} on platform {platform!r}: add it to "
+            f"obs.costmodel._PEAK_TABLE, or set DL4J_TPU_PEAK_TFLOPS "
+            f"and DL4J_TPU_PEAK_HBM_GBPS")
     estimated = flops_est or bw_est
     reg = get_registry()
     reg.gauge("tpudl_perf_peak_flops").set(flops)
@@ -308,9 +310,10 @@ def analyze_jitted(fn: Any, abstract_args: Any, kind: Optional[str] = None,
     """Pull cost_analysis from the compiled program behind ``fn`` for
     the given abstract call signature.  ``fn.lower().compile()`` is a
     REAL second XLA compilation under the default config (the AOT path
-    has no in-memory executable cache) — set ``config.compile_cache_dir``
-    to make it a persistent-cache hit, or use :func:`schedule_analysis`
-    to keep the cost off the step/dispatch path entirely.  Never raises
+    has no in-memory executable cache) — a persistent-cache hit once the
+    entry point has called ``config.place_compile_cache``; use
+    :func:`schedule_analysis` to keep the cost off the step/dispatch
+    path entirely.  Never raises
     — telemetry must not break a training step."""
     if fn is None or not enabled():
         return None

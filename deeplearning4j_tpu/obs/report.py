@@ -3,11 +3,10 @@
 Renders everything the verdict layer knows as a single page: SLO
 status with budget remaining (from a live :class:`SLOMonitor` in
 library use, or the published ``tpudl_slo_*`` series when reading a
-registry), the bench trajectory with per-round deltas and the
-staleness verdict from :mod:`deeplearning4j_tpu.obs.trend`, ROADMAP
-target tracking, open health anomalies, and the honesty counters
-(artifact rejects, recompiles, rollbacks) — as markdown for humans
-(default) and JSON for machines (``--json``).
+registry), open health anomalies, and the honesty counters (artifact
+rejects, recompiles, rollbacks) — as markdown for humans (default) and
+JSON for machines (``--json``).  The perf trajectory is the driver's
+``PERF_LEDGER.jsonl``, not a page of this report.
 """
 
 from __future__ import annotations
@@ -17,7 +16,6 @@ import json
 import sys
 from typing import Optional
 
-from . import trend
 from .registry import (MetricsRegistry, get_registry,
                        install_standard_metrics)
 
@@ -80,29 +78,11 @@ def _health_section(registry: Optional[MetricsRegistry] = None) -> dict:
     return {"anomalies_by_kind": by_kind, "counters": counters}
 
 
-def _deltas(records: list[dict]) -> dict[str, list]:
-    """metric → [(round, value, delta_vs_previous_real)] over the real
-    bench trajectory — the table's raw material."""
-    series: dict[str, list] = {}
-    for rec in records:
-        if rec["kind"] != "bench" or rec["status"] != "real":
-            continue
-        for name, value in rec["metrics"].items():
-            prev = series.get(name, [])
-            delta = value - prev[-1][1] if prev else None
-            series.setdefault(name, []).append(
-                (rec["round"], value, delta))
-    return series
-
-
-def build_report(records_dir: Optional[str] = None, monitor=None,
+def build_report(monitor=None,
                  registry: Optional[MetricsRegistry] = None) -> dict:
     """The whole machine-readable report; every renderer reads this."""
-    trajectory = trend.summarize(records_dir)
     return {
         "slos": _slo_section(monitor, registry),
-        "trajectory": trajectory,
-        "trajectory_deltas": _deltas(trajectory["records"]),
         "health": _health_section(registry),
     }
 
@@ -126,38 +106,6 @@ def render_markdown(report: dict) -> str:
                    "SLOMonitor, or read a serving process's registry)")
     out.append("")
 
-    traj = report["trajectory"]
-    out.append("## Perf trajectory")
-    out.append("| record | status | note |")
-    out.append("|---|---|---|")
-    for rec in traj["records"]:
-        out.append(f"| {rec['record']} | {rec['status']} "
-                   f"| {rec['reason'] or '—'} |")
-    out.append("")
-    out.append(f"**Staleness:** {traj['staleness']['message']}")
-    out.append("")
-    if report["trajectory_deltas"]:
-        out.append("| metric | latest (round) | delta vs prior real |")
-        out.append("|---|---|---|")
-        for name, rows in sorted(report["trajectory_deltas"].items()):
-            rnd, value, delta = rows[-1]
-            out.append(
-                f"| {name} | {value:g} (r{rnd:02d}) "
-                f"| {f'{delta:+g}' if delta is not None else '—'} |")
-        out.append("")
-    for tgt in traj["roadmap_targets"]:
-        out.append(f"- ROADMAP target `{tgt['metric']} >= "
-                   f"{tgt['target']:g}`: **{tgt['status']}** "
-                   f"({tgt['note']})")
-    if traj["regressions"]:
-        out.append("")
-        out.append(f"**{len(traj['regressions'])} regression(s):**")
-        for r in traj["regressions"]:
-            out.append("- " + trend.Regression(**r).render())
-    else:
-        out.append("- regressions: none")
-    out.append("")
-
     health = report["health"]
     out.append("## Health & honesty counters")
     if health["anomalies_by_kind"]:
@@ -173,17 +121,15 @@ def render_markdown(report: dict) -> str:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m deeplearning4j_tpu.obs.report",
-        description="fleet health: SLO status, perf trajectory, "
-                    "health + honesty counters")
-    p.add_argument("--dir", default=None,
-                   help="bench records directory (default: repo root)")
+        description="fleet health: SLO status, health + honesty "
+                    "counters")
     p.add_argument("--json", action="store_true",
                    help="emit the machine-readable report")
     args = p.parse_args(argv)
     # a fresh CLI process has an empty registry: install the standard
     # family so the counter rows render (as zeros) instead of vanishing
     install_standard_metrics()
-    report = build_report(args.dir)
+    report = build_report()
     if args.json:
         print(json.dumps(report, indent=1, default=str))
     else:

@@ -17,12 +17,7 @@ consistent on a bare CPU box:
 5. **federation smoke** — a loopback ``RemoteStatsRouter`` →
    ``UIServer`` ingest round-trip: pushed step records appear in the
    ``/cluster.json`` summary and as ``worker``-labeled series on
-   ``/metrics`` (the tpudl_cluster_* families stay wired end-to-end);
-6. **trajectory gate** — ``obs.trend --check`` over the committed
-   ``BENCH_r*``/``MULTICHIP_r*`` records: a future record that
-   regresses the trailing window of real measurements fails the suite
-   with the exact metric and delta named (tunnel-down/skipped records
-   classify ``stale`` and never gate).
+   ``/metrics`` (the tpudl_cluster_* families stay wired end-to-end).
 
 This module also absorbs the deprecated ``obs.check`` entry point: the
 metric-name lint lives here as :func:`metric_lint` /
@@ -207,28 +202,6 @@ def check_federation_smoke(problems: list) -> None:
         server.stop()
 
 
-def check_trend_gate(problems: list) -> None:
-    """The perf-trajectory sentinel over the records committed at the
-    repo root: any regression of the newest real record against the
-    trailing-window baseline fails selfcheck with the metric named."""
-    from deeplearning4j_tpu.obs import trend
-    try:
-        summary = trend.summarize()
-    except Exception as e:
-        problems.append(f"trend gate: trajectory unreadable: {e!r}")
-        return
-    for r in summary["regressions"]:
-        problems.append("trend gate: "
-                        + trend.Regression(**r).render())
-    for row in summary["records"]:
-        # the gate never regresses on stale/failed rounds, but a record
-        # that fails to CLASSIFY at all means the writer and the
-        # sentinel disagree about the schema — surface it
-        if row["status"] not in ("real", "stale", "failed"):
-            problems.append(f"trend gate: {row['record']} has "
-                            f"unclassifiable status {row['status']!r}")
-
-
 def main(argv=None) -> int:
     problems: list[str] = []
     check_registry_lint(problems)
@@ -236,7 +209,6 @@ def main(argv=None) -> int:
     check_costmodel_smoke(problems)
     check_flight_recorder_smoke(problems)
     check_federation_smoke(problems)
-    check_trend_gate(problems)
     if problems:
         print(f"obs.selfcheck: {len(problems)} problem(s):")
         for p in problems:
@@ -247,8 +219,7 @@ def main(argv=None) -> int:
     print(f"obs.selfcheck OK: registry lint clean ({n} metrics), "
           f"metric-doc parity holds, cost_analysis smoke passed, "
           f"flight-recorder dump round-trips, router→UIServer "
-          f"federation round-trips on loopback, bench trajectory "
-          f"gate clean (no regressions vs the trailing window)")
+          f"federation round-trips on loopback")
     return 0
 
 

@@ -19,9 +19,10 @@ import jax.numpy as jnp
 NEG_INF = -1e9
 
 # the promoted default (ROADMAP item 1): sequences at/above this length
-# route through the Pallas flash kernel automatically — the measured
-# crossover on v5e is ~1k (1.29x over einsum at seq 4096,
-# bench/PROFILE.md), below it the einsum chain wins on launch overhead
+# route through the Pallas flash kernel automatically — the crossover
+# measured on a v5e before PR 1 was ~1k (1.29x over einsum at seq 4096;
+# not re-measured since, see PERF.md), below it the einsum chain wins on
+# launch overhead
 FLASH_AUTO_SEQ_LEN = 1024
 
 
@@ -71,9 +72,9 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if use_flash:
         from deeplearning4j_tpu.ops.pallas import flash_attention
         key_mask = mask if mask is not None else kv_mask
-        # flash_block=0: tuned defaults (1024×1024 — the round-4 measured
-        # optimum on v5e at both narrow and BERT-base widths; the round-3
-        # 512×1024 default was 1.35-1.5× slower, see bench/PROFILE.md)
+        # flash_block=0: tuned defaults (1024×1024 — the optimum measured
+        # on a v5e before PR 1 at both narrow and BERT-base widths; the
+        # earlier 512×1024 default was 1.35-1.5× slower)
         out = flash_attention(q, k, v, n_heads=n_heads, causal=causal,
                               key_mask=key_mask,
                               block_q=flash_block or 1024,

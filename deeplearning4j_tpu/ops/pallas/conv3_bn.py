@@ -1,6 +1,6 @@
-"""Fused 3×3-conv + BatchNorm Pallas kernel — the round-5 experiment
-PROFILE.md round 4 named as the last ResNet-50 HBM lever (~310 MB/image
-of BN normalize/stats traffic around the 3×3 bottleneck convs).
+"""Fused 3×3-conv + BatchNorm Pallas kernel — an experiment on the last
+ResNet-50 HBM lever (~310 MB/image of BN normalize/stats traffic around
+the 3×3 bottleneck convs).
 
 Forward: NHWC stride-1 SAME 3×3 conv expressed as 9 shifted
 [H·W, C] @ [C, Cout] MXU matmuls with the ENTIRE image plane resident
@@ -15,8 +15,8 @@ Backward: jax.vjp of the jnp reference (XLA conv) — the fusion claim
 under test is the FORWARD's elimination of the normalize + stats
 passes; the backward is shared between both paths being compared.
 
-Verdict (measured, see bench/PROFILE.md round 5): recorded there either
-way next to the 1×1 result.
+Verdict: measured slower than the XLA path before PR 1, on another chip
+and stack; not re-measured since (PERF.md).  No layer uses it.
 """
 
 from __future__ import annotations
@@ -274,10 +274,10 @@ def conv3x3_bn_act(x, w, a=None, b=None, *, relu_in: bool = True,
     if C < 128 and not interpret:
         # Mosaic rejects the [rows, W·C] → [rows·W, C] shape cast below
         # 128 lanes; padding C to 128 would double the bytes the fusion
-        # exists to save — see bench/PROFILE.md round-5 verdict
+        # exists to save
         raise NotImplementedError(
             f"conv3x3_bn_act requires C >= 128 on TPU (got {C}); "
-            f"use the XLA path (bench/PROFILE.md round 5)")
+            f"use the XLA path")
     if H * W * C * jnp.dtype(x.dtype).itemsize > 2 ** 20 and H % 8:
         raise ValueError("large image plane needs H divisible by 8 "
                          "(row-tiled path)")

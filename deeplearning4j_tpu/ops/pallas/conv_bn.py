@@ -21,8 +21,6 @@ kernels: dX (epilogue: da, db reductions) and dW (VMEM-accumulated);
 the cotangents of the emitted statistics (ds1, ds2) fold into
 ``dy_total = dy + ds1 + 2*y*ds2`` inside the kernels, so the entire
 BN-training backward costs no extra HBM passes over activations.
-
-bench/PROFILE.md (round 3) records the measured traffic/throughput.
 """
 
 from __future__ import annotations
@@ -141,32 +139,63 @@ def _pad_m(x, block_m):
     return x
 
 
-_VMEM_BUDGET = 10 * 1024 * 1024   # conservative slice of the 16 MB scoped VMEM
+_MIB = 1024 * 1024
+_VMEM_SCOPED = 16 * _MIB    # Mosaic's limit for a kernel that states none
+_VMEM_BUDGET = 10 * _MIB    # the slice of it the M-tiles are sized into
+_VMEM_CEILING = 64 * _MIB   # the most a kernel here states for itself:
+#                             half of a v5e core's 128 MiB, the rest stays
+#                             with the XLA program around the kernel
+
+
+def _resident_bytes(k, n, itemsize, *, bwd):
+    """VMEM no M-block choice changes.  Pallas double-buffers every
+    in/out block, so the backward holds W and its f32 dW OUTPUT block
+    twice, plus the f32 dW scratch; the forward holds W."""
+    if bwd:
+        return 2 * (k * n * itemsize + 4 * k * n) + 4 * k * n
+    return k * n * itemsize
+
+
+def _tile_bytes(bm, k, n, itemsize, *, bwd):
+    """Double-buffered M-tiles: x, dx, y, dy (backward) or x, y."""
+    return 2 * bm * ((2 * k + 2 * n) if bwd else (k + n)) * itemsize
 
 
 def _pick_block(m, k, n, itemsize, *, bwd):
-    """Largest power-of-two M-block whose double-buffered working set
-    (tiles + resident W + f32 dW scratch for the backward) fits VMEM."""
-    if bwd:
-        fixed = k * n * itemsize + 4 * k * n              # W + dW scratch
-    else:
-        fixed = k * n * itemsize
-    if fixed > 14 * 1024 * 1024:
-        # W (+ dW scratch) alone exceed VMEM — no block size can help
+    """Largest power-of-two M-block whose tiles fit beside the resident
+    blocks in ``_VMEM_BUDGET``; the smallest candidate where none does
+    (the backward then states its real need, ``_bwd_vmem_limit``)."""
+    fixed = _resident_bytes(k, n, itemsize, bwd=bwd)
+    if not bwd and fixed > 14 * _MIB:
+        # W alone exceeds the scoped VMEM — no block size can help
         raise ValueError(
-            f"matmul_bn_act: weight [{k}, {n}] (+ f32 dW scratch) cannot "
-            f"fit the ~16 MB TPU VMEM; channel dims too large for the "
-            f"fused kernel — use the unfused conv+BN path")
+            f"matmul_bn_act: weight [{k}, {n}] cannot fit the ~16 MB "
+            f"scoped TPU VMEM; channel dims too large for the fused "
+            f"kernel — use the unfused conv+BN path")
     for bm in (4096, 2048, 1024, 512, 256, 128):
-        if bwd:
-            tiles = 2 * bm * (2 * k + 2 * n) * itemsize   # x, dx, y, dy
-        else:
-            tiles = 2 * bm * (k + n) * itemsize           # x, y
-        if tiles + fixed <= _VMEM_BUDGET:
+        if _tile_bytes(bm, k, n, itemsize, bwd=bwd) + fixed <= _VMEM_BUDGET:
             break
-    # fall through with the smallest candidate (the estimate is
-    # conservative; Mosaic reports its own OOM if it truly doesn't fit)
     return max(8, min(bm, -(-m // 8) * 8))
+
+
+def _bwd_vmem_limit(bm, k, n, itemsize):
+    """Scoped-VMEM limit the backward states for itself.  Inside a whole
+    train step XLA may keep a neighbouring buffer in VMEM too, so the
+    default limit that admits the kernel alone refuses it there (the
+    ResNet-50 stage-5 K=1024, N=2048 conv); the kernel states what it
+    holds plus a quarter for the [8, ·] rows and Mosaic's own f32
+    temporaries."""
+    need = (_tile_bytes(bm, k, n, itemsize, bwd=True)
+            + _resident_bytes(k, n, itemsize, bwd=True))
+    need += need // 4
+    if need > _VMEM_CEILING:
+        raise ValueError(
+            f"matmul_bn_act: the backward of weight [{k}, {n}] holds W, "
+            f"the f32 dW block and scratch and its tiles in VMEM, "
+            f"{need // _MIB} MiB of the {_VMEM_CEILING // _MIB} MiB a "
+            f"kernel may claim; channel dims too large for the fused "
+            f"kernel — use the unfused conv+BN path")
+    return max(need, _VMEM_SCOPED)
 
 
 def _row(v, n):
@@ -271,6 +300,9 @@ def _matmul_bn_bwd(has_prologue, relu_in, block_m, interpret, res, cts):
         scratch_shapes=[pltpu.VMEM((k, n), jnp.float32),
                         pltpu.VMEM((8, k), jnp.float32),
                         pltpu.VMEM((8, k), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_limit(
+                block_m, k, n, jnp.dtype(x.dtype).itemsize)),
         interpret=interpret,
     )(xf, w, av, bv, yf, dyf, ds1v, ds2v)
 

@@ -209,11 +209,18 @@ class _BatchStage:
 
 def _pure_forward_net(model) -> bool:
     """True for nets whose forward is a pure function of (params, state,
-    x, mask) with one input — the MultiLayerNetwork family.  Those get a
-    process-cached jit forward; ComputationGraph (multi-input ``output``)
-    and duck-typed models fall back to ``model.output``."""
-    return (hasattr(model, "_forward") and not hasattr(model, "layer_params")
-            and getattr(model, "params_", None) is not None)
+    x, mask) with one input and one output — the MultiLayerNetwork
+    family and a ComputationGraph with a single input and output (the
+    conv zoo: ResNet-50 is one).  Those get a process-cached jit
+    forward, and with it the recompile guard and the artifact store;
+    multi-input/-output graphs and duck-typed models fall back to
+    ``model.output``."""
+    if not hasattr(model, "_forward") \
+            or getattr(model, "params_", None) is None:
+        return False
+    if hasattr(model, "layer_params"):          # ComputationGraph
+        return len(model.conf.inputs) == 1 and len(model.conf.outputs) == 1
+    return True
 
 
 def _build_forward(net):

@@ -4,8 +4,8 @@ Restarts are a *routine* event in this stack: the supervisor respawns
 gangs on purpose (PR 8), the online loop hot-swaps models continuously
 (PR 9), and precision flips redeploy the same architecture (PR 11) —
 yet every one of them used to pay live XLA compilation per bucket on
-first traffic.  This module extends :mod:`train.step_cache` and
-``config.compile_cache_dir``'s idea (compiled programs are durable
+first traffic.  This module extends :mod:`train.step_cache` and the
+persistent compilation cache's idea (compiled programs are durable
 state, not a per-process accident) into a **versioned artifact store
 that travels inside the checkpoint zip**:
 
@@ -36,8 +36,9 @@ Key schema (one index entry per program)::
               [updater sig, sharding sig,] kind>,
      "kind": "train" | "tbptt" | "train_stats" | "eval" | "serve_forward",
      "in_sig":  [[shape, dtype], ...]   # abstract call signature
-     "format":  1, "jax": "0.4.37", "backend": "cpu",
+     "format":  2, "jax": "0.9.0", "backend": "cpu",
      "donation": "0,1,2",               # donate_argnums the kind expects
+     "devices": [0],                    # device ids the program runs on
      "exec": "artifacts/<id>.exec",     # serialized XLA executable
      "stablehlo": "artifacts/<id>.stablehlo.mlir"}  # portable module
 
@@ -58,7 +59,7 @@ from typing import Any, Callable, Optional, Sequence
 
 log = logging.getLogger("deeplearning4j_tpu")
 
-ARTIFACT_FORMAT = 1
+ARTIFACT_FORMAT = 2    # 2: entries name the devices they were baked for
 INDEX_ENTRY = "artifacts/index.json"
 
 # donate_argnums each program kind is built with (train/trainer.py,
@@ -329,6 +330,8 @@ def bake_program(fn: Any, abstract_args: Any, key: Sequence, kind: str,
         "id": art_id, "key": list(key), "kind": kind,
         "in_sig": _sig_to_json(sig),
         "donation": KIND_DONATION.get(kind, ""),
+        "devices": [d.id for d in
+                    compiled.runtime_executable().local_devices()],
         "exec": f"artifacts/{art_id}.exec",
         **environment(),
     }
@@ -352,6 +355,10 @@ def _serve_feature_struct(net, bucket: int):
     import jax
     import numpy as np
     input_type = getattr(net.conf, "input_type", None)
+    if input_type is None:
+        # a single-input ComputationGraph declares a list of one
+        input_types = getattr(net.conf, "input_types", None) or [None]
+        input_type = input_types[0]
     if input_type is None:
         return None
     try:
@@ -540,6 +547,7 @@ def warm_from_zip(path: str) -> int:
     process pool.  Mismatched or undeserializable artifacts are counted
     rejects that fall back to live compilation — never an error.
     Returns the number of programs loaded."""
+    import jax
     from jax.experimental.serialize_executable import deserialize_and_load
 
     from deeplearning4j_tpu.obs import flight_recorder
@@ -551,6 +559,7 @@ def warm_from_zip(path: str) -> int:
         return 0
     reg = get_registry()
     env = environment()
+    by_id = {d.id: d for d in jax.devices()}
     t0 = time.perf_counter()
     loaded = rejected = resident = 0
     with zipfile.ZipFile(path, "r") as zf:
@@ -561,8 +570,12 @@ def warm_from_zip(path: str) -> int:
                 try:
                     kstr = key_str(tuple(ix["key"]))
                     sig = _sig_from_json(ix["in_sig"])
+                    baked_for = [int(i) for i in ix["devices"]]
                 except (KeyError, TypeError, ValueError):
                     reason = "malformed index entry"
+            if reason is None and not set(baked_for) <= set(by_id):
+                reason = (f"baked for devices {baked_for}, this process "
+                          f"has {sorted(by_id)}")
             if reason is None and _pool_has(kstr, sig):
                 # an equivalent program is already resident (baked or
                 # previously warmed) — first wins, nothing to load
@@ -575,8 +588,12 @@ def warm_from_zip(path: str) -> int:
                 try:
                     raw = zf.read(ix["exec"])
                     blob = pickle.loads(raw)
+                    # the program runs on the devices it was baked for —
+                    # one for a serve forward, the mesh's for a layout
+                    # step — not on every device of the process
                     compiled = deserialize_and_load(
-                        blob["payload"], blob["in_tree"], blob["out_tree"])
+                        blob["payload"], blob["in_tree"], blob["out_tree"],
+                        execution_devices=[by_id[i] for i in baked_for])
                 except Exception as e:
                     reason = f"undeserializable: {type(e).__name__}: {e}"
             if reason is not None:

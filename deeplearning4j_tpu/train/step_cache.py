@@ -19,9 +19,8 @@ Trainers with per-layer updater overrides or frozen layers opt out
 (key ``None`` → per-instance build, exactly the old behavior).
 
 jax's **persistent compilation cache** (XLA programs serialized to
-disk, surviving process restarts) is enabled from ``config.py`` when
-``compile_cache_dir`` / ``DL4J_TPU_COMPILE_CACHE_DIR`` is set — see
-:func:`deeplearning4j_tpu.config.get_config`.
+disk, surviving process restarts) is placed by the entry points — see
+:func:`deeplearning4j_tpu.config.place_compile_cache`.
 
 Metrics: ``tpudl_train_step_cache_hits_total`` /
 ``tpudl_train_step_cache_misses_total``.

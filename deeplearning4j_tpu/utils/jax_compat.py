@@ -1,45 +1,10 @@
-"""jax version-compatibility shims.
+"""The one import home of ``shard_map`` and ``pcast``.
 
-The TPU rig and CI containers can pin different jax releases; the two
-APIs the parallel stack leans on moved homes across versions:
-
-- ``shard_map``: top-level ``jax.shard_map`` in newer releases,
-  ``jax.experimental.shard_map.shard_map`` before that.  The older form
-  also lacks the varying-axis rep system, so replication checking is
-  disabled there (the newer checker is what the ``pcast`` annotations
-  below exist for).
-- ``lax.pcast(..., to="varying")``: newer-jax annotation marking a value
-  device-varying for the rep checker.  On older jax there is nothing to
-  annotate — the identity is semantically exact.
-
-Import from here instead of jax so every module degrades the same way.
+Both have moved between jax releases before.  Every module imports them
+from here (lint rule TPU304 points here), so the next move is one edit.
 """
 
-from __future__ import annotations
+from jax import shard_map
+from jax.lax import pcast
 
-from jax import lax
-
-try:
-    from jax import shard_map as _shard_map
-    _LEGACY_SHARD_MAP = False
-except ImportError:                       # pre-0.5 home
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY_SHARD_MAP = True
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    if _LEGACY_SHARD_MAP:
-        # newer jax calls the replication checker check_vma; legacy calls
-        # it check_rep AND predates the pcast annotations the checker
-        # needs, so it is forced off either way
-        kwargs.pop("check_vma", None)
-        kwargs["check_rep"] = False
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-else:
-    def pcast(x, axes, to=None):
-        return x
+__all__ = ["shard_map", "pcast"]

@@ -42,6 +42,7 @@ import json, os, sys, time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["DL4J_TPU_COSTMODEL"] = "0"
 import numpy as np
+from deeplearning4j_tpu.obs.registry import get_registry
 from deeplearning4j_tpu.serve import ModelRegistry
 zip_path, n_in, bucket = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
 x = np.zeros((bucket, n_in), np.float32)
@@ -56,6 +57,8 @@ print(json.dumps({"ready_s": round(ready_s, 4),
                   "total_s": round(total_s, 4),
                   "compiled_programs": entry.engine.compiled_programs,
                   "warm_programs": entry.engine.warm_programs,
+                  "artifact_rejects": get_registry().counter(
+                      "tpudl_compile_artifact_rejects_total").value,
                   "classes": int(out.shape[-1])}))
 registry.close()
 """

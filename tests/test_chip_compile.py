@@ -1,0 +1,152 @@
+"""Compiles for a TPU v5e that is described, not attached.
+
+The chip's compiler is installed beside the CPU backend and refuses what
+interpret mode never sees: a kernel that asks for more VMEM than it may
+use, a slice off the tiling, a program that does not fit the HBM.  These
+tests hand it the main path's kernels at ResNet-50 / BERT-base widths and
+ONE whole program, the ResNet-50 train step — the only level at which the
+fused 1x1 backward's scoped-VMEM overrun ever showed (alone, the same
+kernel at the same shapes compiles).  Nothing runs: no results, no times.
+
+Only one process may load the TPU library, and it keeps it until it exits.
+So the topology is described inside a module-scoped fixture, never at
+import, in ``parametrize`` or in ``skipif``, and every such compile lives in
+this one file: under xdist exactly one worker is handed it.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops.pallas.conv_bn import matmul_bn_act
+from deeplearning4j_tpu.ops.pallas.flash_attention import flash_attention
+from deeplearning4j_tpu.ops.pallas.quant_matmul import int8_matmul_pallas
+
+V5E_HBM_BYTES = 16e9
+
+# every 1x1 convolution of ResNet-50 at batch 128 as [M, K] @ [K, N]
+RESNET50_1X1 = [
+    (401408, 64, 256), (401408, 256, 64),
+    (100352, 512, 128), (100352, 128, 512),
+    (25088, 256, 1024), (25088, 1024, 256),
+    (6272, 1024, 2048), (6272, 2048, 512), (6272, 512, 2048),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding onto one described chip, with the persistent compilation
+    cache off while this module compiles: an entry written for a described
+    chip cannot be read back without one, and the retry warns."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_bert_base_seq4096(one_chip, grad):
+    attend = functools.partial(flash_attention, n_heads=12, interpret=False)
+    fn = attend
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+    qkv = ((2, 4096, 768), jnp.bfloat16)
+    assert _has_kernel(_compile(fn, one_chip, qkv, qkv, qkv))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("m,k,n", RESNET50_1X1)
+def test_matmul_bn_act_resnet50_1x1(one_chip, m, k, n, grad):
+    def fused(x, w, a, b):
+        return matmul_bn_act(x, w, a, b, interpret=False)
+
+    fn = fused
+    if grad:
+        def fn(x, w, a, b):
+            def loss(*args):
+                y, s1, s2 = fused(*args)
+                return (jnp.sum(y.astype(jnp.float32)) + jnp.sum(s1)
+                        + jnp.sum(s2))
+            return jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, a, b)
+    compiled = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                        ((k, n), jnp.bfloat16), ((k,), jnp.float32),
+                        ((k,), jnp.float32))
+    assert _has_kernel(compiled)
+
+
+def test_int8_matmul(one_chip):
+    fn = functools.partial(int8_matmul_pallas, interpret=False)
+    compiled = _compile(fn, one_chip, ((64, 2048), jnp.bfloat16),
+                        ((2048, 2048), jnp.int8), ((2048,), jnp.float32))
+    assert _has_kernel(compiled)
+
+
+def test_resnet50_train_step_whole_program(one_chip, monkeypatch):
+    """The program ``bench.py`` and ``chip_smoke.py`` run: ResNet-50,
+    224x224, 1000 classes, batch 128, bf16 policy, the default fused
+    path, lowered from ``make_train_step``.  Inside it XLA keeps a
+    neighbour of the stage-5 (K=1024, N=2048) fused backward in VMEM, and
+    the kernel's working set then overran the default scoped limit."""
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    from deeplearning4j_tpu.models import resnet50
+    from deeplearning4j_tpu.train import Nesterovs
+    from deeplearning4j_tpu.train.trainer import Trainer, make_train_step
+    batch = 128
+    was = dtype_policy()
+    set_dtype_policy(DTypePolicy.bf16())
+    try:
+        net = resnet50(height=224, width=224, num_classes=1000,
+                       updater=Nesterovs(0.1, 0.9))
+        net.init()
+        tx = Trainer(net).tx
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                                  sharding=one_chip), tree)
+
+        args = (on_chip(net.params_), on_chip(net.state_),
+                on_chip(jax.eval_shape(tx.init, net.params_)),
+                on_chip(jax.ShapeDtypeStruct((batch, 224, 224, 3),
+                                             jnp.float32)),
+                on_chip(jax.ShapeDtypeStruct((batch, 1000), jnp.float32)),
+                None, None,
+                on_chip(jax.eval_shape(lambda: jax.random.key(0))))
+        # the layers ask jax.default_backend() whether to interpret their
+        # kernels; the attached backend here is the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        compiled = make_train_step(net, tx).lower(*args).compile()
+    finally:
+        set_dtype_policy(was)
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < V5E_HBM_BYTES, mem

@@ -2,7 +2,7 @@
 (round 3, VERDICT #1: the cuDNN-platform-engine analog).
 
 Interpreter mode on the CPU rig; jnp implementations are the oracles.
-End-to-end ResNet numbers live in bench/PROFILE.md (round-3 section).
+The compiled kernels at ResNet-50's shapes: tests/test_chip_compile.py.
 """
 
 import jax

@@ -65,6 +65,22 @@ class TestBackendPeaks:
         assert peaks.estimated            # bandwidth is still synthetic
 
 
+    def test_unknown_tpu_kind_raises(self, monkeypatch):
+        """A chip the table does not know gets no other chip's peaks."""
+        import types
+        monkeypatch.delenv("DL4J_TPU_PEAK_TFLOPS", raising=False)
+        monkeypatch.delenv("DL4J_TPU_PEAK_HBM_GBPS", raising=False)
+        unknown = types.SimpleNamespace(platform="tpu",
+                                        device_kind="TPU v99 mega")
+        with pytest.raises(ValueError, match="v99 mega"):
+            costmodel.backend_peaks(unknown)
+        v5e = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")
+        peaks = costmodel.backend_peaks(v5e)
+        assert (peaks.peak_flops, peaks.peak_bytes_per_s) == (197e12, 819e9)
+        assert not peaks.estimated
+
+
 class TestAnalyze:
     def test_jitted_matmul_costs_and_roofline(self):
         @jax.jit

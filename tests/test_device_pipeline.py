@@ -401,17 +401,38 @@ def test_tpu307_clean_cases(tmp_path):
 
 
 # ------------------------------------------------------- persistent cache
-def test_compile_cache_dir_applied(tmp_path, monkeypatch):
-    import deeplearning4j_tpu.config as config_mod
-    prev = jax.config.jax_compilation_cache_dir
-    monkeypatch.setattr(config_mod, "_compile_cache_applied", None)
-    try:
-        set_config(compile_cache_dir=str(tmp_path / "xla-cache"))
-        assert jax.config.jax_compilation_cache_dir == \
-            str(tmp_path / "xla-cache")
-        # an empty path REVERTS the persistent cache, it is not a no-op
-        set_config(compile_cache_dir="")
-        assert jax.config.jax_compilation_cache_dir is None
-    finally:
-        set_config(compile_cache_dir="")
-        jax.config.update("jax_compilation_cache_dir", prev)
+@pytest.fixture
+def cache_dir_restored():
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_include_full_tracebacks_in_locations)
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev[0])
+    jax.config.update("jax_include_full_tracebacks_in_locations", prev[1])
+
+
+def test_compile_cache_placed_from_outside_is_untouched(
+        tmp_path, monkeypatch, cache_dir_restored):
+    from deeplearning4j_tpu import config as config_mod
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append(name))
+    assert config_mod.place_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in calls
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch,
+                                                cache_dir_restored):
+    import os
+
+    from deeplearning4j_tpu import config as config_mod
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    assert config_mod.place_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert config_mod.place_compile_cache() == want     # not pid/time made
+    # cache keys of programs that hold a Pallas kernel must not depend on
+    # the Python call stack that traced them
+    assert not jax.config.jax_include_full_tracebacks_in_locations

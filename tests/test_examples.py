@@ -95,7 +95,7 @@ def test_warm_restart_example(tmp_path):
     result = warm_restart.main(workdir=str(tmp_path), verbose=False)
     # the restarted server answered from the artifact store: no XLA
     # trace on the request path, and the first response got faster
-    assert result["zero_jit_after_warm"] is True
+    assert result["zero_jit_after_warm"] is True, result
     assert result["warm"]["classes"] == warm_restart.N_CLASSES
     assert result["first_response_speedup"] > 1.0
 

@@ -2,8 +2,8 @@
 
 Runs in interpreter mode on the CPU test rig; the jnp implementations
 (_block_attention / reference_attention) are the numerical oracles.
-The TPU-compiled path + long-seq microbench live in bench/flash_bench.py
-(numbers recorded in bench/PROFILE.md).
+The described-chip compiles live in tests/test_chip_compile.py, the
+compiled run against the einsum chain in chip_smoke.py.
 """
 
 import jax
@@ -295,8 +295,8 @@ class TestRingWithFlash:
 
 
 class TestConv3BnFused:
-    """Round-5 measurement artifact (negative result — see
-    bench/PROFILE.md): the 3×3 conv+BN kernel must still be CORRECT."""
+    """A measurement artifact (measured slower than XLA before PR 1): the
+    3×3 conv+BN kernel must still be CORRECT."""
 
     def _case(self, N=2, H=8, W=7, C=16):
         rng = np.random.default_rng(0)
