@@ -226,11 +226,16 @@ def place_compile_cache() -> str:
     and never a temporary one."""
     # A Mosaic kernel rides inside its custom call as bytecode WITH its
     # MLIR locations, and jax's cache key strips debug info only from the
-    # module around it.  With full tracebacks in those locations the key
+    # module around it.  With whole tracebacks in those locations the key
     # of every program that holds a Pallas kernel changes with the Python
     # call stack that first traced it — the train step of ``net.fit``
     # never hit the entry ``fit_batch`` wrote (seen on the chip, PR 22).
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # One frame, the innermost, is the same from every call stack.  (PR 22
+    # switched ``jax_include_full_tracebacks_in_locations`` off instead:
+    # that also moves the name stack out of the location XLA reads, and
+    # every ``jax.named_scope`` was gone from the compiled program and
+    # from the device trace, PR 27.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -207,9 +207,13 @@ class DeviceFeeder:
 
     Queue discipline is event-driven: the producer blocks in ``put`` and
     the consumer *drains* the queue on abandonment (no polling
-    timeouts on the hot path).  Metrics: ``tpudl_data_etl_wait_seconds``
-    (consumer-side wait per batch), ``tpudl_data_prefetch_depth``
-    (ready batches at each get), and a ``feed`` span per batch.
+    timeouts on the hot path).  Per batch, each span beside the counter
+    taken at the same boundary: on the producer's thread ``feed.source``
+    / ``tpudl_data_source_seconds`` (``next()`` on the iterator) and
+    ``feed.stage`` / ``tpudl_data_stage_seconds`` (:meth:`stage`, retries
+    included); on the consumer's ``feed.wait`` /
+    ``tpudl_data_etl_wait_seconds`` (the queue get), and
+    ``tpudl_data_prefetch_depth`` (ready batches after each get).
     """
 
     _DONE = object()
@@ -267,17 +271,37 @@ class DeviceFeeder:
         stop = threading.Event()
         error: list[BaseException] = []
 
+        reg = get_registry()
+        source_hist = reg.histogram("tpudl_data_source_seconds")
+        stage_hist = reg.histogram("tpudl_data_stage_seconds")
+        wait_hist = reg.histogram("tpudl_data_etl_wait_seconds")
+        depth_gauge = reg.gauge("tpudl_data_prefetch_depth")
+        # the producer's thread has no ambient span: its spans join the
+        # trace of whatever span called feed() (the trainer's epoch)
+        parent = tracing.current_context()
+
         def producer():
             try:
-                for item in iterator:
+                source = iter(iterator)
+                while not stop.is_set():
+                    t0 = time.perf_counter()
+                    with tracing.span("feed.source", parent=parent) as sp:
+                        item = next(source, self._DONE)
+                        if item is self._DONE:
+                            sp.set_attribute("exhausted", True)
+                    if item is self._DONE:
+                        return
+                    t1 = time.perf_counter()
+                    source_hist.observe(t1 - t0)
                     if stop.is_set():
                         return
-                    staged = with_retries(
-                        lambda item=item: self.stage(item),
-                        policy=self.retry_policy, site="feeder.stage")
+                    with tracing.span("feed.stage", parent=parent) as sp:
+                        staged = with_retries(
+                            lambda item=item: self.stage(item),
+                            policy=self.retry_policy, site="feeder.stage")
+                        sp.set_attribute("n_examples", staged.n_examples)
+                    stage_hist.observe(time.perf_counter() - t1)
                     q.put(staged)   # blocking; consumer drains on abandon
-                    if stop.is_set():
-                        return
             except BaseException as e:   # surfaced on the consumer side
                 error.append(e)
             finally:
@@ -292,14 +316,17 @@ class DeviceFeeder:
         # staging (see _drain's docstring)
         # tpudl: ok(TPU405) — feed()'s own finally stops+drains the producer
         thread.start()
-        reg = get_registry()
-        wait_hist = reg.histogram("tpudl_data_etl_wait_seconds")
-        depth_gauge = reg.gauge("tpudl_data_prefetch_depth")
         try:
             while True:
                 t0 = time.perf_counter()
-                item = q.get()
-                wait = time.perf_counter() - t0
+                with tracing.span("feed.wait") as sp:
+                    item = q.get()
+                    wait = time.perf_counter() - t0
+                    if item is not self._DONE:
+                        sp.set_attribute("wait_ms", round(wait * 1e3, 3))
+                        sp.set_attribute("n_examples", item.n_examples)
+                        if item.padded:
+                            sp.set_attribute("padded", item.padded)
                 if item is self._DONE:
                     if error:
                         raise error[0]
@@ -309,10 +336,6 @@ class DeviceFeeder:
                 # batches still ready AFTER taking this one: 0 here means
                 # the consumer is racing the producer (starvation)
                 depth_gauge.set(q.qsize())
-                with tracing.span("feed", wait_ms=round(wait * 1e3, 3),
-                                  n_examples=item.n_examples) as sp:
-                    if item.padded:
-                        sp.set_attribute("padded", item.padded)
                 yield item
         finally:
             stop.set()

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from functools import partial
 from typing import Any, Optional
 
@@ -27,7 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.config import dtype_policy
+from deeplearning4j_tpu.obs import tracing
+from deeplearning4j_tpu.obs.registry import train_loop_metrics
 from deeplearning4j_tpu.ops.attention import multi_head_attention
+from deeplearning4j_tpu.train import step_cache
 
 
 @dataclasses.dataclass
@@ -160,36 +164,40 @@ def encoder_layer(lp: dict, config: BertConfig, x: jnp.ndarray,
                   rng: Optional[jax.Array] = None) -> jnp.ndarray:
     """One transformer encoder block (bert/encoder/layer_N) — the single
     source for both :func:`encode` and :func:`pipeline_stages`."""
-    if config.fused_qkv:
-        at = lp["attention"]
-        policy = dtype_policy()
-        cd = policy.compute_dtype
-        kernel = jnp.concatenate(
-            [at["query"]["kernel"], at["key"]["kernel"],
-             at["value"]["kernel"]], axis=1).astype(cd)
-        bias = jnp.concatenate(
-            [at["query"]["bias"], at["key"]["bias"], at["value"]["bias"]])
-        qkv = (jnp.einsum("...i,io->...o", x.astype(cd), kernel)
-               + bias.astype(cd)).astype(policy.output_dtype)
-        h = x.shape[-1]
-        q, k, v = qkv[..., :h], qkv[..., h:2 * h], qkv[..., 2 * h:]
-    else:
-        q = _dense(lp["attention"]["query"], x)
-        k = _dense(lp["attention"]["key"], x)
-        v = _dense(lp["attention"]["value"], x)
-    attn = multi_head_attention(q, k, v, n_heads=config.num_heads,
-                                kv_mask=attention_mask,
-                                use_flash=config.use_flash,
-                                flash_block=config.flash_block)
-    attn = _dense(lp["attention"]["output"], attn)
-    attn = _dropout(attn, config.hidden_dropout, train, rng)
-    x = _layer_norm(lp["attention"]["output_layer_norm"], x + attn,
-                    config.layer_norm_eps)
-    inter = jax.nn.gelu(_dense(lp["intermediate"], x))
-    out = _dense(lp["output"], inter)
-    out = _dropout(out, config.hidden_dropout, train,
-                   jax.random.fold_in(rng, 7) if rng is not None else None)
-    return _layer_norm(lp["output_layer_norm"], x + out, config.layer_norm_eps)
+    with jax.named_scope("attention"):
+        if config.fused_qkv:
+            at = lp["attention"]
+            policy = dtype_policy()
+            cd = policy.compute_dtype
+            kernel = jnp.concatenate(
+                [at["query"]["kernel"], at["key"]["kernel"],
+                 at["value"]["kernel"]], axis=1).astype(cd)
+            bias = jnp.concatenate(
+                [at["query"]["bias"], at["key"]["bias"], at["value"]["bias"]])
+            qkv = (jnp.einsum("...i,io->...o", x.astype(cd), kernel)
+                   + bias.astype(cd)).astype(policy.output_dtype)
+            h = x.shape[-1]
+            q, k, v = qkv[..., :h], qkv[..., h:2 * h], qkv[..., 2 * h:]
+        else:
+            q = _dense(lp["attention"]["query"], x)
+            k = _dense(lp["attention"]["key"], x)
+            v = _dense(lp["attention"]["value"], x)
+        attn = multi_head_attention(q, k, v, n_heads=config.num_heads,
+                                    kv_mask=attention_mask,
+                                    use_flash=config.use_flash,
+                                    flash_block=config.flash_block)
+        attn = _dense(lp["attention"]["output"], attn)
+        attn = _dropout(attn, config.hidden_dropout, train, rng)
+        x = _layer_norm(lp["attention"]["output_layer_norm"], x + attn,
+                        config.layer_norm_eps)
+    with jax.named_scope("ffn"):
+        inter = jax.nn.gelu(_dense(lp["intermediate"], x))
+        out = _dense(lp["output"], inter)
+        out = _dropout(out, config.hidden_dropout, train,
+                       jax.random.fold_in(rng, 7) if rng is not None
+                       else None)
+        return _layer_norm(lp["output_layer_norm"], x + out,
+                           config.layer_norm_eps)
 
 
 def embed(params: dict, config: BertConfig, input_ids: jnp.ndarray,
@@ -211,14 +219,16 @@ def encode(params: dict, config: BertConfig, input_ids: jnp.ndarray,
            attention_mask: Optional[jnp.ndarray] = None,
            *, train: bool = False, rng: Optional[jax.Array] = None) -> jnp.ndarray:
     """input_ids [B,T] int32 → hidden states [B,T,H]."""
-    x = embed(params, config, input_ids, token_type_ids)
-    if rng is not None:
-        rng = jax.random.fold_in(rng, 0)
-    x = _dropout(x, config.hidden_dropout, train, rng)
+    with jax.named_scope("embeddings"):
+        x = embed(params, config, input_ids, token_type_ids)
+        if rng is not None:
+            rng = jax.random.fold_in(rng, 0)
+        x = _dropout(x, config.hidden_dropout, train, rng)
     for i in range(config.num_layers):
         layer_rng = jax.random.fold_in(rng, i + 1) if rng is not None else None
-        x = encoder_layer(params["encoder"][f"layer_{i}"], config, x,
-                          attention_mask, train=train, rng=layer_rng)
+        with jax.named_scope(f"encoder_{i}"):
+            x = encoder_layer(params["encoder"][f"layer_{i}"], config, x,
+                              attention_mask, train=train, rng=layer_rng)
     return x
 
 
@@ -261,14 +271,16 @@ def mlm_loss(params: dict, config: BertConfig, input_ids, labels, label_weights,
     which is TF BERT's max_predictions_per_seq behavior)."""
     hidden = encode(params, config, input_ids, token_type_ids, attention_mask,
                     train=train, rng=rng)
-    k = config.max_predictions
-    if k and k < hidden.shape[1]:
-        _, pos = jax.lax.top_k(label_weights, k)           # [B, k]
-        hidden = jnp.take_along_axis(hidden, pos[..., None], axis=1)
-        labels = jnp.take_along_axis(labels, pos, axis=1)
-        label_weights = jnp.take_along_axis(label_weights, pos, axis=1)
-    logits = mlm_logits(params, config, hidden)
-    return _weighted_mlm_ce(logits, labels, label_weights)
+    with jax.named_scope("mlm_head"):
+        k = config.max_predictions
+        if k and k < hidden.shape[1]:
+            _, pos = jax.lax.top_k(label_weights, k)           # [B, k]
+            hidden = jnp.take_along_axis(hidden, pos[..., None], axis=1)
+            labels = jnp.take_along_axis(labels, pos, axis=1)
+            label_weights = jnp.take_along_axis(label_weights, pos, axis=1)
+        logits = mlm_logits(params, config, hidden)
+    with jax.named_scope("loss"):
+        return _weighted_mlm_ce(logits, labels, label_weights)
 
 
 def pipeline_stages(config: BertConfig, params: dict, n_stages: int):
@@ -309,7 +321,8 @@ def pipeline_stages(config: BertConfig, params: dict, n_stages: int):
             else:
                 x = h
             for i in range(s * per, (s + 1) * per):
-                x = encoder_layer(p["layers"][f"layer_{i}"], config, x)
+                with jax.named_scope(f"encoder_{i}"):
+                    x = encoder_layer(p["layers"][f"layer_{i}"], config, x)
             if s == n_stages - 1:
                 y = jax.nn.gelu(_dense(p["mlm"]["transform"], x))
                 y = _layer_norm(p["mlm"]["transform_layer_norm"], y, eps)
@@ -388,18 +401,21 @@ class BertForMaskedLM:
         """
         config = self.config
 
+        # the name the device trace's XLA Modules line shows: jit_<name>
         @partial(jax.jit, donate_argnums=(0, 1))
-        def step(params, opt_state, input_ids, labels, label_weights,
-                 attention_mask, rng):
+        def tpudl_bert_mlm_step(params, opt_state, input_ids, labels,
+                                label_weights, attention_mask, rng):
             def loss_fn(p):
                 return mlm_loss(p, config, input_ids, labels, label_weights,
                                 attention_mask=attention_mask, train=True, rng=rng)
             loss, grads = jax.value_and_grad(loss_fn)(params)
-            updates, opt_state2 = tx.update(grads, opt_state, params)
-            params2 = jax.tree_util.tree_map(lambda a, u: a + u, params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state2 = tx.update(grads, opt_state, params)
+                params2 = jax.tree_util.tree_map(lambda a, u: a + u, params,
+                                                 updates)
             return params2, opt_state2, loss
 
-        return step
+        return tpudl_bert_mlm_step
 
     def fit(self, batches, updater=None, epochs: int = 1, listeners=None):
         from deeplearning4j_tpu.train import updaters as updater_mod
@@ -427,18 +443,51 @@ class BertForMaskedLM:
 
         from deeplearning4j_tpu.data.device_pipeline import DeviceFeeder
         feeder = DeviceFeeder(_place, bucketing=False)
-        for _ in range(epochs):
-            if hasattr(batches, "reset"):
-                batches.reset()
-            for fed in feeder.feed(batches):
-                key, sub = jax.random.split(key)
-                ids, labels, weights, attn = fed.batch
+        # the spans and counters Trainer.step_batch keeps, at the same
+        # boundaries (the handles looked up once, here)
+        metrics = train_loop_metrics()
+        with tracing.span("fit", model=type(self).__name__, epochs=epochs):
+            for epoch in range(epochs):
+                if hasattr(batches, "reset"):
+                    batches.reset()
+                with tracing.span("epoch", epoch=epoch):
+                    for fed in feeder.feed(batches):
+                        key, sub = jax.random.split(key)
+                        last = self._fit_step(fed, sub, bus, metrics)
+        return last
+
+    def _fit_step(self, fed, rng, bus, metrics) -> float:
+        """One iteration of :meth:`fit`: ``step`` over all of it,
+        ``step.dispatch`` around the jitted call and nothing else,
+        ``step.read`` around the loss's read and the listeners."""
+        t0 = time.perf_counter()
+        with tracing.span("step", iteration=self.iteration) as sp:
+            ids, labels, weights, attn = fed.batch
+            traces_before = step_cache.jit_cache_entries(self._step)
+            t1 = time.perf_counter()
+            with tracing.span("step.dispatch"):
                 self.params, self.opt_state, loss = self._step(
                     self.params, self.opt_state, ids, labels, weights,
-                    attn, sub)
+                    attn, rng)
+            t2 = time.perf_counter()
+            retraced = (step_cache.jit_cache_entries(self._step)
+                        - traces_before)
+            if retraced > 0:
+                sp.set_attribute("compile", True)
+                metrics.recompiles.inc(retraced)
+                metrics.compile_seconds.set(t2 - t0)
+            metrics.steps.inc()
+            metrics.examples.inc(fed.n_examples)
+            t3 = time.perf_counter()
+            with tracing.span("step.read"):
                 last = float(loss)
                 bus.dispatch("iteration_done", self, self.iteration, 0, last)
-                self.iteration += 1
+            t4 = time.perf_counter()
+            self.iteration += 1
+        if retraced == 0:
+            metrics.iteration.observe(time.perf_counter() - t0)
+            metrics.dispatch.observe(t2 - t1)
+            metrics.read.observe(t4 - t3)
         return last
 
     def predict_mlm(self, input_ids, attention_mask=None):

@@ -293,34 +293,37 @@ class ComputationGraph:
             in_acts = [acts[i] for i in spec.inputs]
             in_mask = next((act_masks.get(i) for i in spec.inputs
                             if act_masks.get(i) is not None), None)
-            if spec.kind == "layer":
-                layer_rng = jax.random.fold_in(rng, vi) if rng is not None else None
-                itype = known_types[spec.inputs[0]]
-                x = preprocessors.adapt_array(in_acts[0], itype, spec.obj)
-                if (labels is not None and spec.name in self.conf.outputs
-                        and hasattr(spec.obj, "compute_score_array")):
-                    out_idx = self.conf.outputs.index(spec.name)
-                    # same noised weights as apply(): IWeightNoise applies
-                    # to the loss path too (DL4J BaseLayer.getParamWithNoise)
-                    score_arrays.append(spec.obj.compute_score_array(
+            # the vertex's name on every device operation it lowers to:
+            # what obs.profiler.timeline sums device time by
+            with jax.named_scope(spec.name):
+                if spec.kind == "layer":
+                    layer_rng = jax.random.fold_in(rng, vi) if rng is not None else None
+                    itype = known_types[spec.inputs[0]]
+                    x = preprocessors.adapt_array(in_acts[0], itype, spec.obj)
+                    if (labels is not None and spec.name in self.conf.outputs
+                            and hasattr(spec.obj, "compute_score_array")):
+                        out_idx = self.conf.outputs.index(spec.name)
+                        # same noised weights as apply(): IWeightNoise applies
+                        # to the loss path too (DL4J BaseLayer.getParamWithNoise)
+                        score_arrays.append(spec.obj.compute_score_array(
+                            spec.obj.noised_params(params[spec.name], train,
+                                                   layer_rng),
+                            state[spec.name], x,
+                            label_list[out_idx], train=train, rng=layer_rng,
+                            mask=in_mask))
+                    y, s = spec.obj.apply(
                         spec.obj.noised_params(params[spec.name], train,
                                                layer_rng),
                         state[spec.name], x,
-                        label_list[out_idx], train=train, rng=layer_rng,
-                        mask=in_mask))
-                y, s = spec.obj.apply(
-                    spec.obj.noised_params(params[spec.name], train,
-                                           layer_rng),
-                    state[spec.name], x,
-                    train=train, rng=layer_rng, mask=in_mask)
-                new_state[spec.name] = s
-                known_types[spec.name] = spec.obj.get_output_type(
-                    preprocessors.adapt_type(itype, spec.obj))
-            else:
-                y = spec.obj.apply(in_acts)
-                new_state[spec.name] = state[spec.name]
-                known_types[spec.name] = spec.obj.get_output_type(
-                    [known_types[i] for i in spec.inputs])
+                        train=train, rng=layer_rng, mask=in_mask)
+                    new_state[spec.name] = s
+                    known_types[spec.name] = spec.obj.get_output_type(
+                        preprocessors.adapt_type(itype, spec.obj))
+                else:
+                    y = spec.obj.apply(in_acts)
+                    new_state[spec.name] = state[spec.name]
+                    known_types[spec.name] = spec.obj.get_output_type(
+                        [known_types[i] for i in spec.inputs])
             acts[spec.name] = y
             act_masks[spec.name] = in_mask
         outs = [acts[name] for name in self.conf.outputs]

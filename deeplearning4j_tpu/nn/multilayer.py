@@ -89,29 +89,33 @@ class MultiLayerNetwork:
         current_mask = mask
         score_array = None
         for i, (layer, itype) in enumerate(zip(self.layers, types)):
-            x = preprocessors.adapt_array(x, itype_before(self, i, types), layer)
-            layer_rng = jax.random.fold_in(rng, i) if rng is not None else None
-            is_last = i == len(self.layers) - 1
-            if is_last and labels is not None and hasattr(layer, "compute_score_array"):
-                # same noised weights as apply(): IWeightNoise applies to
-                # the loss path too (DL4J BaseLayer.getParamWithNoise)
-                score_array = layer.compute_score_array(
-                    layer.noised_params(params[i], train, layer_rng),
-                    state[i], x, labels, train=train, rng=layer_rng,
-                    mask=current_mask)
-            if carries is not None and isinstance(layer, BaseRecurrentLayer):
-                carry = carries[i]
-                if carry is not None:
-                    carry = jax.lax.stop_gradient(carry)
-                y, s, new_carries[i] = layer.apply_with_carry(
-                    layer.noised_params(params[i], train, layer_rng),
-                    state[i], x, carry, train=train, rng=layer_rng,
-                    mask=current_mask)
-            else:
-                y, s = layer.apply(
-                    layer.noised_params(params[i], train, layer_rng),
-                    state[i], x, train=train,
-                    rng=layer_rng, mask=current_mask)
+            # the layer's name on every device operation it lowers to: what
+            # obs.profiler.timeline sums device time by
+            with jax.named_scope(layer.name
+                                 or f"layer{i}_{type(layer).__name__}"):
+                x = preprocessors.adapt_array(x, itype_before(self, i, types), layer)
+                layer_rng = jax.random.fold_in(rng, i) if rng is not None else None
+                is_last = i == len(self.layers) - 1
+                if is_last and labels is not None and hasattr(layer, "compute_score_array"):
+                    # same noised weights as apply(): IWeightNoise applies to
+                    # the loss path too (DL4J BaseLayer.getParamWithNoise)
+                    score_array = layer.compute_score_array(
+                        layer.noised_params(params[i], train, layer_rng),
+                        state[i], x, labels, train=train, rng=layer_rng,
+                        mask=current_mask)
+                if carries is not None and isinstance(layer, BaseRecurrentLayer):
+                    carry = carries[i]
+                    if carry is not None:
+                        carry = jax.lax.stop_gradient(carry)
+                    y, s, new_carries[i] = layer.apply_with_carry(
+                        layer.noised_params(params[i], train, layer_rng),
+                        state[i], x, carry, train=train, rng=layer_rng,
+                        mask=current_mask)
+                else:
+                    y, s = layer.apply(
+                        layer.noised_params(params[i], train, layer_rng),
+                        state[i], x, train=train,
+                        rng=layer_rng, mask=current_mask)
             new_state.append(s)
             x = y
             # time-geometry layers reshape the [B,T] mask alongside the data

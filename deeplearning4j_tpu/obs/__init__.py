@@ -8,7 +8,7 @@ from deeplearning4j_tpu.obs.listeners import (
     EvaluativeListener,
 )
 from deeplearning4j_tpu.obs.metrics import MetricsWriter
-from deeplearning4j_tpu.obs.profiler import check_finite, StepTimer
+from deeplearning4j_tpu.obs.profiler import check_finite, timeline
 from deeplearning4j_tpu.obs.registry import (
     Counter, Gauge, Histogram, LabeledCounter, LabeledGauge,
     LabeledHistogram, MetricsRegistry,
@@ -38,7 +38,7 @@ __all__ = [
     "EvaluativeListener",
     "MetricsWriter",
     "check_finite",
-    "StepTimer",
+    "timeline",
     "Counter",
     "Gauge",
     "Histogram",

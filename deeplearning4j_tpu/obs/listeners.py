@@ -16,6 +16,8 @@ import logging
 import time
 from typing import Any, Callable, Optional
 
+from deeplearning4j_tpu.obs.registry import get_registry
+
 log = logging.getLogger("deeplearning4j_tpu")
 
 
@@ -61,8 +63,10 @@ class ScoreIterationListener(TrainingListener):
         self.frequency = max(1, frequency)
 
     def iteration_done(self, model, iteration, epoch, score):
-        if iteration % self.frequency == 0:
+        if iteration % self.frequency == 0 and log.isEnabledFor(logging.INFO):
+            score = float(score)   # the one read of the device's scalar
             log.info("Score at iteration %d (epoch %d) is %.6f", iteration, epoch, score)
+            get_registry().gauge("tpudl_train_last_score").set(score)
 
 
 class CollectScoresListener(TrainingListener):

@@ -23,7 +23,7 @@ from __future__ import annotations
 import math
 import re
 import threading
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 METRIC_NAME_RE = re.compile(r"^tpudl_[a-z0-9]+_[a-z][a-z0-9_]*[a-z0-9]$")
 
@@ -464,16 +464,26 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
         r.counter("tpudl_train_examples_total",
                   "Training examples consumed"),
         r.counter("tpudl_train_epochs_total", "Epochs completed"),
-        r.histogram("tpudl_train_step_seconds",
-                    "Wall time per training step (sync-inclusive when "
-                    "tracing is on, dispatch-only otherwise)"),
+        r.histogram("tpudl_train_iteration_seconds",
+                    "Host time of one whole loop iteration, fault site to "
+                    "the iteration counter's increment (the step span); "
+                    "compile steps are left out of this and the next two"),
+        r.histogram("tpudl_train_dispatch_seconds",
+                    "Host time inside the jitted step's call(s) of one "
+                    "iteration: enqueueing, not the device's execution "
+                    "(the step.dispatch spans)"),
+        r.histogram("tpudl_train_read_seconds",
+                    "Host time of one iteration's listeners, where the "
+                    "loop may block on a device value (the step.read "
+                    "spans)"),
         r.histogram("tpudl_train_epoch_seconds",
                     "Wall time per completed epoch (fit loop, feed "
                     "included)"),
         r.gauge("tpudl_train_compile_seconds",
                 "Wall time of the most recent first-call (trace+compile) "
                 "step through a jit boundary"),
-        r.gauge("tpudl_train_last_score", "Most recent training loss"),
+        r.gauge("tpudl_train_last_score",
+                "Most recent loss a ScoreIterationListener read"),
         r.counter("tpudl_train_recompiles_total",
                   "New XLA traces of trainer step functions (first "
                   "compile included; shape churn past step 1 means the "
@@ -512,6 +522,13 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
         r.histogram("tpudl_data_etl_wait_seconds",
                     "Consumer-side wait for the next ready batch "
                     "(DeviceFeeder / AsyncDataSetIterator queue get)"),
+        r.histogram("tpudl_data_source_seconds",
+                    "DeviceFeeder producer: time inside next() on the "
+                    "user's iterator, per batch (the feed.source span)"),
+        r.histogram("tpudl_data_stage_seconds",
+                    "DeviceFeeder producer: time staging one batch, "
+                    "retries included: bucket pad, place_fn, device_put's "
+                    "call (the feed.stage span)"),
         r.gauge("tpudl_data_prefetch_depth",
                 "Device-ready batches still queued after the most "
                 "recent get (0 = consumer racing the producer)"),
@@ -839,6 +856,32 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
                     "(arbiter) — the elastic MTTR"),
     ]
     return {m.name: m for m in metrics}
+
+
+class TrainLoopMetrics(NamedTuple):
+    """Handles of the series every training loop keeps (``Trainer``,
+    ``BertForMaskedLM.fit``): looked up once per fit, used every step."""
+
+    steps: Counter
+    examples: Counter
+    recompiles: Counter
+    compile_seconds: Gauge
+    iteration: Histogram
+    dispatch: Histogram
+    read: Histogram
+
+
+def train_loop_metrics(
+        registry: Optional[MetricsRegistry] = None) -> TrainLoopMetrics:
+    r = registry or get_registry()
+    return TrainLoopMetrics(
+        r.counter("tpudl_train_steps_total"),
+        r.counter("tpudl_train_examples_total"),
+        r.counter("tpudl_train_recompiles_total"),
+        r.gauge("tpudl_train_compile_seconds"),
+        r.histogram("tpudl_train_iteration_seconds"),
+        r.histogram("tpudl_train_dispatch_seconds"),
+        r.histogram("tpudl_train_read_seconds"))
 
 
 def record_device_memory(registry: Optional[MetricsRegistry] = None,

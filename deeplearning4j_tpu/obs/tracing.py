@@ -6,10 +6,15 @@ it has no notion of *where inside a step* time went, and nothing that
 survives a process boundary.  This module is the TPU-native upgrade:
 
 - :func:`span` opens a nestable span (``fit`` → ``epoch`` → ``step`` →
-  ...) carrying wall time, attributes, and device-sync time (the part of
-  a step spent blocked on the accelerator, attributed explicitly via
-  :func:`device_sync` because an async-dispatch runtime makes plain wall
-  clocks lie).
+  ``step.dispatch`` / ``step.read``; ``feed.*`` on the feeder's threads)
+  carrying its start and end in Unix nanoseconds, the thread that did
+  the work, and attributes.  A span never syncs the device: it covers
+  host time only, on the clock a ``jax.profiler`` trace starts from
+  (``profile_start_time``), so :func:`obs.profiler.timeline` can lay the
+  spans over the device's own events.  Device time comes from that
+  trace, never from a span.  (:func:`device_sync` is for code that must
+  block anyway, e.g. the serving engine's D2H, and wants the wait
+  attributed.)
 - Span context (trace id + span id) serializes with :func:`inject` /
   :func:`extract` and propagates to child processes through the
   ``DL4J_TPU_TRACE_CONTEXT`` environment variable, so spans emitted by
@@ -69,7 +74,13 @@ class Span:
     device_sync_s: float = 0.0           # time blocked on device→host sync
     pid: int = dataclasses.field(default_factory=os.getpid)
     tid: int = dataclasses.field(default_factory=threading.get_ident)
-    _t0: float = 0.0                     # perf_counter at start (duration)
+    # Unix nanoseconds: time_ns() at the start, the end a perf_counter_ns
+    # difference later, so a span's length never sees the wall clock step
+    start_ns: int = 0
+    end_ns: Optional[int] = None
+    thread: str = dataclasses.field(
+        default_factory=lambda: threading.current_thread().name)
+    _t0: int = 0                         # perf_counter_ns at start
 
     @property
     def duration_s(self) -> float:
@@ -88,9 +99,10 @@ class Span:
             "name": self.name, "trace_id": self.trace_id,
             "span_id": self.span_id, "parent_id": self.parent_id,
             "start_s": self.start_s, "end_s": self.end_s,
+            "start_ns": self.start_ns, "end_ns": self.end_ns,
             "duration_s": self.duration_s,
             "device_sync_s": self.device_sync_s,
-            "pid": self.pid, "tid": self.tid,
+            "pid": self.pid, "tid": self.tid, "thread": self.thread,
             "attributes": self.attributes,
         }
 
@@ -178,13 +190,19 @@ class Tracer:
             cur = _current_span.get()
             parent = cur.context() if cur is not None else self._remote_parent
         trace_id = parent.trace_id if parent else _new_id()
-        return Span(name=name, trace_id=trace_id, span_id=_new_id(),
+        span_id = _new_id()
+        # the two clocks read back to back, after the ids are made: what
+        # lies between the reads would shift this span's end against its
+        # parent's and children's
+        start_ns, t0 = time.time_ns(), time.perf_counter_ns()
+        return Span(name=name, trace_id=trace_id, span_id=span_id,
                     parent_id=parent.span_id if parent else None,
-                    start_s=time.time(), _t0=time.perf_counter(),
+                    start_s=start_ns / 1e9, start_ns=start_ns, _t0=t0,
                     attributes=dict(attributes or {}))
 
     def finish_span(self, s: Span) -> None:
-        s.end_s = s.start_s + (time.perf_counter() - s._t0)
+        s.end_ns = s.start_ns + (time.perf_counter_ns() - s._t0)
+        s.end_s = s.end_ns / 1e9
         with self._lock:
             if len(self.spans) < self.MAX_SPANS:
                 self.spans.append(s)
@@ -308,6 +326,39 @@ def current_context() -> Optional[SpanContext]:
     if s is not None:
         return s.context()
     return _global_tracer._remote_parent
+
+
+def self_intervals(spans) -> list:
+    """Each finished span's own time as ``(start_ns, end_ns, thread,
+    name)``, Unix nanoseconds: its interval less what its children on the
+    same thread cover (a child on another thread, the feeder's, does work
+    of its own).  A thread is then in at most one interval at any instant,
+    its innermost span's, so summing them by name never counts time twice.
+    The name says where the span sat: ``step>step.read``,
+    ``feed.stage>retry_attempt``, the parent first where it ran on the
+    same thread.  ``spans`` are :class:`Span`\\ s or their ``to_dict()``
+    forms."""
+    spans = [s if isinstance(s, dict) else s.to_dict() for s in spans]
+    by_id = {s["span_id"]: s for s in spans}
+    kids: dict = {}
+    for s in spans:
+        if s.get("end_ns"):
+            kids.setdefault((s["parent_id"], s["tid"]), []).append(s)
+    out = []
+    for (parent_id, tid), group in kids.items():
+        parent = by_id.get(parent_id)
+        prefix = (parent["name"] + ">"
+                  if parent is not None and parent["tid"] == tid else "")
+        for s in group:
+            name, cur = prefix + s["name"], s["start_ns"]
+            for k in sorted(kids.get((s["span_id"], tid), ()),
+                            key=lambda k: k["start_ns"]):
+                if k["start_ns"] > cur:
+                    out.append((cur, k["start_ns"], s["thread"], name))
+                cur = max(cur, k["end_ns"])
+            if cur < s["end_ns"]:
+                out.append((cur, s["end_ns"], s["thread"], name))
+    return out
 
 
 # ------------------------------------------------------ wire propagation

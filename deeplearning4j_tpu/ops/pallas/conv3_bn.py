@@ -208,6 +208,7 @@ def _fwd_impl(x, w, a, b, *, has_prologue, relu_in, interpret):
             ],
             scratch_shapes=[pltpu.VMEM((8, Cout), jnp.float32),
                             pltpu.VMEM((8, Cout), jnp.float32)],
+            name="tpudl_conv3x3_bn_fwd_tiled",
             interpret=interpret,
         )(xf, xf, xf, wf, av, bv)
         return y.reshape(N, H, W, Cout), s1[0], s2[0]
@@ -235,6 +236,7 @@ def _fwd_impl(x, w, a, b, *, has_prologue, relu_in, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((8, Cout), jnp.float32),
                         pltpu.VMEM((8, Cout), jnp.float32)],
+        name="tpudl_conv3x3_bn_fwd",
         interpret=interpret,
     )(xf, wf, av, bv)
     return y.reshape(N, H, W, Cout), s1[0], s2[0]

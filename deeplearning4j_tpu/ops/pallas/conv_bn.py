@@ -243,6 +243,7 @@ def _fwd_impl(x, w, a, b, *, has_prologue, relu_in, block_m, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((8, n), jnp.float32),
                         pltpu.VMEM((8, n), jnp.float32)],
+        name="tpudl_matmul_bn_fwd",
         interpret=interpret,
     )(xf, w, av, bv)
     return y[:m], s1[0], s2[0]
@@ -303,6 +304,7 @@ def _matmul_bn_bwd(has_prologue, relu_in, block_m, interpret, res, cts):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_bwd_vmem_limit(
                 block_m, k, n, jnp.dtype(x.dtype).itemsize)),
+        name="tpudl_matmul_bn_bwd",
         interpret=interpret,
     )(xf, w, av, bv, yf, dyf, ds1v, ds2v)
 

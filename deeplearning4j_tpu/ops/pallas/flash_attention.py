@@ -229,6 +229,7 @@ def flash_attention_block(q, k, v, *, scale: float, causal: bool = False,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+        name="tpudl_flash_fwd",
         interpret=interpret,
     )(qoff, koff, klen, qf, kf, vf, kmaskf)
     o = o[:, :tq].reshape(b, h, tq, d)
@@ -544,6 +545,7 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                        _sds(qf, kf, (n_k, b * h, tq_p, d), dqp_dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
+            name="tpudl_flash_bwd_merged",
             interpret=interpret,
         )(qoff, koff, klen, qf, kf, vf, kmaskf, dof, lsef, deltaf)
         dq = jnp.sum(dqp.astype(jnp.float32), axis=0)
@@ -570,6 +572,7 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
         out_specs=q_spec,
         out_shape=_sds(qf, kf, (b * h, tq_p, d)),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="tpudl_flash_bwd_dq",
         interpret=interpret,
     )(qoff, koff, klen, qf, kf, vf, kmaskf, dof, lsef, deltaf)
 
@@ -592,6 +595,7 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                    _sds(qf, kf, (b * h, tk_p, d))],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="tpudl_flash_bwd_dkv",
         interpret=interpret,
     )(qoff, koff, klen, qf, kf, vf, kmaskf, dof, lsef, deltaf)
 

@@ -118,6 +118,7 @@ def int8_matmul_pallas(x, w_q, scale, *, block_m: int = 0,
         ],
         out_specs=pl.BlockSpec((block_m, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xf.shape[0], n), x.dtype),
+        name="tpudl_quant_matmul",
         interpret=interpret,
     )(xf, w_q, _scale_row(scale, n))
     return y[:m]

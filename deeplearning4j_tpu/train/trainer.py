@@ -15,6 +15,7 @@ divides by batch size, DL4J semantics).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -32,7 +33,8 @@ from deeplearning4j_tpu.obs import costmodel, flight_recorder, tracing
 from deeplearning4j_tpu.obs import remote as obs_remote
 from deeplearning4j_tpu.obs.listeners import ListenerBus
 from deeplearning4j_tpu.obs.profiler import check_finite
-from deeplearning4j_tpu.obs.registry import get_registry, record_device_memory
+from deeplearning4j_tpu.obs.registry import (
+    get_registry, record_device_memory, train_loop_metrics)
 from deeplearning4j_tpu.resilience import faults
 from deeplearning4j_tpu.train import step_cache
 from deeplearning4j_tpu.train import updaters as updater_mod
@@ -95,7 +97,9 @@ def make_loss_fn(net, with_carries: bool = False, train: bool = True):
             out, new_state, score_array, new_carries = net._forward_impl(
                 params, state, features, carries, train=train, rng=rng,
                 mask=features_mask, labels=labels)
-            loss = _score(params, state, score_array, features_mask, labels_mask)
+            with jax.named_scope("loss"):
+                loss = _score(params, state, score_array, features_mask,
+                              labels_mask)
             return loss, (new_state, new_carries)
     else:
         def loss_fn(params, state, features, labels, features_mask,
@@ -103,7 +107,9 @@ def make_loss_fn(net, with_carries: bool = False, train: bool = True):
             out, new_state, score_array = net._forward(
                 params, state, features, train=train, rng=rng,
                 mask=features_mask, labels=labels)
-            loss = _score(params, state, score_array, features_mask, labels_mask)
+            with jax.named_scope("loss"):
+                loss = _score(params, state, score_array, features_mask,
+                              labels_mask)
             return loss, new_state
 
     return loss_fn
@@ -118,19 +124,21 @@ def make_tbptt_step(net, tx, opt_state_shardings=None):
     loss_fn = make_loss_fn(net, with_carries=True)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-    def step(params, state, opt_state, carries, features, labels,
-             features_mask, labels_mask, rng):
+    def tpudl_tbptt_step(params, state, opt_state, carries, features, labels,
+                         features_mask, labels_mask, rng):
         (loss, (new_state, new_carries)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, state, carries, features, labels,
                                    features_mask, labels_mask, rng)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        if opt_state_shardings is not None:   # ZeRO-1 placement pin
-            opt_state = jax.lax.with_sharding_constraint(
-                opt_state, opt_state_shardings)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            if opt_state_shardings is not None:   # ZeRO-1 placement pin
+                opt_state = jax.lax.with_sharding_constraint(
+                    opt_state, opt_state_shardings)
+            params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                            updates)
         return params, new_state, opt_state, new_carries, loss
 
-    return step
+    return tpudl_tbptt_step
 
 
 def make_train_step(net, tx, with_stats: bool = False,
@@ -155,24 +163,30 @@ def make_train_step(net, tx, with_stats: bool = False,
 
     # donate params/state/opt_state buffers: the step's outputs reuse their
     # HBM (essential for large models — no 2x parameter memory)
+    # The function's name is the program's on the device trace's XLA Modules
+    # line (jit_tpudl_train_step); the scopes (each layer's in the forward
+    # loop, "loss", "optimizer") are on its operations.
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-    def step(params, state, opt_state, features, labels, features_mask,
-             labels_mask, rng):
+    def tpudl_train_step(params, state, opt_state, features, labels,
+                         features_mask, labels_mask, rng):
         (loss, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, state, features, labels, features_mask, labels_mask, rng)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        if opt_state_shardings is not None:
-            opt_state = jax.lax.with_sharding_constraint(
-                opt_state, opt_state_shardings)
-        new_params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            if opt_state_shardings is not None:
+                opt_state = jax.lax.with_sharding_constraint(
+                    opt_state, opt_state_shardings)
+            new_params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                                updates)
         if with_stats:
-            stats = {"params": _layer_stats(new_params),
-                     "gradients": _layer_stats(grads),
-                     "updates": _layer_stats(updates)}
+            with jax.named_scope("stats"):
+                stats = {"params": _layer_stats(new_params),
+                         "gradients": _layer_stats(grads),
+                         "updates": _layer_stats(updates)}
             return new_params, new_state, opt_state, loss, stats
         return new_params, new_state, opt_state, loss
 
-    return step
+    return tpudl_train_step
 
 
 def make_eval_step(net):
@@ -254,6 +268,10 @@ class Trainer:
         self._stats_listeners = [l for l in self.bus.listeners
                                  if getattr(l, "wants_model_stats", False)]
         self._compiled = False   # first step through a jit boundary = compile
+        # host seconds of the current iteration inside the jitted call(s)
+        # and inside listeners; step_batch zeroes and observes them
+        self._dispatch_s = self._read_s = 0.0
+        self._step_metrics = None   # (registry, handles): see _metrics()
         # process-level step-cache identity; None (per-layer updaters,
         # frozen layers, unserializable conf) = build per instance
         self._cache_sig = None
@@ -558,6 +576,28 @@ class Trainer:
             net.params_, net.state_, batch.features, batch.labels,
             fmask, lmask)
 
+    def _dispatch(self, step_fn, *args):
+        """The jitted step's call and nothing else: the ``step.dispatch``
+        span, and the time ``tpudl_train_dispatch_seconds`` takes.  It
+        returns when the program is enqueued, not when the device is done."""
+        t0 = time.perf_counter()
+        with tracing.span("step.dispatch"):
+            out = step_fn(*args)
+        self._dispatch_s += time.perf_counter() - t0
+        return out
+
+    @contextlib.contextmanager
+    def _reading(self, **attributes):
+        """Around listeners, where the loop may block on a device value:
+        the ``step.read`` span, and the time ``tpudl_train_read_seconds``
+        takes."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.span("step.read", **attributes):
+                yield
+        finally:
+            self._read_s += time.perf_counter() - t0
+
     def fit_batch(self, batch, rng, prepared: bool = False) -> float:
         """One optimization step on one batch; returns host-side loss.
         ``prepared=True`` marks a batch the DeviceFeeder already staged
@@ -605,15 +645,17 @@ class Trainer:
                     lambda: make_train_step(
                         net, self.tx, with_stats=True,
                         opt_state_shardings=self._opt_state_shardings))
-            params, state, opt_state, loss, stats = self._stats_step(*args)
+            params, state, opt_state, loss, stats = self._dispatch(
+                self._stats_step, *args)
             # publish the fresh (non-donated) buffers BEFORE listeners run —
             # net.params_ still references donated inputs at this point
             net.params_, net.state_, net.opt_state = params, state, opt_state
-            for listener in sampling:
-                listener.stats_ready(net, net.iteration, net.epoch,
-                                     float(loss), stats)
+            with self._reading(stats=True):
+                for listener in sampling:
+                    listener.stats_ready(net, net.iteration, net.epoch,
+                                         float(loss), stats)
         else:
-            params, state, opt_state, loss = self._step(*args)
+            params, state, opt_state, loss = self._dispatch(self._step, *args)
         net.params_, net.state_, net.opt_state = params, state, opt_state
         self._last_step_fn = self._stats_step if sampling else self._step
         self._last_step_calls = 1
@@ -690,7 +732,8 @@ class Trainer:
                         (net.params_, net.state_, net.opt_state, carries,
                          seg.features, seg.labels, seg.features_mask,
                          seg.labels_mask, seg_rng))
-            params, state, opt_state, carries, loss = self._tbptt_step(
+            params, state, opt_state, carries, loss = self._dispatch(
+                self._tbptt_step,
                 net.params_, net.state_, net.opt_state, carries,
                 seg.features, seg.labels, seg.features_mask,
                 seg.labels_mask, seg_rng)
@@ -709,104 +752,123 @@ class Trainer:
             check_finite(net.params_, "params after tBPTT step")
         return loss
 
+    def _loop_metrics(self):
+        """The loop's counter and histogram handles, looked up once per
+        registry (tests swap it), not by name every step."""
+        reg = get_registry()
+        if self._step_metrics is None or self._step_metrics[0] is not reg:
+            self._step_metrics = (reg, train_loop_metrics(reg))
+        return self._step_metrics[1]
+
     def step_batch(self, batch, rng):
         """One training iteration with full semantics: tBPTT routing,
         score tracking, listener dispatch, iteration counter.  Used by
         ``fit`` and by external epoch drivers (EarlyStoppingTrainer).
 
-        Observability: emits a ``step`` span (device-sync time split out,
-        HBM gauges sampled) and feeds the metrics registry.  With tracing
-        OFF the step stays sync-free — the latency histogram then records
-        dispatch wall time only."""
+        Observability: a ``step`` span over the whole iteration with two
+        children, ``step.dispatch`` (the jitted call, :meth:`_dispatch`)
+        and ``step.read`` (the listeners, where the loop may block on a
+        device value), and a histogram at each of the three boundaries
+        (``tpudl_train_iteration_seconds``, ``_dispatch_``, ``_read_``).
+        Nothing here syncs the device, tracing on or off: all of it is
+        host time, and the device's time per step comes from a profiler
+        trace (``obs.profiler.timeline``)."""
         net = self.net
+        metrics = self._loop_metrics()
+        self._dispatch_s = self._read_s = 0.0
         # the step clock starts BEFORE the fault site: an injected delay
         # models a slow step, so it must show in the reported step time
         # (the federated straggler check judges exactly that number)
         t0 = time.perf_counter()
-        # fault-injection site: a "crash" here models preemption BEFORE
-        # the step commits — the last durable checkpoint stays authoritative
-        faults.fire("trainer.step", index=net.iteration)
-        flight_recorder.progress("trainer.step")
-        fed = isinstance(batch, FedBatch)
-        data = batch.batch if fed else batch
-        first = (data.features[0] if isinstance(data.features, (list, tuple))
-                 else data.features)
-        # listeners and the examples counter must see the REAL example
-        # count, not the bucket-padded shape
-        n_examples = batch.n_examples if fed else int(first.shape[0])
-        compile_step = not self._compiled
-        traces_before = step_cache.jit_cache_entries(*self._jit_step_fns())
         with tracing.span("step", iteration=net.iteration,
                           epoch=net.epoch) as sp:
+            # fault-injection site: a "crash" here models preemption
+            # BEFORE the step commits — the last durable checkpoint stays
+            # authoritative
+            faults.fire("trainer.step", index=net.iteration)
+            flight_recorder.progress("trainer.step")
+            fed = isinstance(batch, FedBatch)
+            data = batch.batch if fed else batch
+            first = (data.features[0]
+                     if isinstance(data.features, (list, tuple))
+                     else data.features)
+            # listeners and the examples counter must see the REAL example
+            # count, not the bucket-padded shape
+            n_examples = batch.n_examples if fed else int(first.shape[0])
+            if not self._compiled:
+                sp.set_attribute("compile", True)
+            traces_before = step_cache.jit_cache_entries(
+                *self._jit_step_fns())
             if net.conf.backprop_type == "tbptt" \
                     and not isinstance(data.features, (list, tuple)) \
                     and first.ndim == 3:
                 loss = self._fit_tbptt(data, rng, prepared=fed)
             else:
                 loss = self.fit_batch(data, rng, prepared=fed)
-            if tracing.get_tracer().enabled:
-                loss = tracing.device_sync(loss)
-                sp.set_attribute("score", float(loss))
-                if compile_step:
-                    sp.set_attribute("compile", True)
-                hbm = record_device_memory()
-                if hbm and "bytes_in_use" in hbm:
-                    sp.set_attribute("hbm_bytes_in_use", hbm["bytes_in_use"])
-                get_registry().gauge("tpudl_train_last_score").set(float(loss))
-        dt = time.perf_counter() - t0
-        self._compiled = True
-        # recompile guard measurement: new traced programs across this
-        # step (first compile counts too; a shared step-cache hit does
-        # not — the program already existed)
-        retraced = (step_cache.jit_cache_entries(*self._jit_step_fns())
-                    - traces_before)
-        reg = get_registry()
-        if retraced > 0:
-            reg.counter("tpudl_train_recompiles_total").inc(retraced)
-            reg.gauge("tpudl_train_compile_seconds").set(dt)
-        else:
-            reg.histogram("tpudl_train_step_seconds").observe(dt)
-            # steady-state step: self-report MFU / HBM utilization against
-            # the program's cost_analysis facts (compile steps would lie —
-            # their wall time is dominated by XLA, not execution)
-            costmodel.observe_step(self._last_step_fn, dt,
-                                   calls=self._last_step_calls,
-                                   sig=getattr(self, "_last_step_sig", None))
-        reg.counter("tpudl_train_steps_total").inc()
-        reg.counter("tpudl_train_examples_total").inc(n_examples)
-        if retraced == 0 and not self._bake_scheduled \
-                and get_config().artifact_bake \
-                and (self._bake_args is not None
-                     or self._tbptt_bake_args is not None):
-            # compiles have settled: bake this trainer's programs ONCE
-            # on the background worker, so every checkpoint written
-            # from here on carries warm-restart artifacts
-            self._bake_scheduled = True
-            from deeplearning4j_tpu.train import artifact_store
-            artifact_store.schedule_bake(self.bake_artifacts)
-        flight_recorder.record("step", iteration=net.iteration,
-                               epoch=net.epoch,
-                               duration_ms=round(dt * 1e3, 3),
-                               examples=n_examples,
-                               compile=bool(retraced))
-        flight_recorder.progress("trainer.step")
-        # fault site: a "nan" rule poisons the reported loss (numeric-
-        # blowup stand-in) so health-monitor detection runs end-to-end
-        if faults.poison("trainer.step", index=net.iteration):
-            loss = float("nan")
-        # cluster federation: stamp this worker's progress onto the
-        # coordinator's dashboard (buffer-append only — the router's
-        # background thread does the network I/O; see obs/remote.py)
-        obs_remote.notify_step(net.iteration, epoch=net.epoch,
-                               duration_s=dt, score=loss,
-                               examples=n_examples,
-                               compile=bool(retraced))
-        net._score = loss
-        for listener in self.bus.listeners:
-            if hasattr(listener, "record_batch"):
-                listener.record_batch(n_examples)
-        self.bus.dispatch("iteration_done", net, net.iteration, net.epoch, loss)
-        net.iteration += 1
+            # fault site to the dispatch's return: what the straggler
+            # check, the flight recorder and the cost model are handed
+            dt = time.perf_counter() - t0
+            self._compiled = True
+            # recompile guard measurement: new traced programs across this
+            # step (first compile counts too; a shared step-cache hit does
+            # not — the program already existed)
+            retraced = (step_cache.jit_cache_entries(*self._jit_step_fns())
+                        - traces_before)
+            if retraced > 0:
+                metrics.recompiles.inc(retraced)
+                metrics.compile_seconds.set(dt)
+            else:
+                # steady-state step: self-report MFU / HBM utilization
+                # against the program's cost_analysis facts (compile steps
+                # would lie — their wall time is dominated by XLA, not
+                # execution)
+                costmodel.observe_step(self._last_step_fn, dt,
+                                       calls=self._last_step_calls,
+                                       sig=getattr(self, "_last_step_sig",
+                                                   None))
+            metrics.steps.inc()
+            metrics.examples.inc(n_examples)
+            if retraced == 0 and not self._bake_scheduled \
+                    and get_config().artifact_bake \
+                    and (self._bake_args is not None
+                         or self._tbptt_bake_args is not None):
+                # compiles have settled: bake this trainer's programs ONCE
+                # on the background worker, so every checkpoint written
+                # from here on carries warm-restart artifacts
+                self._bake_scheduled = True
+                from deeplearning4j_tpu.train import artifact_store
+                artifact_store.schedule_bake(self.bake_artifacts)
+            flight_recorder.record("step", iteration=net.iteration,
+                                   epoch=net.epoch,
+                                   duration_ms=round(dt * 1e3, 3),
+                                   examples=n_examples,
+                                   compile=bool(retraced))
+            flight_recorder.progress("trainer.step")
+            # fault site: a "nan" rule poisons the reported loss (numeric-
+            # blowup stand-in) so health-monitor detection runs end-to-end
+            if faults.poison("trainer.step", index=net.iteration):
+                loss = float("nan")
+            # cluster federation: stamp this worker's progress onto the
+            # coordinator's dashboard (buffer-append only — the router's
+            # background thread does the network I/O; see obs/remote.py)
+            obs_remote.notify_step(net.iteration, epoch=net.epoch,
+                                   duration_s=dt, score=loss,
+                                   examples=n_examples,
+                                   compile=bool(retraced))
+            net._score = loss
+            with self._reading():
+                for listener in self.bus.listeners:
+                    if hasattr(listener, "record_batch"):
+                        listener.record_batch(n_examples)
+                self.bus.dispatch("iteration_done", net, net.iteration,
+                                  net.epoch, loss)
+            net.iteration += 1
+        if retraced == 0:
+            # the three together, so that their sums subtract: iteration
+            # less dispatch less read is the loop's own python
+            metrics.iteration.observe(time.perf_counter() - t0)
+            metrics.dispatch.observe(self._dispatch_s)
+            metrics.read.observe(self._read_s)
         return loss
 
     def bake_artifacts(self) -> int:
@@ -982,7 +1044,6 @@ class Trainer:
             from deeplearning4j_tpu.obs.profiler import trace as profiler_trace
             profile_ctx = profiler_trace(cfg.trace_dir)
         else:
-            import contextlib
             profile_ctx = contextlib.nullcontext()
         with profile_ctx:
             with tracing.span("fit", epochs=epochs, **attrs):
@@ -1023,6 +1084,9 @@ class Trainer:
                         # listener-bus info dict
                         get_registry().histogram(
                             "tpudl_train_epoch_seconds").observe(epoch_s)
+                        # the HBM gauges, once an epoch: memory_stats()
+                        # asks the allocator, not the device
+                        record_device_memory()
                         info = {"epoch_time_s": epoch_s,
                                 "batches": n_batches, "score": net._score}
                         self.bus.dispatch("on_epoch_end", net, net.epoch, info)

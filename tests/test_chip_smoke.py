@@ -71,5 +71,5 @@ def test_chip_smoke_phases_at_tiny_sizes(tmp_path):
             # the train step child A's ``net.fit`` compiled is found again
             # by child B's ``fit_batch``: another call stack, the same key
             steps = [f for f in os.listdir(tmp_path / "cache")
-                     if f.startswith("jit_step-")]
+                     if f.startswith("jit_tpudl_train_step-")]
             assert len(steps) == 1, steps

@@ -270,6 +270,60 @@ def test_feeder_producer_failure_hygiene():
     assert threading.active_count() <= before, "feeder thread leaked"
 
 
+def test_feeder_spans_cover_their_work_on_the_thread_that_does_it(registry):
+    """``feed.source`` and ``feed.stage`` on the producer's thread, each
+    as long as the work it names (a slow iterator, a slow placement, a
+    retried stage), ``feed.wait`` on the consumer's, and a histogram at
+    each of the three boundaries."""
+    from deeplearning4j_tpu.obs import tracing
+    from deeplearning4j_tpu.resilience import faults
+
+    def slow_source():
+        for i in range(3):
+            time.sleep(0.02)
+            yield DataSet(*_mlp_data(4, seed=i))
+
+    def slow_place(batch):
+        time.sleep(0.03)
+        return batch
+
+    t = tracing.Tracer(enabled=True)
+    with tracing.use_tracer(t), tracing.span("epoch") as epoch, \
+            faults.inject("feeder.stage@1:error"):    # one retried attempt
+        fed = list(DeviceFeeder(slow_place, bucketing=False, depth=1)
+                   .feed(slow_source()))
+    assert len(fed) == 3
+    me = threading.current_thread().name
+    sources = [s for s in t.find("feed.source")
+               if not s.attributes.get("exhausted")]
+    stages, waits = t.find("feed.stage"), t.find("feed.wait")
+    assert len(sources) == len(stages) == 3
+    assert len(waits) == 4                 # the last one finds the end
+    for s in sources + stages:
+        assert s.thread == "tpudl-device-feeder" and s.thread != me
+        assert s.parent_id == epoch.span_id and s.trace_id == epoch.trace_id
+    assert all(s.duration_s >= 0.02 for s in sources)
+    assert all(s.duration_s >= 0.03 for s in stages)
+    assert [s.attributes["n_examples"] for s in stages] == [4, 4, 4]
+    # the retry (fault, back-off, second placement) is inside its stage
+    retried = max(stages, key=lambda s: s.duration_s)
+    attempts = [s for s in t.find("retry_attempt")
+                if s.parent_id == retried.span_id]
+    assert len(attempts) == 2 and retried.duration_s >= 0.05
+    for s in waits:
+        assert s.thread == me and s.parent_id == epoch.span_id
+        assert s.end_ns > s.start_ns
+    # the consumer waited for the first batch: source + stage at least
+    assert waits[0].duration_s >= 0.05
+    assert waits[0].attributes["wait_ms"] == pytest.approx(
+        waits[0].duration_s * 1e3, abs=1.0)
+    for name, least in (("tpudl_data_source_seconds", 0.06),
+                        ("tpudl_data_stage_seconds", 0.09),
+                        ("tpudl_data_etl_wait_seconds", 0.05)):
+        h = registry.histogram(name)
+        assert h.count == 3 and h.sum >= least, name
+
+
 def test_bucket_helpers():
     assert choose_bucket(7, (32, 64)) == 32
     assert choose_bucket(33, (32, 64)) == 64
@@ -404,10 +458,10 @@ def test_tpu307_clean_cases(tmp_path):
 @pytest.fixture
 def cache_dir_restored():
     prev = (jax.config.jax_compilation_cache_dir,
-            jax.config.jax_include_full_tracebacks_in_locations)
+            jax.config.jax_traceback_in_locations_limit)
     yield
     jax.config.update("jax_compilation_cache_dir", prev[0])
-    jax.config.update("jax_include_full_tracebacks_in_locations", prev[1])
+    jax.config.update("jax_traceback_in_locations_limit", prev[1])
 
 
 def test_compile_cache_placed_from_outside_is_untouched(
@@ -434,5 +488,22 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch,
     assert jax.config.jax_compilation_cache_dir == want
     assert config_mod.place_compile_cache() == want     # not pid/time made
     # cache keys of programs that hold a Pallas kernel must not depend on
-    # the Python call stack that traced them
-    assert not jax.config.jax_include_full_tracebacks_in_locations
+    # the Python call stack that traced them: one frame, the innermost
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    # ... and a named scope must still reach the operation's name in the
+    # compiled program, where the device trace reads it
+    import jax.numpy as jnp
+
+    def layer(x):
+        with jax.named_scope("res2_0_a_conv"):
+            return jnp.tanh(x)
+
+    def caller(x):
+        return layer(x)
+    assert jax.config.jax_include_full_tracebacks_in_locations
+    lowered = jax.jit(caller).lower(jnp.ones((4,)))
+    text = lowered.as_text(debug_info=True)
+    assert 'loc("jit(caller)/res2_0_a_conv/tanh"' in text
+    assert '"caller"' not in text.split("#loc", 1)[1]   # no outer frame
+    assert 'op_name="jit(caller)/res2_0_a_conv/tanh"' in \
+        lowered.compile().as_text()

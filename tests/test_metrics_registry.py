@@ -211,7 +211,7 @@ def test_standard_metrics_install_and_lint(registry):
     from deeplearning4j_tpu.obs.selfcheck import metric_lint
     installed = install_standard_metrics(registry)
     assert "tpudl_train_steps_total" in installed
-    assert "tpudl_train_step_seconds" in installed
+    assert "tpudl_train_dispatch_seconds" in installed
     assert metric_lint(registry) == []
     # a rogue counter without _total is flagged
     registry._metrics["tpudl_test_rogue"] = Counter("tpudl_test_rogue")
@@ -260,8 +260,8 @@ def test_metrics_endpoint_after_training(tmp_path):
     finally:
         server.stop()
     assert "tpudl_train_steps_total" in body
-    assert 'tpudl_train_step_seconds_bucket{le="+Inf"}' in body
-    assert "tpudl_train_step_seconds_count" in body
+    assert 'tpudl_train_dispatch_seconds_bucket{le="+Inf"}' in body
+    assert "tpudl_train_dispatch_seconds_count" in body
 
 
 def test_metrics_writer_feeds_registry(tmp_path):

@@ -152,10 +152,7 @@ def test_device_sync_attribution(tracer):
     assert s.device_sync_s >= 0
 
 
-def test_multilayer_fit_emits_step_spans():
-    """Smoke: MultiLayerNetwork.fit under tracing produces nested
-    fit → epoch → step spans with model attrs (acceptance criterion)."""
-    from deeplearning4j_tpu.data import datasets
+def _mlp():
     from deeplearning4j_tpu.nn import InputType, NeuralNetConfiguration
     from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
@@ -166,12 +163,46 @@ def test_multilayer_fit_emits_step_spans():
             .layer(DenseLayer(n_out=8, activation="relu"))
             .layer(OutputLayer(n_out=10, activation="softmax", loss="mcxent"))
             .set_input_type(InputType.feed_forward(784)).build())
-    net = MultiLayerNetwork(conf).init()
-    it = datasets.mnist(batch_size=64, train=True, n_synthetic=192)
+    return MultiLayerNetwork(conf).init()
 
+
+def _mnist():
+    from deeplearning4j_tpu.data import datasets
+    return datasets.mnist(batch_size=64, train=True, n_synthetic=192)
+
+
+def _bert_batches(n=3, batch=2, seq=8, vocab=50):
+    rng = np.random.default_rng(0)
+    return [{"input_ids": rng.integers(0, vocab, (batch, seq), np.int32),
+             "labels": rng.integers(0, vocab, (batch, seq), np.int32),
+             "label_weights": (rng.random((batch, seq)) < 0.3).astype(
+                 np.float32)} for _ in range(n)]
+
+
+def _tiny_bert():
+    from deeplearning4j_tpu.models.bert import BertConfig, BertForMaskedLM
+    return BertForMaskedLM(BertConfig(
+        vocab_size=50, hidden_size=16, num_layers=2, num_heads=2,
+        intermediate_size=32, max_position=8), seed=3)
+
+
+N_STEPS = 6          # 192/64 MNIST batches, or 3 BERT batches, x 2 epochs
+
+
+def _fit(which):
+    if which == "trainer":
+        _mlp().fit(_mnist(), epochs=2)
+    else:
+        _tiny_bert().fit(_bert_batches(), epochs=2)
+
+
+def test_multilayer_fit_emits_step_spans():
+    """Smoke: MultiLayerNetwork.fit under tracing produces nested
+    fit → epoch → step spans with model attrs (acceptance criterion)."""
+    net = _mlp()
     t = tracing.Tracer(enabled=True)
     with tracing.use_tracer(t):
-        net.fit(it, epochs=2)
+        net.fit(_mnist(), epochs=2)
 
     fits = t.find("fit")
     epochs = t.find("epoch")
@@ -184,6 +215,306 @@ def test_multilayer_fit_emits_step_spans():
     assert fits[0].attributes["model"] == "MultiLayerNetwork"
     assert fits[0].attributes["params"] == net.num_params()
     assert steps[0].attributes.get("compile") is True
-    assert all("score" in s.attributes for s in steps)
-    # tracing path syncs the loss → scores are real floats
-    assert all(np.isfinite(s.attributes["score"]) for s in steps[1:])
+    assert not any(s.attributes.get("compile") for s in steps[1:])
+    # a span never reads the loss: nothing on it needs the device
+    assert all(set(s.attributes) <= {"iteration", "epoch", "compile"}
+               for s in steps)
+    assert all(s.device_sync_s == 0 for s in steps)
+
+
+@pytest.mark.parametrize("which", ["trainer", "bert"])
+def test_fit_emits_the_whole_span_tree(which):
+    """Every span of the table: opened before and closed after the work,
+    under the right parent, on the thread that does it."""
+    import threading
+    t, n_steps = tracing.Tracer(enabled=True), N_STEPS
+    with tracing.use_tracer(t):
+        _fit(which)
+    loop = threading.current_thread().name
+    by_id = {s.span_id: s for s in t.spans}
+    assert all(s.end_ns > s.start_ns for s in t.spans)
+    steps = t.find("step")
+    assert len(steps) == n_steps
+    for name in ("step.dispatch", "step.read"):
+        children = t.find(name)
+        assert len(children) == n_steps
+        for c in children:
+            parent = by_id[c.parent_id]
+            assert parent.name == "step" and c.thread == loop
+            assert parent.start_ns <= c.start_ns < c.end_ns <= parent.end_ns
+    for s in steps:
+        kids = sorted((c for c in t.spans if c.parent_id == s.span_id),
+                      key=lambda c: c.start_ns)
+        assert [c.name for c in kids] == ["step.dispatch", "step.read"]
+        assert kids[0].end_ns <= kids[1].start_ns
+        assert by_id[s.parent_id].name == "epoch" and s.thread == loop
+    # the feeder: wait on the loop, source and stage on the producer,
+    # all three in the epoch's trace
+    waits = [s for s in t.find("feed.wait") if "n_examples" in s.attributes]
+    assert len(waits) == n_steps
+    assert all(s.thread == loop and by_id[s.parent_id].name == "epoch"
+               and "wait_ms" in s.attributes for s in waits)
+    stages = t.find("feed.stage")
+    sources = [s for s in t.find("feed.source")
+               if not s.attributes.get("exhausted")]
+    assert len(stages) == len(sources) == n_steps
+    for s in stages + sources:
+        assert s.thread == "tpudl-device-feeder" != loop
+        assert by_id[s.parent_id].name == "epoch"
+        assert s.trace_id == steps[0].trace_id
+    assert not t.find("feed")                 # the zero-length span went
+
+
+def test_tracing_never_syncs_and_changes_no_loss(monkeypatch):
+    """Turning the tracer on must not change the program it traces: no
+    device_sync, no block_until_ready on the step path, the same losses."""
+    import jax
+    from deeplearning4j_tpu.obs import CollectScoresListener
+    calls = {"device_sync": 0, "block_until_ready": 0}
+    real_sync, real_block = tracing.device_sync, jax.block_until_ready
+
+    def counted(name, fn):
+        def wrapper(*a, **k):
+            calls[name] += 1
+            return fn(*a, **k)
+        return wrapper
+    losses = {}
+    for traced in (False, True):
+        seen = CollectScoresListener()
+        t = tracing.Tracer(enabled=traced)
+        with tracing.use_tracer(t):
+            monkeypatch.setattr(tracing, "device_sync",
+                                counted("device_sync", real_sync))
+            monkeypatch.setattr(jax, "block_until_ready",
+                                counted("block_until_ready", real_block))
+            _mlp().fit(_mnist(), epochs=2, listeners=[seen])
+            monkeypatch.undo()
+        losses[traced] = seen.scores
+        assert bool(t.spans) == traced
+    assert calls == {"device_sync": 0, "block_until_ready": 0}
+    assert len(losses[True]) == 6 and losses[True] == losses[False]
+
+
+_LOOP_HISTOGRAMS = ["tpudl_train_iteration_seconds",
+                    "tpudl_train_dispatch_seconds",
+                    "tpudl_train_read_seconds"]
+_FEED_HISTOGRAMS = ["tpudl_data_source_seconds", "tpudl_data_stage_seconds",
+                    "tpudl_data_etl_wait_seconds"]
+
+
+@pytest.mark.parametrize("which", ["trainer", "bert"])
+def test_histograms_grow_by_the_steps_with_tracing_off(which):
+    """The counters sit at the spans' boundaries and are always on; the
+    loop's three leave the compile step out, together, so that their sums
+    subtract."""
+    from deeplearning4j_tpu.obs.registry import (MetricsRegistry,
+                                                 get_registry, set_registry)
+    prev = set_registry(MetricsRegistry())
+    try:
+        _fit(which)
+        reg = get_registry()
+        assert reg.counter("tpudl_train_steps_total").value == N_STEPS
+        # 0 where an earlier test left the MLP's step in the step cache
+        compiles = int(reg.counter("tpudl_train_recompiles_total").value)
+        assert compiles in (0, 1)
+        assert reg.counter("tpudl_train_examples_total").value == (
+            384 if which == "trainer" else 12)
+        for name in _LOOP_HISTOGRAMS:
+            assert reg.histogram(name).count == N_STEPS - compiles, name
+        for name in _FEED_HISTOGRAMS:
+            assert reg.histogram(name).count == N_STEPS, name
+        own = (reg.histogram("tpudl_train_iteration_seconds").sum
+               - reg.histogram("tpudl_train_dispatch_seconds").sum
+               - reg.histogram("tpudl_train_read_seconds").sum)
+        assert own > 0
+        assert "tpudl_train_step_seconds" not in reg.names()
+    finally:
+        set_registry(prev)
+
+
+def test_span_clock_is_unix_nanoseconds(tracer):
+    import threading
+    import time
+    before = time.time_ns()
+    with tracing.span("a") as s:
+        time.sleep(0.002)
+    after = time.time_ns()
+    assert before <= s.start_ns < s.end_ns <= after + 1_000_000
+    assert s.end_ns - s.start_ns >= 2_000_000
+    assert s.start_s == s.start_ns / 1e9 and s.end_s == s.end_ns / 1e9
+    assert s.thread == threading.current_thread().name
+    d = s.to_dict()
+    assert (d["start_ns"], d["end_ns"], d["thread"]) == (
+        s.start_ns, s.end_ns, s.thread)
+
+
+def test_self_intervals_take_same_thread_children_out():
+    def span(name, span_id, parent, a, b, tid=1, thread="loop"):
+        return {"name": name, "span_id": span_id, "parent_id": parent,
+                "start_ns": a, "end_ns": b, "tid": tid, "thread": thread}
+    got = sorted(tracing.self_intervals([
+        span("step", "s", None, 0, 100),
+        span("step.dispatch", "d", "s", 10, 30),
+        span("step.read", "r", "s", 60, 90),
+        span("feed.stage", "f", "s", 20, 80, tid=2, thread="feeder"),
+        {**span("open", "o", "s", 95, None)}]))
+    assert got == [(0, 10, "loop", "step"),
+                   (10, 30, "loop", "step>step.dispatch"),
+                   (20, 80, "feeder", "feed.stage"), (30, 60, "loop", "step"),
+                   (60, 90, "loop", "step>step.read"),
+                   (90, 100, "loop", "step")]
+
+
+# ---- the join with a device trace (obs.profiler.timeline)
+
+SMALL_TRACE = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "tests", "small_trace.xplane.pb")
+SMALL_TRACE_START_NS = 1790762435962424595      # its profile_start_time
+
+
+def test_timeline_names_the_gaps_spans_cover_and_no_other():
+    """small_trace.xplane.pb (recorded on a v5e in PR 26, read only): six
+    90 us programs with device gaps of 41.2, 21.9 and 21.0 ms at 68.0, 46.0
+    and 110.0 ms.  Synthetic spans over the first two name those two; the
+    third stays unnamed."""
+    from deeplearning4j_tpu.obs.profiler import timeline
+
+    def span(name, span_id, parent, a_ms, b_ms, tid=1, thread="MainThread"):
+        return {"name": name, "span_id": span_id, "parent_id": parent,
+                "tid": tid, "thread": thread,
+                "start_ns": SMALL_TRACE_START_NS + int(a_ms * 1e6),
+                "end_ns": SMALL_TRACE_START_NS + int(b_ms * 1e6)}
+    spans = [span("step", "s1", None, 68.0, 109.5),
+             span("step.read", "r1", "s1", 68.5, 100.5),
+             span("feed.wait", "w1", None, 45.5, 59.0),
+             span("feed.stage", "g1", None, 40.0, 58.0, tid=2,
+                  thread="tpudl-device-feeder")]
+    got = timeline(SMALL_TRACE, spans)
+    assert got["profile_start_time_ns"] == SMALL_TRACE_START_NS
+    gaps = got["gaps"]
+    assert [round(g["ms"], 1) for g in gaps[:3]] == [41.2, 21.9, 21.0]
+    # the longest: step.read's own time covers 32 of its 41.2 ms, step's
+    # own time (read taken out) the rest
+    assert [s[:2] for s in gaps[0]["spans"]] == [
+        ["step>step.read", "MainThread"], ["step", "MainThread"]]
+    assert gaps[0]["spans"][0][2] == pytest.approx(0.777, abs=2e-3)
+    assert sum(s[2] for s in gaps[0]["spans"]) == pytest.approx(1.0, abs=2e-3)
+    # the second: the loop waits while the producer stages
+    assert [s[:2] for s in gaps[1]["spans"]] == [
+        ["feed.wait", "MainThread"], ["feed.stage", "tpudl-device-feeder"]]
+    assert gaps[2]["spans"] == []
+    covered = 41.17 + (59.0 - 46.006)
+    assert got["idle_ms_in_gaps_over_1ms"] == pytest.approx(84.09, abs=0.01)
+    assert got["named_idle_share"] == pytest.approx(covered / 84.09, abs=2e-3)
+    # device time per executed program, from the XLA Modules line
+    assert got["steps"] == {"jit__lambda": {
+        "count": 6, "mean_ms": pytest.approx(0.090218, rel=1e-4),
+        "max_ms": pytest.approx(0.09022, rel=1e-4)}}
+    # the recorded lambda had no named scope: its op name is read from the
+    # event metadata all the same
+    assert got["scopes"][0][0] == "(unscoped)" and got["scoped_share"] == 0.0
+    assert timeline(SMALL_TRACE, [])["named_idle_share"] == 0.0
+
+
+def test_scope_of_an_op_name():
+    from deeplearning4j_tpu.obs.profiler import _op_names, _scope
+    assert _scope("jit(tpudl_train_step)/jvp(res2_0_a_conv)/"
+                  "conv_general_dilated") == "res2_0_a_conv"
+    assert _scope("jit(tpudl_train_step)/jit(main)/transpose(jvp("
+                  "res2_0_a_conv))/conv_general_dilated:") == "res2_0_a_conv bwd"
+    assert _scope("jit(tpudl_bert_mlm_step)/jvp(encoder_3)/attention/"
+                  "softmax/exp") == "encoder_3/attention"
+    assert _scope("jit(tpudl_train_step)/optimizer/add") == "optimizer"
+    assert _scope("jit(<lambda>)/dot_general:") == "(unscoped)"
+    assert _scope("") == "(unscoped)"
+    names = _op_names(SMALL_TRACE, "/device:TPU:0")
+    assert set(names.values()) == {"jit(<lambda>)/dot_general:"}
+    assert _op_names(SMALL_TRACE, "/device:TPU:7") == {}
+
+
+def test_profiling_with_tracing_writes_the_timeline(tmp_path):
+    """The operator's recipe (DL4J_TPU_PROFILING=1 DL4J_TPU_TRACING=1) on a
+    CPU: no device plane, so no gaps, but spans.jsonl and timeline.json
+    are written beside the trace and fit returns."""
+    from deeplearning4j_tpu.config import get_config
+    prev = get_config()
+    t = tracing.Tracer()
+    try:
+        set_config(profiling=True, tracing=True, trace_dir=str(tmp_path))
+        with tracing.use_tracer(t):
+            _mlp().fit(_mnist(), epochs=1)
+    finally:
+        set_config(profiling=prev.profiling, tracing=prev.tracing,
+                   trace_dir=prev.trace_dir)
+    names = [json.loads(line)["name"] for line in open(tmp_path / "spans.jsonl")]
+    assert names.count("step") == 3 and names[-1] == "fit"
+    with open(tmp_path / "timeline.json") as f:
+        got = json.load(f)
+    assert got["gaps"] == [] and got["named_idle_share"] is None
+    assert got["scopes"] == [] and got["scoped_share"] is None
+    # a trace jax wrote here (it names its host beside the planes) reads
+    # without a device's plane too
+    import glob
+    from deeplearning4j_tpu.obs.profiler import _op_names
+    (xplane,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+    assert _op_names(xplane, "/device:TPU:0") == {}
+    assert got["profile_start_time_ns"] <= t.find("fit")[0].start_ns + 10**9
+
+
+# ---- names on the device's operations
+
+def _lowered_text(lowered) -> str:
+    return lowered.as_text(debug_info=True)
+
+
+def test_resnet_step_carries_every_vertex_scope():
+    """The lowered tiny ResNet-50 step names itself and puts every vertex's
+    name, ``loss`` and ``optimizer`` on its operations."""
+    import jax
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.data.device_pipeline import pad_to_bucket
+    from deeplearning4j_tpu.models import resnet50
+    from deeplearning4j_tpu.obs import costmodel
+    from deeplearning4j_tpu.train.trainer import Trainer
+    net = resnet50(height=32, width=32, channels=3, num_classes=10,
+                   fused=False).init()
+    trainer = Trainer(net)
+    trainer._ensure_ready()
+    batch = DataSet(np.zeros((4, 32, 32, 3), np.float32),
+                    np.eye(10, dtype=np.float32)[:4])
+    placed = trainer._place_batch(pad_to_bucket(batch, 4)[0])
+    text = _lowered_text(trainer._step.lower(*costmodel.abstractify(
+        (net.params_, net.state_, net.opt_state, placed.features,
+         placed.labels, None, placed.labels_mask, jax.random.key(0)))))
+    assert "jit(tpudl_train_step)/" in text and "jit(step)" not in text
+    vertices = [spec.name for spec in net._topo]
+    assert len(vertices) > 100
+    missing = [v for v in vertices
+               if f"jvp({v})/" not in text and f"/{v}/" not in text]
+    assert missing == []
+    assert "transpose(jvp(res2_0_a_conv))/" in text      # the backward pass
+    assert "/loss/" in text or "(loss)" in text
+    assert "/optimizer/" in text
+
+
+def test_bert_step_carries_encoder_scopes():
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.train import updaters
+    model = _tiny_bert()
+    tx = updaters.Adam(1e-3).to_optax()
+    step = model.make_train_step(tx)
+    b = _bert_batches(1)[0]
+    text = _lowered_text(step.lower(
+        model.params, tx.init(model.params), jnp.asarray(b["input_ids"]),
+        jnp.asarray(b["labels"]), jnp.asarray(b["label_weights"]), None,
+        jax.random.key(0, impl="rbg")))
+    assert "jit(tpudl_bert_mlm_step)/" in text
+    for i in range(model.config.num_layers):
+        for part in ("attention", "ffn"):
+            assert f"jvp(encoder_{i})/{part}/" in text, (i, part)
+            assert f"transpose(jvp(encoder_{i}))/{part}/" in text, (i, part)
+    for scope in ("embeddings", "mlm_head", "loss"):
+        assert f"jvp({scope})/" in text, scope
+    assert "/optimizer/" in text
