@@ -150,3 +150,91 @@ def test_resnet50_train_step_whole_program(one_chip, monkeypatch):
     resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert resident < V5E_HBM_BYTES, mem
+
+
+def test_batchnorm_statistics_take_one_pass(one_chip):
+    """The counter that says ``BatchNormalization``'s one-pass statistics
+    engaged: two res2 bottlenecks of ``ConvolutionLayer`` +
+    ``BatchNormalization`` (batch 128, 56x56, 256-64-64-256, bf16 policy,
+    gradient of parameters and input) against the same graph with the
+    two-pass statistics written here.  ``jnp.var`` reads the activation a
+    second time after ``jnp.mean`` and autodiff adds a zero-valued
+    ``sum(x - mean)`` to the backward; the layer's sums hang on ``x``
+    alone and share one read.  Read when written: 0.907 of the bytes (9.05
+    against 9.98 GB), 80 fusions against 98."""
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    from deeplearning4j_tpu.nn.input_type import InputType
+    from deeplearning4j_tpu.nn.layers import (BatchNormalization,
+                                              ConvolutionLayer)
+
+    class TwoPass(BatchNormalization):
+        def apply(self, params, state, x, *, train=False, rng=None,
+                  mask=None):
+            axes = tuple(range(x.ndim - 1))
+            x32 = x.astype(jnp.float32)
+            mean, var = jnp.mean(x32, axis=axes), jnp.var(x32, axis=axes)
+            keep = self.decay
+            new_state = {"mean": keep * state["mean"] + (1.0 - keep) * mean,
+                         "var": keep * state["var"] + (1.0 - keep) * var}
+            scale = jax.lax.rsqrt(var + self.eps) * params["gamma"]
+            shift = params["beta"] - mean * scale
+            y = x * scale.astype(x.dtype) + shift.astype(x.dtype)
+            return (jax.nn.relu(y) if self.activation == "relu" else y,
+                    new_state)
+
+    def graph(bn_cls):
+        layers, itype = [], InputType.convolutional(56, 56, 256)
+        for n_out, kernel, act in 2 * [(64, (1, 1), "relu"),
+                                       (64, (3, 3), "relu"),
+                                       (256, (1, 1), "identity")]:
+            for layer in (ConvolutionLayer(n_out=n_out, kernel_size=kernel,
+                                           convolution_mode="same",
+                                           has_bias=False,
+                                           activation="identity"),
+                          bn_cls(activation=act)):
+                layers.append((layer, itype))
+                itype = layer.get_output_type(itype)
+
+        def init():
+            keys = jax.random.split(jax.random.key(0), len(layers))
+            return ([l.init_params(k, t) for k, (l, t) in zip(keys, layers)],
+                    [l.init_state(t) for l, t in layers])
+
+        def loss(params, x, state):
+            new_state = []
+            for at in range(0, len(layers), 6):
+                shortcut = x
+                for (layer, _), p, s in zip(layers[at:at + 6],
+                                            params[at:at + 6],
+                                            state[at:at + 6]):
+                    x, s = layer.apply(p, s, x, train=True)
+                    new_state.append(s)
+                x = jax.nn.relu(x + shortcut)
+            return jnp.sum(x.astype(jnp.float32)), new_state
+
+        return init, jax.grad(loss, argnums=(0, 1), has_aux=True)
+
+    def compiled(bn_cls):
+        init, grad = graph(bn_cls)
+        params, state = jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=one_chip),
+            jax.eval_shape(init))
+        x = jax.ShapeDtypeStruct((128, 56, 56, 256), jnp.bfloat16,
+                                 sharding=one_chip)
+        return jax.jit(grad).lower(params, x, state).compile()
+
+    def read(c):
+        return (c.cost_analysis()["bytes accessed"],
+                c.as_text().count(" fusion("))
+
+    was = dtype_policy()
+    set_dtype_policy(DTypePolicy.bf16())
+    try:
+        one_bytes, one_fusions = read(compiled(BatchNormalization))
+        two_bytes, two_fusions = read(compiled(TwoPass))
+    finally:
+        set_dtype_policy(was)
+    assert one_bytes <= 0.95 * two_bytes, (one_bytes, two_bytes)
+    assert one_fusions < two_fusions, (one_fusions, two_fusions)
