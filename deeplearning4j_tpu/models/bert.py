@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.config import dtype_policy
 from deeplearning4j_tpu.obs import tracing
 from deeplearning4j_tpu.obs.registry import train_loop_metrics
-from deeplearning4j_tpu.ops.attention import multi_head_attention
+from deeplearning4j_tpu.ops.attention import dropout, multi_head_attention
 from deeplearning4j_tpu.train import step_cache
 
 
@@ -61,13 +61,6 @@ class BertConfig:
     # the gather removes ~6·E·(T−k)/T of vocab-matmul FLOPs AND the
     # [B,T,V] f32 logits materialization (≈0.5 GB at base/seq128).
     max_predictions: int = 0
-    # fuse the per-layer Q/K/V projections into ONE [H,3H] MXU matmul
-    # (kernels concatenated at trace time; param layout keeps the TF
-    # checkpoint naming so importers are unaffected).  MEASURED SLOWER
-    # on v5e at base/seq128 (+1.5 ms/step: the per-step concat + its
-    # transposed backward outweigh the wider matmul) — default OFF,
-    # kept for wider-model experiments.
-    fused_qkv: bool = False
 
     @staticmethod
     def base() -> "BertConfig":
@@ -154,8 +147,7 @@ def _layer_norm(p, x, eps):
 def _dropout(x, rate, train, rng):
     if not train or rate <= 0.0 or rng is None:
         return x
-    keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
-    return jnp.where(keep, x / (1.0 - rate), 0.0)
+    return dropout(x, 1.0 - rate, rng)
 
 
 def encoder_layer(lp: dict, config: BertConfig, x: jnp.ndarray,
@@ -164,28 +156,18 @@ def encoder_layer(lp: dict, config: BertConfig, x: jnp.ndarray,
                   rng: Optional[jax.Array] = None) -> jnp.ndarray:
     """One transformer encoder block (bert/encoder/layer_N) — the single
     source for both :func:`encode` and :func:`pipeline_stages`."""
+    # the key benchmark/reference/bert_base.py draws the same mask on
+    drop_rng = jax.random.fold_in(rng, 3) if train and rng is not None else None
     with jax.named_scope("attention"):
-        if config.fused_qkv:
-            at = lp["attention"]
-            policy = dtype_policy()
-            cd = policy.compute_dtype
-            kernel = jnp.concatenate(
-                [at["query"]["kernel"], at["key"]["kernel"],
-                 at["value"]["kernel"]], axis=1).astype(cd)
-            bias = jnp.concatenate(
-                [at["query"]["bias"], at["key"]["bias"], at["value"]["bias"]])
-            qkv = (jnp.einsum("...i,io->...o", x.astype(cd), kernel)
-                   + bias.astype(cd)).astype(policy.output_dtype)
-            h = x.shape[-1]
-            q, k, v = qkv[..., :h], qkv[..., h:2 * h], qkv[..., 2 * h:]
-        else:
-            q = _dense(lp["attention"]["query"], x)
-            k = _dense(lp["attention"]["key"], x)
-            v = _dense(lp["attention"]["value"], x)
+        q = _dense(lp["attention"]["query"], x)
+        k = _dense(lp["attention"]["key"], x)
+        v = _dense(lp["attention"]["value"], x)
         attn = multi_head_attention(q, k, v, n_heads=config.num_heads,
                                     kv_mask=attention_mask,
                                     use_flash=config.use_flash,
-                                    flash_block=config.flash_block)
+                                    flash_block=config.flash_block,
+                                    dropout_rate=config.attention_dropout,
+                                    dropout_rng=drop_rng)
         attn = _dense(lp["attention"]["output"], attn)
         attn = _dropout(attn, config.hidden_dropout, train, rng)
         x = _layer_norm(lp["attention"]["output_layer_norm"], x + attn,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn import weights as weight_inits
+from deeplearning4j_tpu.ops.attention import dropout
 
 _LAYER_REGISTRY: dict[str, type] = {}
 
@@ -166,8 +167,7 @@ class Layer:
         p = self.dropout
         if not train or p is None or p >= 1.0 or rng is None:
             return x
-        keep = jax.random.bernoulli(rng, p, x.shape)
-        return jnp.where(keep, x / p, 0.0)
+        return dropout(x, p, rng)
 
     def regularization_penalty(self, params: dict) -> jnp.ndarray:
         """L1/L2 penalty for this layer's params (DL4J applies l2*w to the

@@ -31,6 +31,7 @@ from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, layer_from_
 from deeplearning4j_tpu.nn.layers.conv import _pair
 from deeplearning4j_tpu.nn.layers.core import OutputLayer
 from deeplearning4j_tpu.nn.layers.recurrent import Bidirectional, GravesLSTM
+from deeplearning4j_tpu.ops.attention import dropout
 
 
 def _two(v):
@@ -330,8 +331,7 @@ class SpatialDropoutLayer(Layer):
         if not train or rng is None or self.p >= 1.0:
             return x, state
         shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (x.shape[-1],)
-        keep = jax.random.bernoulli(rng, self.p, shape)
-        return jnp.where(keep, x / self.p, 0.0).astype(x.dtype), state
+        return dropout(x, self.p, rng, shape), state
 
 
 # ======================================================== locally connected

@@ -19,6 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.ops.attention import dropout
+
 _REGISTRY: dict[str, type] = {}
 
 
@@ -76,8 +78,7 @@ class DropConnect:
     apply_to_bias: bool = False
 
     def transform(self, w, rng):
-        keep = jax.random.bernoulli(rng, self.p, w.shape)
-        return jnp.where(keep, w / self.p, 0.0).astype(w.dtype)
+        return dropout(w, self.p, rng)
 
 
 @register("weight_noise")

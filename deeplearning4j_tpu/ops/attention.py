@@ -4,8 +4,8 @@ Parity with libnd4j ``dot_product_attention`` /
 ``multi_head_dot_product_attention`` (declarable ops under
 ``include/ops/declarable/generic/nn/attention/``) — the reference
 materializes the [T,T] score matrix; here the standard path is one fused
-einsum chain.  Long-sequence paths (blockwise Pallas kernel, ring
-attention over a mesh `seq` axis) land in later milestones (SURVEY.md §5.7).
+einsum chain, from ``FLASH_AUTO_SEQ_LEN`` on the blockwise Pallas kernel; ring
+attention over a mesh ``seq`` axis is ``parallel/unified.py``'s.
 """
 
 from __future__ import annotations
@@ -33,6 +33,16 @@ def _auto_flash(q, k) -> bool:
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 
+def dropout(x: jnp.ndarray, keep_prob: float, key: jax.Array,
+            shape: Optional[tuple] = None) -> jnp.ndarray:
+    """Inverted dropout in ``x``'s dtype: keep with probability ``keep_prob``,
+    scale what is kept by its inverse.  ``shape`` is the mask's, where it
+    broadcasts against ``x``.  Guards, and what the number means (DL4J's
+    retain probability, BERT's drop rate), are the callers'."""
+    keep = jax.random.bernoulli(key, keep_prob, shape or x.shape)
+    return jnp.where(keep, x / keep_prob, 0.0).astype(x.dtype)
+
+
 def dot_product_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           mask: Optional[jnp.ndarray] = None,
                           scaled: bool = True) -> jnp.ndarray:
@@ -54,7 +64,9 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          kv_mask: Optional[jnp.ndarray] = None,
                          causal: bool = False,
                          use_flash: Optional[bool] = None,
-                         flash_block: int = 0) -> jnp.ndarray:
+                         flash_block: int = 0, dropout_rate: float = 0.0,
+                         dropout_rng: Optional[jax.Array] = None
+                         ) -> jnp.ndarray:
     """Multi-head attention on pre-projected q/k/v of shape [B,T,H*Dh].
 
     ``mask``: [B,T] padding mask applied to keys (and zeroing masked query
@@ -65,13 +77,24 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     it for seq_len >= ``FLASH_AUTO_SEQ_LEN`` (1024), where the kernel is
     the measured winner; an explicit ``False`` always keeps the einsum
     chain.
+    ``dropout_rate`` > 0 with a ``dropout_rng`` drops attention
+    probabilities: one mask over [B,H,Tq,Tk] on ``softmax(scores)``.  The
+    flash kernel cannot apply it, so ``use_flash=None`` then keeps the
+    einsum chain at any length (at long sequences the [B,H,T,T]
+    probabilities exist in memory) and ``use_flash=True`` raises.  With no
+    key or rate 0 both routes compute what they do without the arguments.
     """
     b, tq, d = q.shape
+    drop = dropout_rate > 0.0 and dropout_rng is not None
+    if drop and use_flash:
+        raise ValueError(
+            "use_flash=True cannot apply dropout_rate with dropout_rng: the "
+            "flash kernel never holds the attention probabilities")
     if use_flash is None:
-        use_flash = _auto_flash(q, k)
+        use_flash = not drop and _auto_flash(q, k)
+    key_mask = mask if mask is not None else kv_mask
     if use_flash:
         from deeplearning4j_tpu.ops.pallas import flash_attention
-        key_mask = mask if mask is not None else kv_mask
         # flash_block=0: tuned defaults (1024×1024 — the optimum measured
         # on a v5e before PR 1 at both narrow and BERT-base widths; the
         # earlier 512×1024 default was 1.35-1.5× slower)
@@ -88,13 +111,14 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kh = k.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
     vh = v.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
-    key_mask = mask if mask is not None else kv_mask
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :] > 0, scores, NEG_INF)
     if causal:
         cm = jnp.tril(jnp.ones((tq, tk), dtype=bool))
         scores = jnp.where(cm[None, None], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
+    if drop:
+        weights = dropout(weights, 1.0 - dropout_rate, dropout_rng)
     out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh)
     out = out.transpose(0, 2, 1, 3).reshape(b, tq, d)
     if mask is not None and tq == tk:

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.ops import attention as _attention
+
 
 # ---------------------------------------------------------------- math
 def _norm1(x, axis=None): return jnp.sum(jnp.abs(x), axis=axis)
@@ -146,11 +148,6 @@ math = SimpleNamespace(
 
 
 # ---------------------------------------------------------------- nn
-def _dropout(key, x, keep_prob):
-    keep = jax.random.bernoulli(key, keep_prob, x.shape)
-    return jnp.where(keep, x / keep_prob, 0.0)
-
-
 nn = SimpleNamespace(
     relu=jax.nn.relu, relu6=jax.nn.relu6, elu=jax.nn.elu, selu=jax.nn.selu,
     gelu=jax.nn.gelu, silu=jax.nn.silu, swish=jax.nn.silu,
@@ -162,7 +159,7 @@ nn = SimpleNamespace(
     log_sigmoid=jax.nn.log_sigmoid,
     one_hot=jax.nn.one_hot,
     linear=lambda x, w, b=None: jnp.dot(x, w) + (b if b is not None else 0.0),
-    dropout=_dropout,
+    dropout=lambda key, x, keep_prob: _attention.dropout(x, keep_prob, key),
     layer_norm=lambda x, gamma, beta=None, eps=1e-5: (
         (x - jnp.mean(x, -1, keepdims=True))
         * lax.rsqrt(jnp.var(x, -1, keepdims=True) + eps) * gamma
@@ -191,14 +188,10 @@ nn = SimpleNamespace(
     bias_add=lambda x, b: x + b,
     xw_plus_b=lambda x, w, b: jnp.dot(x, w) + b,
     relu_layer=lambda x, w, b: jax.nn.relu(jnp.dot(x, w) + b),
+    # libnd4j dot_product_attention / multi_head_dot_product_attention
+    dot_product_attention=_attention.dot_product_attention,
+    multi_head_dot_product_attention=_attention.multi_head_attention,
 )
-
-
-# attention ops join nn (libnd4j dot_product_attention /
-# multi_head_dot_product_attention declarables)
-from deeplearning4j_tpu.ops import attention as _attention  # noqa: E402
-nn.dot_product_attention = _attention.dot_product_attention
-nn.multi_head_dot_product_attention = _attention.multi_head_attention
 
 
 # ---------------------------------------------------------------- cnn
