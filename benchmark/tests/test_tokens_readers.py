@@ -1,8 +1,7 @@
-"""The ``.tokens`` readers, which no entry of ``BENCHMARK.json`` names while
-``bert_base.mlm_s512_b32`` is left out: the tiny BERT twin (attention dropout
-0.0, where program and reference agree) is run through ``harness.run_cell``
-with the metrics a ``benchmark`` PR will list for the cell, and each reader
-finds its series in ``BertForMaskedLM.fit``'s loop."""
+"""The ``.tokens`` readers: the tiny BERT twin is run through
+``harness.run_cell`` with the cell's rate and its six ``.tokens`` metrics
+by name, and each reader finds its series in ``BertForMaskedLM.fit``'s
+loop."""
 
 import time
 
@@ -19,7 +18,7 @@ PER_LAYER = {"device_idle_pct.tokens": "%", "step_mfu_pct.tokens": "%",
 
 def _run(metrics, trace, tmp_path):
     import jax
-    config, mix = tiny.bert_base(attention_dropout=0.0)
+    config, mix = tiny.bert_base()
     return harness.run_cell(
         {"name": CELL, "chips": 1}, 2**31 + 77, 2.0, trace, config=config,
         mix=mix, limits=LOOSE, metrics=metrics,
