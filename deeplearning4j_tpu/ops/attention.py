@@ -15,6 +15,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 NEG_INF = -1e9
 
@@ -25,6 +26,10 @@ NEG_INF = -1e9
 # launch overhead
 FLASH_AUTO_SEQ_LEN = 1024
 
+# the layout the TPU's hardware generator writes a [B,H,Tq,Tk] draw in:
+# row-major, the key axis minor-most (and every backend's default)
+GENERATOR_LAYOUT = Layout(major_to_minor=(0, 1, 2, 3))
+
 
 def _auto_flash(q, k) -> bool:
     """Default flash routing for ``use_flash=None``: long sequences in a
@@ -34,12 +39,16 @@ def _auto_flash(q, k) -> bool:
 
 
 def dropout(x: jnp.ndarray, keep_prob: float, key: jax.Array,
-            shape: Optional[tuple] = None) -> jnp.ndarray:
+            shape: Optional[tuple] = None,
+            mask_layout: Optional[Layout] = None) -> jnp.ndarray:
     """Inverted dropout in ``x``'s dtype: keep with probability ``keep_prob``,
     scale what is kept by its inverse.  ``shape`` is the mask's, where it
-    broadcasts against ``x``.  Guards, and what the number means (DL4J's
-    retain probability, BERT's drop rate), are the callers'."""
+    broadcasts against ``x``; ``mask_layout`` pins the layout the mask is
+    held in (the draw is the same).  Guards, and what the number means
+    (DL4J's retain probability, BERT's drop rate), are the callers'."""
     keep = jax.random.bernoulli(key, keep_prob, shape or x.shape)
+    if mask_layout is not None:
+        keep = with_layout_constraint(keep, mask_layout)
     return jnp.where(keep, x / keep_prob, 0.0).astype(x.dtype)
 
 
@@ -83,6 +92,12 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     einsum chain at any length (at long sequences the [B,H,T,T]
     probabilities exist in memory) and ``use_flash=True`` raises.  With no
     key or rate 0 both routes compute what they do without the arguments.
+    The mask is pinned to ``GENERATOR_LAYOUT`` so that the probabilities'
+    chain is kept where the TPU's generator writes its bits and no
+    transposition of ``uint32`` bits exists in the compiled step (PERF.md,
+    PR 34); the draw itself, ``bernoulli(dropout_rng, 1 - rate,
+    [B,H,Tq,Tk])``, is what the benchmark's reference draws and must not
+    change.
     """
     b, tq, d = q.shape
     drop = dropout_rate > 0.0 and dropout_rng is not None
@@ -118,7 +133,8 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scores = jnp.where(cm[None, None], scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     if drop:
-        weights = dropout(weights, 1.0 - dropout_rate, dropout_rng)
+        weights = dropout(weights, 1.0 - dropout_rate, dropout_rng,
+                          mask_layout=GENERATOR_LAYOUT)
     out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh)
     out = out.transpose(0, 2, 1, 3).reshape(b, tq, d)
     if mask is not None and tq == tk:

@@ -364,3 +364,69 @@ def test_encode_and_pipeline_stages_agree_under_train(keyed):
         np.asarray(got), np.asarray(B.encode(
             params, dataclasses.replace(config, attention_dropout=0.0), ids,
             train=True, rng=key)))
+
+
+# ------------- the mask's layout is pinned; the draw is the parent's, bit for bit
+def _under_jit(fn, q, k, v):
+    return jax.jit(fn)(q, k, v)
+
+
+def _under_checkpoint(fn, q, k, v):
+    return jax.jit(jax.checkpoint(fn))(q, k, v)
+
+
+def _under_vmap(fn, q, k, v):
+    """Two independent problems side by side; the key is closed over, so
+    each draws the un-batched call's mask."""
+    two = tuple(jnp.stack([a, a[::-1]]) for a in (q, k, v))
+    return tuple(o[0] for o in jax.jit(jax.vmap(fn))(*two))
+
+
+def _under_data_mesh(fn, q, k, v):
+    """The batch sharded over a ``data`` axis of the CPU's devices, as a
+    data-parallel step holds it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    q, k, v = (jax.device_put(a, rows) for a in (q, k, v))
+    out = jax.jit(fn, in_shardings=(rows,) * 3)(q, k, v)
+    assert all(len(o.sharding.device_set) == 4
+               for o in jax.tree_util.tree_leaves(out))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
+@pytest.mark.parametrize("under", [_under_jit, _under_checkpoint, _under_vmap,
+                                   _under_data_mesh],
+                         ids=lambda f: f.__name__[7:])
+def test_pinned_mask_is_the_parents_draw_bit_for_bit(under, dtype):
+    """``multi_head_attention`` keeps the probabilities' chain in the layout
+    the generator writes its bits in; that may move no bit of the output or
+    of the gradients to q, k, v against the chain written out with
+    ``jax.random.bernoulli(key, 0.9, (B,H,Tq,Tk))``."""
+    b, t, heads, d = 4, 16, 4, 32
+    q, k, v = _qkv(12, b, t, d, dtype)
+    key, rate = jax.random.key(21, impl="rbg"), 0.1
+    w = jnp.asarray(np.random.default_rng(13).normal(size=(b, t, d)), dtype)
+
+    def both(attend):
+        def fn(q, k, v):
+            def loss(q, k, v):
+                out = attend(q, k, v)
+                return jnp.sum((out * w).astype(jnp.float32)), out
+            grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                q, k, v)
+            return (out, *grads)
+        return fn
+
+    program = both(lambda q, k, v: multi_head_attention(
+        q, k, v, n_heads=heads, dropout_rate=rate, dropout_rng=key))
+    plain = both(lambda q, k, v: _plain_attention(
+        q, k, v, heads, rate=rate, key=key))
+    got, want = under(program, q, k, v), under(plain, q, k, v)
+    for g, r in zip(got, want):
+        _same_bits(g, r)
+    dropped = under(both(lambda q, k, v: _plain_attention(q, k, v, heads)),
+                    q, k, v)
+    assert not np.array_equal(np.asarray(got[0].astype(jnp.float32)),
+                              np.asarray(dropped[0].astype(jnp.float32)))

@@ -15,6 +15,7 @@ this one file: under xdist exactly one worker is handed it.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +106,30 @@ def test_int8_matmul(one_chip):
     compiled = _compile(fn, one_chip, ((64, 2048), jnp.bfloat16),
                         ((2048, 2048), jnp.int8), ((2048,), jnp.float32))
     assert _has_kernel(compiled)
+
+
+def test_attention_dropout_bits_are_not_transposed(one_chip):
+    """The counter that says ``multi_head_attention``'s layout pin engaged:
+    the gradient of one BERT-base attention ([32,512,768] bf16, 12 heads)
+    with the published dropout and the ``rbg`` key ``BertForMaskedLM.fit``
+    draws on.  The hardware generator writes its ``uint32`` bits row-major
+    and XLA kept the probabilities query-minor, so the compiled step held a
+    ``copy`` of the raw bits, 805 MB through HBM a layer, before the
+    one-byte comparison; with the mask pinned there is none."""
+    from deeplearning4j_tpu.ops.attention import multi_head_attention
+
+    def loss(q, k, v, key):
+        out = multi_head_attention(q, k, v, n_heads=12, dropout_rate=0.1,
+                                   dropout_rng=key)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((32, 512, 768), jnp.bfloat16)
+    key = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv,
+                    qkv, (key.shape, key.dtype)).as_text()
+    bits = r"u32\[32,12,512,512\]\{[^}]*\}"
+    assert re.search(bits + r" rng-bit-generator\(", text)
+    assert not re.findall(r"= " + bits + r" copy\(", text)
 
 
 def test_resnet50_train_step_whole_program(one_chip, monkeypatch):
