@@ -76,14 +76,6 @@ class Config:
     - ``shape_bucketing``: pad ragged tail batches up to a static bucket
       shape with mask-extension (zero loss / zero gradient padding) so
       an epoch compiles the train step once — see docs/data_pipeline.md.
-    - ``fused_conv``: lower the conv zoo's bottleneck blocks onto the
-      Pallas fused conv+BN kernels (``nn.layers.fused.FusedBottleneck``
-      / ``ops.pallas.conv_bn.matmul_bn_act``) by default — the
-      cuDNN-platform-engine analog, numerically pinned to the unfused
-      graph by the oracle-equivalence tests.  On by default
-      (``DL4J_TPU_FUSED_CONV=0`` reverts to the unfused per-layer
-      graph); an explicit ``fused=`` argument to a zoo factory always
-      wins.
     - ``artifact_store``: honor the compiled-artifact store
       (``train.artifact_store``): warm-load serialized executables from
       checkpoint zips at deploy/resume/respawn time and dispatch
@@ -123,7 +115,6 @@ class Config:
     prefetch_size: int = 2
     device_feed: bool = True
     shape_bucketing: bool = True
-    fused_conv: bool = True
     artifact_store: bool = True
     artifact_bake: bool = False
     profiling: bool = False
@@ -178,8 +169,6 @@ ENV_KNOBS: dict[str, str] = {
                             "buffering in Trainer.fit",
     "DL4J_TPU_SHAPE_BUCKETING": "config.shape_bucketing: pad ragged tail "
                                 "batches to static bucket shapes",
-    "DL4J_TPU_FUSED_CONV": "config.fused_conv: Pallas fused conv+BN "
-                           "bottleneck lowering",
     "DL4J_TPU_ARTIFACT_STORE": "config.artifact_store: warm compiled "
                                "programs from checkpoint zips",
     "DL4J_TPU_ARTIFACT_BAKE": "config.artifact_bake: background "

@@ -195,23 +195,20 @@ def _bottleneck(gb, name, in_name, filters, stride, project):
 
 def resnet50(seed: int = 123, num_classes: int = 1000, height: int = 224,
              width: int = 224, channels: int = 3, updater=None,
-             fused: bool | None = None) -> ComputationGraph:
+             fused: bool = False) -> ComputationGraph:
     """ResNet50.java parity: [3, 4, 6, 3] bottleneck stages — the BASELINE
     headline model.  NHWC + channels-last BN; stride-2 downsampling in the
     first block of stages 3-5 (v1).
 
-    ``fused`` picks the bottleneck lowering: ``True`` builds each block
-    as the single
-    :class:`~deeplearning4j_tpu.nn.layers.fused.FusedBottleneck` layer
-    (Pallas conv+BN kernels — the cuDNN-platform-engine analog),
-    ``False`` the unfused ConvolutionLayer+BatchNormalization graph.
-    ``None`` (default) follows ``config.fused_conv`` — ON by default,
-    since the fused lowering is numerically pinned to the unfused graph
-    (``remap_bottleneck_params`` + the oracle-equivalence tests) and is
-    the conv zoo's arithmetic-intensity lever (ROADMAP item 1)."""
-    if fused is None:
-        from deeplearning4j_tpu.config import get_config
-        fused = bool(get_config().fused_conv)
+    ``fused`` takes ``False`` only: the benchmark's entry still passes the
+    keyword.  ``True``, and ``None`` for "as ``config.fused_conv`` says",
+    named the Pallas conv+BN bottleneck, deleted in PR 35 (slower on the
+    chip, and its gradients near the stem were wrong: PERF.md section 7)."""
+    if fused is not False:
+        raise ValueError(
+            f"resnet50(fused={fused!r}): the Pallas conv+BN bottleneck was "
+            f"deleted in PR 35 (PERF.md section 7); the ConvolutionLayer + "
+            f"BatchNormalization graph is the only one")
     gb = (NeuralNetConfiguration.builder()
           .seed(seed)
           .updater(updater or Nesterovs(1e-1, 0.9))
@@ -233,80 +230,16 @@ def resnet50(seed: int = 123, num_classes: int = 1000, height: int = 224,
         ("res4", [256, 256, 1024], 6, (2, 2)),
         ("res5", [512, 512, 2048], 3, (2, 2)),
     ]
-    if fused:
-        from deeplearning4j_tpu.nn.layers.fused import FusedBottleneck
     for stage_name, filters, blocks, first_stride in stages:
         for i in range(blocks):
             stride = first_stride if i == 0 else (1, 1)
-            if fused:
-                gb.add_layer(f"{stage_name}_{i}",
-                             FusedBottleneck(filters=tuple(filters),
-                                             stride=stride, project=i == 0),
-                             x)
-                x = f"{stage_name}_{i}"
-            else:
-                x = _bottleneck(gb, f"{stage_name}_{i}", x, filters,
-                                stride, project=i == 0)
+            x = _bottleneck(gb, f"{stage_name}_{i}", x, filters,
+                            stride, project=i == 0)
     gb.add_layer("avgpool", GlobalPoolingLayer(pooling_type="avg"), x)
     gb.add_layer("out", OutputLayer(n_out=num_classes, activation="softmax",
                                     loss="mcxent"), "avgpool")
     gb.set_outputs("out")
     return ComputationGraph(gb.build())
-
-
-# fused-branch suffix → unfused node suffix inside one bottleneck
-_BOTTLENECK_BRANCHES = {"a": "a", "b3": "b", "c": "c", "proj": "proj"}
-
-
-def remap_bottleneck_params(params: dict, state: dict, *, to_fused: bool):
-    """Convert resnet50 param/state dicts between the unfused
-    (ConvolutionLayer+BatchNormalization per branch) and fused
-    (:class:`FusedBottleneck`) layouts, so checkpoints from either graph
-    load into the other.  1x1 conv kernels reshape between HWIO
-    ``(1, 1, Cin, Cout)`` and the fused matmul's ``(Cin, Cout)``."""
-    params, state = dict(params), dict(state)
-    if to_fused:
-        names = sorted(k[:-len("_a_conv")] for k in params
-                       if k.endswith("_a_conv") and not k.startswith("stem"))
-        for n in names:
-            fp, fs = {}, {}
-            for fb, ub in _BOTTLENECK_BRANCHES.items():
-                ck, bk = f"{n}_{ub}_conv", f"{n}_{ub}_bn"
-                if ck not in params:
-                    continue
-                W = params.pop(ck)["W"]
-                if fb != "b3":
-                    W = W.reshape(W.shape[-2], W.shape[-1])
-                bn = params.pop(bk)
-                st = state.pop(bk)
-                state.pop(ck, None)
-                fp[f"W_{fb}"] = W
-                fp[f"gamma_{fb}"], fp[f"beta_{fb}"] = bn["gamma"], bn["beta"]
-                fs[f"mean_{fb}"], fs[f"var_{fb}"] = st["mean"], st["var"]
-            for suffix in ("_add", "_out"):
-                params.pop(n + suffix, None)
-                state.pop(n + suffix, None)
-            params[n], state[n] = fp, fs
-    else:
-        names = sorted(k for k, v in params.items()
-                       if isinstance(v, dict) and "W_a" in v)
-        for n in names:
-            fp, fs = params.pop(n), state.pop(n)
-            for fb, ub in _BOTTLENECK_BRANCHES.items():
-                if f"W_{fb}" not in fp:
-                    continue
-                W = fp[f"W_{fb}"]
-                if fb != "b3":
-                    W = W.reshape(1, 1, *W.shape)
-                params[f"{n}_{ub}_conv"] = {"W": W}
-                params[f"{n}_{ub}_bn"] = {"gamma": fp[f"gamma_{fb}"],
-                                          "beta": fp[f"beta_{fb}"]}
-                state[f"{n}_{ub}_conv"] = {}
-                state[f"{n}_{ub}_bn"] = {"mean": fs[f"mean_{fb}"],
-                                         "var": fs[f"var_{fb}"]}
-            params[f"{n}_add"], state[f"{n}_add"] = {}, {}
-            params[f"{n}_out"], state[f"{n}_out"] = {}, {}
-    return params, state
 
 
 # ------------------------------------------------------------------ RNN zoo

@@ -62,7 +62,6 @@ from deeplearning4j_tpu.nn.layers.attention import (
     LearnedSelfAttentionLayer,
 )
 from deeplearning4j_tpu.nn.layers.norm import LayerNormalization, PReLULayer
-from deeplearning4j_tpu.nn.layers.fused import FusedBottleneck
 from deeplearning4j_tpu.nn.layers.extra import (
     ZeroPadding1DLayer,
     Cropping1DLayer,
@@ -116,6 +115,6 @@ __all__ = [
     "MaskZeroLayer", "GravesBidirectionalLSTM", "CenterLossOutputLayer",
     "Yolo2OutputLayer", "VariationalAutoencoder", "PrimaryCapsules",
     "CapsuleLayer", "CapsuleStrengthLayer", "RecurrentAttentionLayer",
-    "MixtureOfExperts", "FusedBottleneck",
+    "MixtureOfExperts",
     "PermuteLayer", "SeparableConvolution1D", "ConvLSTM2D",
 ]

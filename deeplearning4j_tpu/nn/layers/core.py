@@ -234,8 +234,8 @@ class BatchNormalization(Layer):
 
     Training statistics are taken in ONE pass over the activation, in
     float32 or wider: ``mean = sum(x)/n`` and
-    ``var = max(sum(x*x)/n - mean*mean, 0)`` (biased), as
-    ``FusedBottleneck`` and flax's ``BatchNorm`` take theirs.  Both sums
+    ``var = max(sum(x*x)/n - mean*mean, 0)`` (biased), as flax's
+    ``BatchNorm`` takes its own.  Both sums
     depend on ``x`` alone, so the compiler reads ``x`` once for the two
     (``jnp.mean`` then ``jnp.var`` reads it twice, and a third time in the
     backward); autodiff differentiates the expression as written.

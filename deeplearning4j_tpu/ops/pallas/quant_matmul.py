@@ -80,7 +80,7 @@ def _pick_block(m, k, n, itemsize):
             f"channel dims too large for the fused kernel")
     # between the conservative budget and the hard ceiling: fall through
     # with the smallest candidate (the estimate is conservative; Mosaic
-    # reports its own OOM if it truly doesn't fit) — conv_bn semantics
+    # reports its own OOM if it truly doesn't fit)
     return max(8, min(128, -(-m // 8) * 8))
 
 

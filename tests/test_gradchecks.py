@@ -341,12 +341,16 @@ LAYER_CASES = {
     "recurrent_attention": ([RecurrentAttentionLayer(n_out=4, activation="tanh"),
                              RNN_OUT()],
                             InputType.recurrent(3, 5), lambda: _rnn_batch(3, 3)),
-    # relu kinks are measure-zero under random inputs (as for max-pool);
-    # f64 policy routes matmul_bn_act through its exact reference path
-    "fused_bottleneck": ([FusedBottleneck(filters=(3, 3, 8), project=True),
-                          GlobalPoolingLayer(pooling_type="avg"), FF_OUT()],
-                         InputType.convolutional(6, 6, 4),
-                         lambda: _cnn_batch(6, 6, 4, 3)),
+    # one branch of the ResNet bottleneck (zoo._conv_bn); relu kinks are
+    # measure-zero under random inputs (as for max-pool)
+    "conv1x1_nobias_bn_relu": ([ConvolutionLayer(n_out=8, kernel_size=(1, 1),
+                                                 has_bias=False,
+                                                 activation="identity"),
+                                BatchNormalization(activation="relu"),
+                                GlobalPoolingLayer(pooling_type="avg"),
+                                FF_OUT()],
+                               InputType.convolutional(6, 6, 4),
+                               lambda: _cnn_batch(6, 6, 4, 3)),
     # generous capacity: no token drops, so routing is locally constant
     # and the loss is differentiable at the sampled inputs
     "mixture_of_experts": ([MixtureOfExperts(n_experts=3, hidden=6, top_k=2,

@@ -294,54 +294,6 @@ class TestRingWithFlash:
                                    rtol=2e-4, atol=2e-5)
 
 
-class TestConv3BnFused:
-    """A measurement artifact (measured slower than XLA before PR 1): the
-    3×3 conv+BN kernel must still be CORRECT."""
-
-    def _case(self, N=2, H=8, W=7, C=16):
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.normal(0, 1, (N, H, W, C)).astype(np.float32))
-        w = jnp.asarray(rng.normal(0, 0.1, (3, 3, C, C)).astype(np.float32))
-        a = jnp.asarray(rng.normal(1, 0.1, C).astype(np.float32))
-        b = jnp.asarray(rng.normal(0, 0.1, C).astype(np.float32))
-        return x, w, a, b
-
-    def test_matches_reference_with_and_without_prologue(self):
-        from deeplearning4j_tpu.ops.pallas import conv3_bn as cb
-        x, w, a, b = self._case()
-        for has_pro in (False, True):
-            y, s1, s2 = cb.conv3x3_bn_act(
-                x, w, a if has_pro else None, b if has_pro else None,
-                interpret=True)
-            yr, s1r, s2r = cb._reference(x, w, a, b, has_prologue=has_pro,
-                                         relu_in=True)
-            np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
-                                       rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(np.asarray(s1), np.asarray(s1r),
-                                       rtol=1e-4)
-            np.testing.assert_allclose(np.asarray(s2), np.asarray(s2r),
-                                       rtol=1e-4)
-
-    def test_gradients_flow_through_custom_vjp(self):
-        from deeplearning4j_tpu.ops.pallas import conv3_bn as cb
-        x, w, a, b = self._case()
-
-        def loss(x, w, a, b):
-            y, s1, s2 = cb.conv3x3_bn_act(x, w, a, b, interpret=True)
-            return y.sum() + (s1 * s1).sum() + s2.sum()
-
-        def loss_ref(x, w, a, b):
-            y, s1, s2 = cb._reference(x, w, a, b, has_prologue=True,
-                                      relu_in=True)
-            return y.sum() + (s1 * s1).sum() + s2.sum()
-
-        g = jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, a, b)
-        gr = jax.grad(loss_ref, argnums=(0, 1, 2, 3))(x, w, a, b)
-        for gi, gri in zip(g, gr):
-            np.testing.assert_allclose(np.asarray(gi), np.asarray(gri),
-                                       rtol=1e-4, atol=1e-5)
-
-
 class TestFlashAutoDefault:
     """ISSUE 11 satellite: the flash kernel is the standard BERT path —
     ``use_flash=None`` auto-enables at seq >= 1024 (explicit False
