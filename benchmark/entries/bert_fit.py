@@ -1,6 +1,9 @@
 """Entry ``bert_fit``: BERT masked-LM pre-training through
-``BertForMaskedLM.fit`` (its own jitted step behind ``DeviceFeeder``, a
-``float(loss)`` every step).  Used by the ``bert_base`` configuration.
+``BertForMaskedLM.fit`` (its own jitted step behind ``DeviceFeeder``).
+The window's listener reads the loss every ``loss_every``-th step of the
+traffic mix, whatever the loop hands it: a python float today, a device
+scalar once the loop stops reading the loss itself.  Used by the
+``bert_base`` configuration.
 
 ROADMAP R1 will move BERT onto ``Trainer``; when it removes
 ``BertForMaskedLM.fit``, a ``benchmark`` PR re-points this entry.
@@ -54,32 +57,12 @@ def _get(tree, path):
     return tree
 
 
-class _Steps:
-    """Listener: counts steps; over the first ``n_first`` it also takes
-    the optimizer's first moment after step one and the parameters'
-    change after the last.  The program has read the loss already."""
-
-    def __init__(self, entry, n_first: int = 0):
-        self.entry, self.n_first = entry, n_first
-        self.steps, self.losses = 0, []
-        self.moment = self.change = None
-
-    def iteration_done(self, model, iteration, epoch, loss):
-        self.steps += 1
-        self.losses.append(loss)
-        if self.n_first and self.steps == 1:
-            self.moment = self.entry.reader.norms(
-                probe.first_moment(model.opt_state))
-        if self.n_first and self.steps == self.n_first:
-            self.change = self.entry.reader.change(model.params)
-
-
 class Entry:
     def __init__(self, config: dict, mix: dict):
         self.config, self.mix = config, mix
         self.model = None
         self.weights = None
-        self.seen = _Steps(self)
+        self.cadence = probe.Cadence(mix["loss_every"])
 
     # ---- set-up -------------------------------------------------------------
     def build(self, weights: dict, seed: int) -> None:
@@ -153,27 +136,23 @@ class Entry:
     def first_steps(self, batches: list) -> dict:
         """The warm-up IS the first steps: the same ``fit`` and feeder the
         window uses, over batches that all differ."""
-        seen = _Steps(self, n_first=len(batches))
+        seen = probe.FirstSteps(self.reader, len(batches),
+                                lambda model: model.params)
         self._fit(iter(batches), seen)
-        factor = probe.first_gradient_factor(self.config["optimizer"])
-        return {
-            "losses": [float(x) for x in seen.losses],
-            "grad_norms": self.reader.as_dict(seen.moment, factor),
-            "delta_norms": self.reader.as_dict(seen.change),
-        }
+        return seen.readings(self.config["optimizer"])
 
     # ---- the window ---------------------------------------------------------
     def run(self, iterator) -> None:
-        self._fit(iterator, self.seen)
+        self._fit(iterator, self.cadence)
 
     def wait(self) -> None:
         jax.block_until_ready(self.model.params)
 
     def steps(self) -> int:
-        return self.seen.steps
+        return self.cadence.steps
 
     def window_losses(self) -> list:
-        return list(self.seen.losses)
+        return list(self.cadence.losses)
 
     def recompiles(self) -> float:
         """Programs the step has traced so far (1 after the warm-up; the

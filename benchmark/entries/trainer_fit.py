@@ -1,12 +1,11 @@
 """Entry ``trainer_fit``: a zoo network trained through ``net.fit``
 (``ComputationGraph.fit`` -> ``Trainer.fit`` -> ``DeviceFeeder`` -> the
-donating jitted step).  Used by the ``resnet50`` configurations.
+donating jitted step).  Used by the ``resnet50_unfused`` configuration.
 
 The adapter between the benchmark's names and the program's: the
 reference's flat leaves (``res2_0.a.w``, HWIO) go into the graph's
-nested parameter dict (``res2_0``/``W_a``, a matmul weight, on the fused
-path; ``res2_0_a_conv``/``W`` on the unfused one), and what the
-comparison reads comes back under the reference's names.
+nested parameter dict (``res2_0_a_conv``/``W``, ``res2_0_a_bn``/``gamma``),
+and what the comparison reads comes back under the reference's names.
 """
 
 from __future__ import annotations
@@ -16,54 +15,18 @@ import jax.numpy as jnp
 
 import probe
 
-_BRANCH = {"a": "a", "b": "b3", "c": "c", "proj": "proj"}
-_LEAF = {"w": "W", "gamma": "gamma", "beta": "beta"}
 
-
-def _where(name: str, fused: bool) -> tuple:
+def _where(name: str) -> tuple:
     """Reference leaf name -> (vertex, parameter) of the graph."""
     parts = name.split(".")
     if parts[0] == "fc":
         return "out", {"w": "W", "b": "b"}[parts[1]]
-    if parts[0] == "stem" or not fused:
-        vertex = "_".join(parts[:-1])
-        return ((f"{vertex}_conv", "W") if parts[-1] == "w"
-                else (f"{vertex}_bn", parts[-1]))
-    block, branch, leaf = parts
-    return block, f"{_LEAF[leaf]}_{_BRANCH[branch]}"
+    vertex = "_".join(parts[:-1])
+    return ((f"{vertex}_conv", "W") if parts[-1] == "w"
+            else (f"{vertex}_bn", parts[-1]))
 
 
 _POLICIES = {("float32", "bfloat16"): "bf16", ("float32", "float32"): "f32"}
-
-
-class _FirstSteps:
-    """Listener for the first steps: every loss, the optimizer's first
-    moment after step one, the parameters' change after the last."""
-
-    def __init__(self, entry, n_steps: int):
-        self.entry, self.n_steps = entry, n_steps
-        self.losses, self.moment, self.change = [], None, None
-
-    def iteration_done(self, net, iteration, epoch, loss):
-        self.losses.append(loss)
-        if len(self.losses) == 1:
-            self.moment = self.entry.reader.norms(
-                probe.first_moment(net.opt_state))
-        if len(self.losses) == self.n_steps:
-            self.change = self.entry.reader.change(net.params_)
-
-
-class _Cadence:
-    """The window's listener: reads the loss every ``every`` steps, as
-    ``ScoreIterationListener(every)`` does for a user who logs it."""
-
-    def __init__(self, every: int):
-        self.every, self.steps, self.losses = max(1, int(every)), 0, []
-
-    def iteration_done(self, net, iteration, epoch, loss):
-        self.steps += 1
-        if self.steps % self.every == 0:
-            self.losses.append(float(loss))
 
 
 class Entry:
@@ -71,7 +34,7 @@ class Entry:
         self.config, self.mix = config, mix
         self.net = None
         self.weights = None
-        self.cadence = _Cadence(mix["loss_every"])
+        self.cadence = probe.Cadence(mix["loss_every"])
 
     # ---- set-up -------------------------------------------------------------
     def build(self, weights: dict, seed: int) -> None:
@@ -90,8 +53,7 @@ class Entry:
                        channels=model["channels"],
                        num_classes=model["classes"], seed=seed,
                        updater=Nesterovs(opt["learning_rate"],
-                                         opt["momentum"]),
-                       fused=self.config["program"]["fused"])
+                                         opt["momentum"]))
         l2 = {layer.l2 for layer in net.layers}
         if l2 != {opt["l2"]}:
             raise ValueError(f"the zoo's l2 {l2} is not the "
@@ -101,8 +63,7 @@ class Entry:
             net.init()
             return net.params_, net.state_
         param_shapes, state_shapes = jax.eval_shape(shapes)
-        fused = any("W_a" in leaves for leaves in param_shapes.values())
-        self._names = {name: _where(name, fused) for name in weights}
+        self._names = {name: _where(name) for name in weights}
         n_leaves = len(jax.tree_util.tree_leaves(param_shapes))
         if n_leaves != len(weights):
             raise ValueError(f"the graph has {n_leaves} parameter leaves, "
@@ -135,14 +96,10 @@ class Entry:
     def first_steps(self, batches: list) -> dict:
         """The warm-up IS the first steps: the same ``net.fit`` and feeder
         the window uses, over batches that all differ."""
-        seen = _FirstSteps(self, len(batches))
+        seen = probe.FirstSteps(self.reader, len(batches),
+                                lambda net: net.params_)
         self.net.fit(iter(batches), listeners=[seen])
-        factor = probe.first_gradient_factor(self.config["optimizer"])
-        return {
-            "losses": [float(x) for x in seen.losses],
-            "grad_norms": self.reader.as_dict(seen.moment, factor),
-            "delta_norms": self.reader.as_dict(seen.change),
-        }
+        return seen.readings(self.config["optimizer"])
 
     # ---- the window ---------------------------------------------------------
     def run(self, iterator) -> None:

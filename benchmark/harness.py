@@ -189,7 +189,10 @@ def _memory_peak(devices, live_bytes: dict, compiled) -> int:
     return peak
 
 
-def _finite(x: float) -> float:
+def _finite(x) -> float:
+    """``x`` as a python float the result line can hold: an entry may
+    hand over a device or numpy scalar, and JSON has no nan or inf."""
+    x = float(x)
     return x if math.isfinite(x) else 1e30
 
 

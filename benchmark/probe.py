@@ -1,5 +1,8 @@
 """What an entry reads off the program's state for the comparison:
-per-leaf norms, and the first gradient out of an optax state."""
+per-leaf norms, the first gradient out of an optax state, and the two
+listeners every entry hangs on the program's ``fit``.  A listener takes
+the score as the loop hands it, python float or device scalar, and
+converts it itself where it needs the number."""
 
 from __future__ import annotations
 
@@ -41,6 +44,48 @@ class FlatReader:
 
     def as_dict(self, values, scale: float = 1.0) -> dict:
         return {n: float(v) / scale for n, v in zip(self.names, values)}
+
+
+class FirstSteps:
+    """Listener for the first steps: every score as handed over, the
+    optimizer's first moment after step one, the parameters' change after
+    the last.  ``params_of(model)`` is the program's parameter tree."""
+
+    def __init__(self, reader: FlatReader, n_steps: int, params_of):
+        self.reader, self.n_steps, self.params_of = reader, n_steps, params_of
+        self.losses, self.moment, self.change = [], None, None
+
+    def iteration_done(self, model, iteration, epoch, loss):
+        self.losses.append(loss)
+        if len(self.losses) == 1:
+            self.moment = self.reader.norms(first_moment(model.opt_state))
+        if len(self.losses) == self.n_steps:
+            self.change = self.reader.change(self.params_of(model))
+
+    def readings(self, optimizer: dict) -> dict:
+        """What ``compare.gaps`` takes, as python floats."""
+        return {
+            "losses": [float(x) for x in self.losses],
+            "grad_norms": self.reader.as_dict(
+                self.moment, first_gradient_factor(optimizer)),
+            "delta_norms": self.reader.as_dict(self.change),
+        }
+
+
+class Cadence:
+    """The window's listener: counts every step and reads the loss every
+    ``every`` steps, as ``ScoreIterationListener(every)`` does for a user
+    who logs it.  ``float()`` takes a python float and a device scalar
+    alike (the second waits for the step), so the entry never relies on
+    the program's loop having read the loss."""
+
+    def __init__(self, every: int):
+        self.every, self.steps, self.losses = max(1, int(every)), 0, []
+
+    def iteration_done(self, model, iteration, epoch, loss):
+        self.steps += 1
+        if self.steps % self.every == 0:
+            self.losses.append(float(loss))
 
 
 def first_moment(opt_state):

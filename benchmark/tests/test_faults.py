@@ -83,8 +83,6 @@ def _half_batch(monkeypatch):
 
 
 FAULTS = {
-    ("resnet50.train_b128", "state_unchanged"): _frozen_trainer_step,
-    ("resnet50.train_b128", "half_batch"): _half_batch,
     ("resnet50_unfused.train_b128", "state_unchanged"): _frozen_trainer_step,
     ("resnet50_unfused.train_b128", "half_batch"): _half_batch,
     ("bert_base.mlm_s512_b32", "state_unchanged"): _frozen_bert_step,

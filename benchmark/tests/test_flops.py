@@ -25,19 +25,21 @@ def _mix(name):
 
 
 def test_resnet50_matches_the_paper():
-    macs = resnet.forward_macs(_config("resnet50")["model"])
+    config = _config("resnet50_unfused")
+    macs = resnet.forward_macs(config["model"])
     total = sum(macs.values())
     assert total == 3_857_973_248
     # He et al. 2015, Table 1: 3.8e9 multiply-adds for the 50-layer net
     assert abs(total - 3.8e9) / 3.8e9 < 0.02
-    per_image = resnet.per_unit(_config("resnet50"), _mix("train_b128"))
+    per_image = resnet.per_unit(config, _mix("train_b128"))
     assert per_image == 3 * 2 * total - 2 * macs["stem"]
     assert round(per_image / 1e9, 2) == 22.91
 
 
 def test_resnet50_first_block_by_hand():
     # res2_0 at 56x56: 64->64 1x1, 64->64 3x3, 64->256 1x1, 64->256 shortcut
-    model = dict(_config("resnet50")["model"], blocks=[1], widths=[[64, 64, 256]])
+    model = dict(_config("resnet50_unfused")["model"], blocks=[1],
+                 widths=[[64, 64, 256]])
     px = 56 * 56
     assert resnet.forward_macs(model)["blocks"] == px * (
         64 * 64 + 9 * 64 * 64 + 64 * 256 + 64 * 256)

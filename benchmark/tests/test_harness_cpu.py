@@ -25,9 +25,7 @@ def run_tiny(name, *, trace=False, limits=LOOSE, seed=2**31 + 12345,
              tmp_path=None):
     import jax
     bench = _benchmark()
-    # a twin whose cell is left out of BENCHMARK.json still runs here
-    cell = {c["name"]: c for c in bench["workloads"]}.get(
-        name, {"name": name, "chips": 1})
+    cell = {c["name"]: c for c in bench["workloads"]}[name]
     config, mix = tiny.CELLS[name]()
     return harness.run_cell(
         cell, seed, 2.0, trace, config=config, mix=mix, limits=limits,
@@ -54,6 +52,13 @@ def test_end_to_end_line(name, tmp_path):
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] == result["window"]["steps"] > 0
     assert result["window"]["recompiles"] == 0
+    # the window's listener reads the loss at the mix's cadence, in both
+    # entries, whatever the program's loop does with it
+    loss_every = tiny.CELLS[name]()[1]["loss_every"]
+    assert loss_every == 2          # a cadence of 1 would show nothing
+    assert result["window"]["losses_read"] == \
+        result["window"]["steps"] // loss_every
+    assert type(result["window"]["last_loss"]) is float
     bench = _benchmark()
     want = {m["name"] for m in bench["end_to_end"]
             if name in m.get("workloads", [name])}

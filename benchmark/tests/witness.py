@@ -4,25 +4,22 @@ with the float32 reference on ``stem.beta``, ``stem.gamma`` and
 
     python benchmark/tests/witness.py --seeds 3
 
-With every gamma at 1 the program's first-gradient norm of ``stem.beta``
-read 1.45-2.29 away from the reference's on all 12 seeds; with each
-block's last gamma at 0.1 the worst leaf still reads 0.06-0.17.  Program
-or reference?  For both initialisations and every seed this reads, against
-the float32 reference:
+With every gamma at 1 even the program's float32 path reads 0.23-0.53 at
+the worst leaf against the float32 reference, two float32 codes; with
+each block's last gamma at 0.1 it reads 0.02-0.05 (chip call 6 of PR 26).
+Program or reference?  For both initialisations and every seed this
+reads, against the float32 reference:
 
-* ``program``: the cell as configured (bf16 policy, the fused path);
-* ``program_unfused``: the same policy on the program's other path;
-* ``program_unfused_f32``: that path under the float32 policy;
+* ``program``: the cell as configured (bf16 policy, the conv+BN graph);
+* ``program_f32``: the same graph under the float32 policy;
 * ``reference_bf16``: the reference itself with the configuration's
   precision: weights at use, every layer's output and the cotangents
   that come back through them held in bfloat16.
 
 If the float32 program sides with the reference and the bfloat16
 reference reads as the program does, the cause is the precision the
-configuration states, and neither side is at fault.  It came out
-otherwise (chip call 6, PR 26): the bfloat16 reference and the unfused
-program side with the reference, and only the fused path stands apart,
-so ``resnet50.train_b128`` is out of ``BENCHMARK.json``.  One JSON object per
+configuration states, and neither side is at fault: so it came out for
+this graph (PERF.md section 6, PR 26).  One JSON object per
 initialisation and seed goes to standard output and to
 ``chiprun_out/witness.resnet50.jsonl``.
 """
@@ -41,8 +38,7 @@ WATCH = ("stem.beta", "stem.gamma", "res2_0.a.w")
 F32 = {"params": "float32", "compute": "float32", "activations": "float32"}
 VARIANTS = {
     "program": {},
-    "program_unfused": {"program": {"fused": False}},
-    "program_unfused_f32": {"program": {"fused": False}, "precision": F32},
+    "program_f32": {"precision": F32},
 }
 
 
@@ -96,7 +92,7 @@ def main(argv=None) -> int:
     import harness
     import traffic
     from deeplearning4j_tpu import config as program_config
-    config = harness.load_json("configs", "resnet50.json")
+    config = harness.load_json("configs", "resnet50_unfused.json")
     mix = traffic.load_mix("train_b128")
     harness.require_chips(1)
     program_config.place_compile_cache()
