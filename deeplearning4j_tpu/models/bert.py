@@ -399,7 +399,20 @@ class BertForMaskedLM:
 
         return tpudl_bert_mlm_step
 
-    def fit(self, batches, updater=None, epochs: int = 1, listeners=None):
+    def fit(self, batches, updater=None, epochs: int = 1,
+            listeners=None) -> float:
+        """Train over ``batches`` for ``epochs`` and return the last step's
+        loss as a python float (``nan`` over no batches): the loop's ONE
+        host read, after the last epoch, and the point where the caller is
+        sure the work is done.
+
+        ``iteration_done`` listeners are handed the step's loss as the
+        DEVICE scalar the jitted step returned, as ``Trainer.step_batch``
+        hands it; the loop never converts it, so back-to-back steps enqueue
+        without a wait.  A listener that converts every score (e.g.
+        ``CollectScoresListener``) makes the loop wait for every step;
+        ``ScoreIterationListener(n)`` reads one in ``n``.
+        """
         from deeplearning4j_tpu.train import updaters as updater_mod
         from deeplearning4j_tpu.obs.listeners import ListenerBus
         bus = listeners if isinstance(listeners, ListenerBus) else ListenerBus(listeners)
@@ -436,12 +449,14 @@ class BertForMaskedLM:
                     for fed in feeder.feed(batches):
                         key, sub = jax.random.split(key)
                         last = self._fit_step(fed, sub, bus, metrics)
-        return last
+        return float(last)
 
-    def _fit_step(self, fed, rng, bus, metrics) -> float:
+    def _fit_step(self, fed, rng, bus, metrics) -> jax.Array:
         """One iteration of :meth:`fit`: ``step`` over all of it,
         ``step.dispatch`` around the jitted call and nothing else,
-        ``step.read`` around the loss's read and the listeners."""
+        ``step.read`` around the listeners (what ``Trainer._reading``
+        holds).  Hands the listeners, and returns, the loss as the device
+        scalar the step returned: whoever reads it converts it."""
         t0 = time.perf_counter()
         with tracing.span("step", iteration=self.iteration) as sp:
             ids, labels, weights, attn = fed.batch
@@ -462,15 +477,14 @@ class BertForMaskedLM:
             metrics.examples.inc(fed.n_examples)
             t3 = time.perf_counter()
             with tracing.span("step.read"):
-                last = float(loss)
-                bus.dispatch("iteration_done", self, self.iteration, 0, last)
+                bus.dispatch("iteration_done", self, self.iteration, 0, loss)
             t4 = time.perf_counter()
             self.iteration += 1
         if retraced == 0:
             metrics.iteration.observe(time.perf_counter() - t0)
             metrics.dispatch.observe(t2 - t1)
             metrics.read.observe(t4 - t3)
-        return last
+        return loss
 
     def predict_mlm(self, input_ids, attention_mask=None):
         hidden = encode(self.params, self.config, jnp.asarray(input_ids),

@@ -27,6 +27,8 @@ def main(epochs: int = 2, seq_len: int = 16, batch_size: int = 8,
                         max_position=seq_len)
     model = BertForMaskedLM(config, seed=0)
     from deeplearning4j_tpu.obs import CollectScoresListener
+    # converts EVERY score, so the loop waits for every step (fine at this
+    # size); ScoreIterationListener(n) reads one in n and lets steps pipeline
     scores = CollectScoresListener()
     model.fit(it, updater=Adam(5e-4), epochs=epochs, listeners=[scores])
     losses = scores.scores

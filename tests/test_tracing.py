@@ -189,11 +189,11 @@ def _tiny_bert():
 N_STEPS = 6          # 192/64 MNIST batches, or 3 BERT batches, x 2 epochs
 
 
-def _fit(which):
+def _fit(which, listeners=None):
     if which == "trainer":
-        _mlp().fit(_mnist(), epochs=2)
+        _mlp().fit(_mnist(), epochs=2, listeners=listeners)
     else:
-        _tiny_bert().fit(_bert_batches(), epochs=2)
+        _tiny_bert().fit(_bert_batches(), epochs=2, listeners=listeners)
 
 
 def test_multilayer_fit_emits_step_spans():
@@ -265,7 +265,8 @@ def test_fit_emits_the_whole_span_tree(which):
     assert not t.find("feed")                 # the zero-length span went
 
 
-def test_tracing_never_syncs_and_changes_no_loss(monkeypatch):
+@pytest.mark.parametrize("which", ["trainer", "bert"])
+def test_tracing_never_syncs_and_changes_no_loss(which, monkeypatch):
     """Turning the tracer on must not change the program it traces: no
     device_sync, no block_until_ready on the step path, the same losses."""
     import jax
@@ -287,12 +288,122 @@ def test_tracing_never_syncs_and_changes_no_loss(monkeypatch):
                                 counted("device_sync", real_sync))
             monkeypatch.setattr(jax, "block_until_ready",
                                 counted("block_until_ready", real_block))
-            _mlp().fit(_mnist(), epochs=2, listeners=[seen])
+            _fit(which, listeners=[seen])
             monkeypatch.undo()
         losses[traced] = seen.scores
         assert bool(t.spans) == traced
     assert calls == {"device_sync": 0, "block_until_ready": 0}
-    assert len(losses[True]) == 6 and losses[True] == losses[False]
+    assert len(losses[True]) == N_STEPS and losses[True] == losses[False]
+
+
+# ---- who reads the loss in BertForMaskedLM.fit (Trainer's form: the loop
+# hands out the device scalar and converts nothing but fit's return value)
+
+class _CountedLoss:
+    """Stands in for the step's loss: counts the conversions to float."""
+
+    def __init__(self, loss, conversions: list):
+        self.loss, self.conversions = loss, conversions
+
+    def __float__(self):
+        self.conversions.append(1)
+        return float(self.loss)
+
+
+class _EveryKth:
+    """A listener that converts every ``k``-th score, as
+    ``ScoreIterationListener(k)`` does for a user who logs it."""
+
+    def __init__(self, k: int):
+        self.k, self.steps, self.scores = k, 0, []
+
+    def iteration_done(self, model, iteration, epoch, score):
+        self.steps += 1
+        if self.steps % self.k == 0:
+            self.scores.append(float(score))
+
+
+def _bert_with_counted_loss(conversions: list):
+    """A tiny BERT whose jitted step is wrapped: same step, but the loss it
+    hands the loop counts every ``float()`` taken of it (the transfer guard
+    does not fire on the CPU backend, so conversions are counted)."""
+    from deeplearning4j_tpu.train import Adam
+    model, updater = _tiny_bert(), Adam(1e-3)
+    step = model.make_train_step(updater.to_optax())
+
+    def counted_step(*args):
+        params, opt_state, loss = step(*args)
+        return params, opt_state, _CountedLoss(loss, conversions)
+    model._step = counted_step
+    return model, updater
+
+
+@pytest.mark.parametrize("every", [None, 1, 2, 3, 4, 7])
+def test_bert_loop_converts_the_loss_as_often_as_its_listeners(every):
+    """``fit`` over N batches with a listener converting every k-th score:
+    N // k conversions in the loop and one at ``fit``'s return; with no
+    listener exactly that one."""
+    n = 6
+    conversions = []
+    model, updater = _bert_with_counted_loss(conversions)
+    listeners = [] if every is None else [_EveryKth(every)]
+    last = model.fit(_bert_batches(n), updater=updater, listeners=listeners)
+    in_loop = 0 if every is None else n // every
+    assert len(conversions) == in_loop + 1
+    assert isinstance(last, float) and np.isfinite(last)
+    for seen in listeners:
+        assert seen.steps == n and len(seen.scores) == in_loop
+        if n % every == 0:
+            assert seen.scores[-1] == last
+    assert model.iteration == n
+
+
+def test_bert_listener_gets_the_device_scalar_after_the_rebind():
+    """Every step hands the listeners a ``jax.Array``, never a python
+    float, and only after ``model.params`` / ``model.opt_state`` were
+    rebound to the step's outputs (the step donates both): readable at
+    step one, and changed by it."""
+    import jax
+    seen = []
+    model = _tiny_bert()
+    before = np.asarray(
+        model.params["embeddings"]["word_embeddings"]).copy()
+
+    class Reads:
+        def iteration_done(self, model, iteration, epoch, score):
+            leaves = jax.tree_util.tree_leaves(
+                (model.params, model.opt_state))
+            seen.append({
+                "score": score,
+                "deleted": [leaf.is_deleted() for leaf in leaves
+                            if isinstance(leaf, jax.Array)],
+                "word": np.asarray(
+                    model.params["embeddings"]["word_embeddings"]).copy(),
+                "iteration": (iteration, model.iteration)})
+
+    last = model.fit(_bert_batches(3), listeners=[Reads()])
+    assert len(seen) == 3
+    for i, got in enumerate(seen):
+        assert isinstance(got["score"], jax.Array)
+        assert got["score"].shape == () and got["deleted"]
+        assert not any(got["deleted"])
+        assert got["iteration"] == (i, i)
+    # step one's parameters at step one's callback, not the initial ones
+    assert not np.array_equal(seen[0]["word"], before)
+    assert not np.array_equal(seen[1]["word"], seen[0]["word"])
+    assert last == float(seen[-1]["score"])
+
+
+def test_bert_fit_returns_a_python_float_and_nan_over_no_batches():
+    import math
+    from deeplearning4j_tpu.obs import CollectScoresListener
+    model, seen = _tiny_bert(), CollectScoresListener()
+    last = model.fit(_bert_batches(2), epochs=2, listeners=[seen])
+    assert type(last) is float and last == seen.scores[-1]
+    assert len(seen.scores) == 4 and seen.iterations == [0, 1, 2, 3]
+    empty = model.fit([], listeners=[seen])
+    assert type(empty) is float and math.isnan(empty)
+    assert len(seen.scores) == 4 and model.iteration == 4
 
 
 _LOOP_HISTOGRAMS = ["tpudl_train_iteration_seconds",
