@@ -12,6 +12,10 @@ Parity notes per model (reference classes under
 - ``simple_cnn`` → ``SimpleCNN.java``.
 - ``text_gen_lstm`` → ``TextGenerationLSTM.java`` (char-RNN,
   GravesLSTM stack + RnnOutputLayer MCXENT).
+- ``joyai_llm_flash`` has no reference twin: a decoder-only language model
+  of DeepSeek-V3's kind (latent attention, sigmoid-routed dropless experts
+  beside a shared expert, a multi-token-prediction module) from the keys of
+  its published ``config.json``, as a ComputationGraph of ``nn.layers.decoder``.
 - ``mlp_mnist`` / ``lstm_classifier`` → dl4j-examples workloads named in
   BASELINE.json (MLPMnistTwoLayerExample; UCI HAR sequence classification).
 
@@ -32,7 +36,14 @@ from deeplearning4j_tpu.nn.layers import (
     LocalResponseNormalization, LSTM, GravesLSTM, LastTimeStep, RnnOutputLayer,
     ZeroPaddingLayer,
 )
-from deeplearning4j_tpu.nn.vertices import ElementWiseVertex
+from deeplearning4j_tpu.nn.layers import (
+    CausalLMOutput, EmbeddingSequenceLayer, GatedFeedForward, LatentAttention,
+    RMSNorm, RoutedExperts,
+)
+from deeplearning4j_tpu.nn.weights import distribution
+from deeplearning4j_tpu.nn.vertices import (
+    ElementWiseVertex, MergeVertex, ShiftTimeVertex, StackVertex,
+)
 from deeplearning4j_tpu.train import Adam, Nesterovs, Sgd
 
 
@@ -239,6 +250,100 @@ def resnet50(seed: int = 123, num_classes: int = 1000, height: int = 224,
     gb.add_layer("out", OutputLayer(n_out=num_classes, activation="softmax",
                                     loss="mcxent"), "avgpool")
     gb.set_outputs("out")
+    return ComputationGraph(gb.build())
+
+
+# ------------------------------------------------------- decoder-only LMs
+def _decoder_block(gb, name, x, c, *, routed: bool, std: float):
+    """One pre-norm block under ``name``: RMS norm, latent attention, add;
+    RMS norm, gated feed-forward or routed experts, add.  Rematerialised
+    as one run.  Returns the name of its output vertex."""
+    eps = c["rms_norm_eps"]
+    gb.add_layer(f"{name}_attn_norm", RMSNorm(eps=eps), x)
+    gb.add_layer(f"{name}_attn", LatentAttention(
+        n_heads=c["num_attention_heads"], q_lora_rank=c["q_lora_rank"],
+        kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_theta=float(c["rope_theta"]), eps=eps, init_std=std),
+        f"{name}_attn_norm")
+    gb.add_vertex(f"{name}_attn_add", ElementWiseVertex(op="add"), x,
+                  f"{name}_attn")
+    gb.add_layer(f"{name}_ffn_norm", RMSNorm(eps=eps), f"{name}_attn_add")
+    if routed:
+        ffn = RoutedExperts(
+            n_routed_experts=c["n_routed_experts"],
+            experts_held=c.get("experts_held", 0),
+            first_expert=c.get("first_expert", 0),
+            top_k=c["num_experts_per_tok"], hidden=c["moe_intermediate_size"],
+            shared_hidden=c["moe_intermediate_size"] * c["n_shared_experts"],
+            routed_scaling_factor=c["routed_scaling_factor"],
+            norm_topk_prob=c["norm_topk_prob"], init_std=std)
+    else:
+        ffn = GatedFeedForward(hidden=c["intermediate_size"], init_std=std)
+    gb.add_layer(f"{name}_ffn", ffn, f"{name}_ffn_norm")
+    gb.add_vertex(f"{name}_out", ElementWiseVertex(op="add"),
+                  f"{name}_attn_add", f"{name}_ffn")
+    gb.remat(f"{name}_attn_norm", f"{name}_out")
+    return f"{name}_out"
+
+
+def joyai_llm_flash(config: dict, seq_len: int, seed: int = 123,
+                    updater=None, mtp_weight: float = 0.3,
+                    init_std: float = 0.02) -> ComputationGraph:
+    """JoyAI-LLM-Flash (``model_type`` ``joyai_llm_flash``, 48B-A2.7B) from
+    the keys of its published ``config.json``: ``first_k_dense_replace``
+    dense blocks, then routed ones (sigmoid scores, ``noaux_tc`` selection
+    bias, ``norm_topk_prob``, ``routed_scaling_factor``, a shared expert),
+    latent attention in every block, an untied head, and with
+    ``num_nextn_predict_layers`` 1 DeepSeek-V3's multi-token-prediction
+    module: ``W_eh [RMSNorm(h) ; RMSNorm(Emb(t_{i+1}))]``, one more routed
+    block, its own final norm, the shared head, weighted ``mtp_weight``.
+
+    A chip's share of an expert-parallel deployment is two more keys:
+    ``experts_held`` of the ``n_routed_experts`` live here, from
+    ``first_expert`` on; ``vocab_size`` is the slice held.  Input and
+    labels are the same ``[B, seq_len]`` int32 ids: ``net.fit`` over
+    ``DataSet(ids, ids)``."""
+    c, h = config, config["hidden_size"]
+    if c["num_nextn_predict_layers"] not in (0, 1):
+        raise ValueError("one multi-token-prediction module at most")
+    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing is not built: n_group and "
+                         "topk_group have to be 1")
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed)
+          .updater(updater or Adam(1e-4))
+          .graph()
+          .add_inputs("tokens")
+          .set_input_types(InputType.recurrent(1, seq_len)))
+    gb.add_layer("embed", EmbeddingSequenceLayer(
+        n_in=c["vocab_size"], n_out=h, has_bias=False,
+        weight_init=distribution("normal", std=init_std)), "tokens")
+    x = "embed"
+    for n in range(c["num_hidden_layers"]):
+        x = _decoder_block(gb, f"l{n}", x, c, std=init_std,
+                           routed=n >= c["first_k_dense_replace"])
+    eps = c["rms_norm_eps"]
+    gb.add_layer("final_norm", RMSNorm(eps=eps), x)
+    streams = ["final_norm"]
+    if c["num_nextn_predict_layers"]:
+        gb.add_layer("mtp_h_norm", RMSNorm(eps=eps), x)
+        gb.add_vertex("mtp_next", ShiftTimeVertex(), "embed")
+        gb.add_layer("mtp_e_norm", RMSNorm(eps=eps), "mtp_next")
+        gb.add_vertex("mtp_merge", MergeVertex(), "mtp_h_norm", "mtp_e_norm")
+        gb.add_layer("mtp_proj", DenseLayer(
+            n_out=h, has_bias=False, activation="identity",
+            weight_init=distribution("normal", std=init_std)), "mtp_merge")
+        y = _decoder_block(gb, "mtp", "mtp_proj", c, routed=True,
+                           std=init_std)
+        gb.add_layer("mtp_final_norm", RMSNorm(eps=eps), y)
+        streams.append("mtp_final_norm")
+    gb.add_vertex("streams", StackVertex(), *streams)
+    gb.add_layer("lm_head", CausalLMOutput(
+        n_out=c["vocab_size"], n_streams=len(streams), mtp_weight=mtp_weight,
+        init_std=init_std), "streams")
+    gb.set_outputs("lm_head")
     return ComputationGraph(gb.build())
 
 

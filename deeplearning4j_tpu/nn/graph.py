@@ -62,6 +62,10 @@ class ComputationGraphConfiguration:
     backprop_type: str = "standard"
     tbptt_fwd_length: int = 20
     tbptt_back_length: int = 20
+    # [[first, last], ...]: runs of vertices (inclusive, contiguous in the
+    # topological order) whose activations the backward pass recomputes
+    # from the run's inputs instead of keeping (GraphBuilder.remat)
+    remat_segments: list = dataclasses.field(default_factory=list)
 
     # ---------------------------------------------------------- topo/types
     def topo_order(self) -> list[VertexSpec]:
@@ -143,6 +147,8 @@ class ComputationGraphConfiguration:
             "backprop_type": self.backprop_type,
             "tbptt_fwd_length": self.tbptt_fwd_length,
             "tbptt_back_length": self.tbptt_back_length,
+            **({"remat_segments": [list(s) for s in self.remat_segments]}
+               if self.remat_segments else {}),
         }
 
     def to_json(self):
@@ -163,6 +169,7 @@ class ComputationGraphConfiguration:
             backprop_type=d.get("backprop_type", "standard"),
             tbptt_fwd_length=d.get("tbptt_fwd_length", 20),
             tbptt_back_length=d.get("tbptt_back_length", 20),
+            remat_segments=[list(s) for s in d.get("remat_segments", [])],
         )
 
     @staticmethod
@@ -181,6 +188,15 @@ class GraphBuilder:
         self._input_types: list[InputType] = []
         self._backprop_type = "standard"
         self._tbptt = (20, 20)
+        self._remat: list[list[str]] = []
+
+    def remat(self, first: str, last: str) -> "GraphBuilder":
+        """Rematerialise the vertices ``first`` .. ``last`` (as added, both
+        included) in the backward pass: only the run's inputs are kept
+        through the forward pass, and ``jax.checkpoint`` recomputes the
+        rest.  One run a decoder block is what lets 8k tokens fit a chip."""
+        self._remat.append([first, last])
+        return self
 
     def add_inputs(self, *names: str) -> "GraphBuilder":
         self._inputs.extend(names)
@@ -220,6 +236,7 @@ class GraphBuilder:
             mini_batch=p._mini_batch,
             backprop_type=self._backprop_type,
             tbptt_fwd_length=self._tbptt[0], tbptt_back_length=self._tbptt[1],
+            remat_segments=self._remat,
         )
         conf.topo_order()  # validate DAG now
         return conf
@@ -273,6 +290,27 @@ class ComputationGraph:
         return flat_param_vector(self.params_)
 
     # ---------------------------------------------------------- forward
+    def _remat_plan(self) -> dict:
+        """``conf.remat_segments`` against the topological order: {index of
+        a run's first vertex: (index of its last, names it reads from
+        outside, names read after it)}.  Empty for a graph without runs."""
+        if not self.conf.remat_segments:
+            return {}
+        index = {spec.name: i for i, spec in enumerate(self._topo)}
+        plan = {}
+        for first, last in self.conf.remat_segments:
+            a, b = index[first], index[last]
+            inside = {spec.name for spec in self._topo[a:b + 1]}
+            if a > b or inside & set(self.conf.outputs):
+                raise ValueError(
+                    f"remat run {first!r}..{last!r} is empty or holds an "
+                    f"output vertex")
+            reads = sorted({i for spec in self._topo[a:b + 1]
+                            for i in spec.inputs} - inside)
+            after = {i for spec in self._topo[b + 1:] for i in spec.inputs}
+            plan[a] = (b, reads, sorted(inside & after))
+        return plan
+
     def _forward(self, params, state, features, *, train: bool, rng=None,
                  mask=None, labels=None):
         """features: array (single input) or tuple/list (multi input);
@@ -289,7 +327,10 @@ class ComputationGraph:
         known_types = dict(zip(self.conf.inputs, self.conf.input_types))
         new_state = {}
         score_arrays = []
-        for vi, spec in enumerate(self._topo):
+
+        def run(vi, spec, acts, act_masks, params, state, rng):
+            """One vertex: its activation and mask into ``acts`` and
+            ``act_masks``, its new state returned."""
             in_acts = [acts[i] for i in spec.inputs]
             in_mask = next((act_masks.get(i) for i in spec.inputs
                             if act_masks.get(i) is not None), None)
@@ -316,16 +357,48 @@ class ComputationGraph:
                                                layer_rng),
                         state[spec.name], x,
                         train=train, rng=layer_rng, mask=in_mask)
-                    new_state[spec.name] = s
                     known_types[spec.name] = spec.obj.get_output_type(
                         preprocessors.adapt_type(itype, spec.obj))
                 else:
                     y = spec.obj.apply(in_acts)
-                    new_state[spec.name] = state[spec.name]
+                    s = state[spec.name]
                     known_types[spec.name] = spec.obj.get_output_type(
                         [known_types[i] for i in spec.inputs])
             acts[spec.name] = y
             act_masks[spec.name] = in_mask
+            return s
+
+        segments = self._remat_plan() if train else {}
+        vi = 0
+        while vi < len(self._topo):
+            spec = self._topo[vi]
+            if vi not in segments:
+                new_state[spec.name] = run(vi, spec, acts, act_masks, params,
+                                           state, rng)
+                vi += 1
+                continue
+            # a rematerialised run: what it reads from outside goes in,
+            # what is read after it (and its layers' new state) comes out
+            last, reads, keeps = segments[vi]
+            specs = list(enumerate(self._topo[vi:last + 1], vi))
+            names = [sp.name for _, sp in specs]
+
+            def segment(seg_params, seg_state, seg_acts, seg_masks, seg_rng):
+                local, local_masks, states = dict(seg_acts), dict(seg_masks), {}
+                for i, sp in specs:
+                    states[sp.name] = run(i, sp, local, local_masks,
+                                          seg_params, seg_state, seg_rng)
+                return ({n: local[n] for n in keeps},
+                        {n: local_masks[n] for n in keeps}, states)
+
+            kept, kept_masks, states = jax.checkpoint(segment)(
+                {n: params[n] for n in names}, {n: state[n] for n in names},
+                {n: acts[n] for n in reads},
+                {n: act_masks.get(n) for n in reads}, rng)
+            acts.update(kept)
+            act_masks.update(kept_masks)
+            new_state.update(states)
+            vi = last + 1
         outs = [acts[name] for name in self.conf.outputs]
         score_array = None
         if score_arrays:

@@ -62,6 +62,13 @@ from deeplearning4j_tpu.nn.layers.attention import (
     LearnedSelfAttentionLayer,
 )
 from deeplearning4j_tpu.nn.layers.norm import LayerNormalization, PReLULayer
+from deeplearning4j_tpu.nn.layers.decoder import (
+    RMSNorm,
+    GatedFeedForward,
+    LatentAttention,
+    RoutedExperts,
+    CausalLMOutput,
+)
 from deeplearning4j_tpu.nn.layers.extra import (
     ZeroPadding1DLayer,
     Cropping1DLayer,
@@ -107,6 +114,8 @@ __all__ = [
     "TimeDistributed", "RnnOutputLayer", "RnnLossLayer",
     "SelfAttentionLayer", "LearnedSelfAttentionLayer",
     "LayerNormalization", "PReLULayer",
+    "RMSNorm", "GatedFeedForward", "LatentAttention", "RoutedExperts",
+    "CausalLMOutput",
     "ZeroPadding1DLayer", "Cropping1DLayer", "Upsampling1DLayer",
     "ZeroPadding3DLayer", "Cropping3DLayer", "Upsampling3DLayer",
     "SpaceToBatchLayer", "GaussianDropoutLayer", "GaussianNoiseLayer",

@@ -185,6 +185,19 @@ class ShiftVertex(GraphVertex):
         return inputs[0] + self.shift
 
 
+@register_vertex("shift_time")
+@dataclasses.dataclass
+class ShiftTimeVertex(GraphVertex):
+    """``y[:, i] = x[:, i + 1]`` along the time axis of [B, T, C], zeros at
+    the last step: the embedded sequence one token ahead, which a
+    multi-token-prediction module reads beside the main stack's output (no
+    second lookup)."""
+
+    def apply(self, inputs):
+        x = inputs[0]
+        return jnp.concatenate([x[:, 1:], jnp.zeros_like(x[:, :1])], axis=1)
+
+
 @register_vertex("attention")
 @dataclasses.dataclass
 class AttentionVertex(GraphVertex):

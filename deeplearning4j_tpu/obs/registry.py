@@ -488,6 +488,16 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
                   "New XLA traces of trainer step functions (first "
                   "compile included; shape churn past step 1 means the "
                   "recompile guard is being bypassed)"),
+        r.counter("tpudl_moe_pairs_total",
+                  "(token, expert) pairs the routed-expert layers held "
+                  "here computed, summed over the layers, on the steps "
+                  "whose loss a listener read"),
+        r.counter("tpudl_moe_pairs_max_expert_total",
+                  "Pairs of each routed-expert layer's busiest held "
+                  "expert, summed over the layers, on the same steps"),
+        r.counter("tpudl_moe_tokens_total",
+                  "Tokens routed, summed over the routed-expert layers, "
+                  "on the same steps"),
         r.counter("tpudl_train_step_cache_hits_total",
                   "Compiled-step reuses served by train.step_cache"),
         r.counter("tpudl_train_step_cache_misses_total",

@@ -77,6 +77,8 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          dropout_rng: Optional[jax.Array] = None
                          ) -> jnp.ndarray:
     """Multi-head attention on pre-projected q/k/v of shape [B,T,H*Dh].
+    ``v`` may have a head size of its own ([B,T,H*Dv], latent attention's
+    192-wide keys beside 128-wide values): the output is then [B,T,H*Dv].
 
     ``mask``: [B,T] padding mask applied to keys (and zeroing masked query
     outputs, matching DL4J's masked-attention semantics); ``kv_mask`` masks
@@ -124,7 +126,8 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     dh = d // n_heads
     qh = q.reshape(b, tq, n_heads, dh).transpose(0, 2, 1, 3)  # [B,H,Tq,Dh]
     kh = k.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
-    vh = v.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
+    dv = v.shape[-1] // n_heads
+    vh = v.reshape(b, tk, n_heads, dv).transpose(0, 2, 1, 3)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :] > 0, scores, NEG_INF)
@@ -136,7 +139,7 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         weights = dropout(weights, 1.0 - dropout_rate, dropout_rng,
                           mask_layout=GENERATOR_LAYOUT)
     out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh)
-    out = out.transpose(0, 2, 1, 3).reshape(b, tq, d)
+    out = out.transpose(0, 2, 1, 3).reshape(b, tq, n_heads * dv)
     if mask is not None and tq == tk:
         out = out * mask[:, :, None]
     return out

@@ -125,6 +125,18 @@ def _sds(q, k, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _wide_heads(d: int, d_v: int):
+    """Compiler parameters for heads wider than 128 (latent attention's
+    192-wide keys): at the tuned 1024x1024 tile the backward's score-sized
+    temporaries plus the wider operands ask for 19.1 MB of VMEM, over the
+    compiler's 16 MiB default scope (a described-chip compile, PR 38), so
+    such a call is given 32 MiB of a v5e core's 128.  Narrower heads keep
+    the default, and their compiled kernels are what they were."""
+    if max(d, d_v) <= 128:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+
+
 def _pad_to(x, axis, multiple):
     n = x.shape[axis]
     pad = (-n) % multiple
@@ -174,22 +186,23 @@ def flash_attention_block(q, k, v, *, scale: float, causal: bool = False,
                           interpret: bool | None = None):
     """One (q-block, kv-block) flash pass.
 
-    q [B,H,Tq,D], k/v [B,H,Tk,D] → (o [B,H,Tq,D] unnormalized,
-    m [B,H,Tq] row max, l [B,H,Tq] row sum-exp) — drop-in for the jnp
-    ``_block_attention`` oracle.  ``q_offset``/``k_offset``: global
+    q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv] → (o [B,H,Tq,Dv]
+    unnormalized, m [B,H,Tq] row max, l [B,H,Tq] row sum-exp) — drop-in
+    for the jnp ``_block_attention`` oracle.  ``Dv`` may differ from
+    ``D`` (latent attention: 192-wide keys, 128-wide values).  ``q_offset``/``k_offset``: global
     positions of row/col 0 (ints or traced scalars).  ``key_mask``:
     optional [B, Tk] padding mask (1 = attend), broadcast over heads.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k, q.dtype,
                                     interpret)
 
     qf = _pad_to(q.reshape(b * h, tq, d), 1, block_q)
     kf = _pad_to(k.reshape(b * h, tk, d), 1, block_k)
-    vf = _pad_to(v.reshape(b * h, tk, d), 1, block_k)
+    vf = _pad_to(v.reshape(b * h, tk, dv), 1, block_k)
     tq_p, tk_p = qf.shape[1], kf.shape[1]
     n_q, n_k = tq_p // block_q, tk_p // block_k
     has_mask = key_mask is not None
@@ -213,26 +226,27 @@ def flash_attention_block(q, k, v, *, scale: float, causal: bool = False,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, 8, block_k), km_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0)),
         ],
-        out_shape=[_sds(qf, kf, (b * h, tq_p, d)),
+        out_shape=[_sds(qf, kf, (b * h, tq_p, dv)),
                    _sds(qf, kf, (b * h, tq_p, 128)),
                    _sds(qf, kf, (b * h, tq_p, 128))],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         name="tpudl_flash_fwd",
+        compiler_params=_wide_heads(d, dv),
         interpret=interpret,
     )(qoff, koff, klen, qf, kf, vf, kmaskf)
-    o = o[:, :tq].reshape(b, h, tq, d)
+    o = o[:, :tq].reshape(b, h, tq, dv)
     m = m[:, :tq, 0].reshape(b, h, tq)
     l = l[:, :tq, 0].reshape(b, h, tq)
     return o, m, l
@@ -473,21 +487,22 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                               merged: bool = True):
     """Backward of normalized blockwise attention.
 
-    q [B,H,Tq,D], k/v [B,H,Tk,D], out/dout [B,H,Tq,D] (normalized output
-    and its cotangent), lse [B,H,Tq] = m + log(l) from the forward pass.
-    Returns (dq, dk, dv) in f32, heads layout.  ``q_offset``/``k_offset``
+    q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv], out/dout [B,H,Tq,Dv]
+    (normalized output and its cotangent), lse [B,H,Tq] = m + log(l) from
+    the forward pass.  Returns (dq, dk, dv) in f32, heads layout, each in
+    its operand's shape.  ``q_offset``/``k_offset``
     give global positions for causal masking inside a sharded ring.
 
     ``merged=True`` (default, round 5): one kernel pass produces dK, dV
     and per-k-block dQ partials (summed outside) — 5 matmuls per tile
     and one HBM stream of the operands, vs 7 matmuls over two kernels
     (measured −22% bwd wall time at seq 4096 on v5e).  ``merged=False``
-    keeps the two-kernel form (the r3 oracle).
+    keeps the two-kernel form (the r3 oracle), for ``Dv == D`` only.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, d_v = k.shape[2], v.shape[3]
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k, q.dtype,
                                     interpret)
 
@@ -498,8 +513,8 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
 
     qf = _pad_to(q.reshape(b * h, tq, d), 1, block_q)
     kf = _pad_to(k.reshape(b * h, tk, d), 1, block_k)
-    vf = _pad_to(v.reshape(b * h, tk, d), 1, block_k)
-    dof = _pad_to(dout.reshape(b * h, tq, d), 1, block_q)
+    vf = _pad_to(v.reshape(b * h, tk, d_v), 1, block_k)
+    dof = _pad_to(dout.reshape(b * h, tq, d_v), 1, block_q)
     # padded q rows carry lse = -inf → p = 0 in both kernels (no NaNs,
     # no contribution to dk/dv); padded k cols are masked via klen
     lsef = _pad_rows(lse.astype(jnp.float32).reshape(b * h, tq),
@@ -519,9 +534,13 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
 
     if merged:
         q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
+        do_spec2 = pl.BlockSpec((1, block_q, d_v),
+                                lambda bh, j, i: (bh, i, 0))
         stat_spec2 = pl.BlockSpec((1, block_q, 128),
                                   lambda bh, j, i: (bh, i, 0))
         k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0))
+        v_spec2 = pl.BlockSpec((1, block_k, d_v),
+                               lambda bh, j, i: (bh, j, 0))
         km_spec2 = pl.BlockSpec((1, 8, block_k),
                                 (lambda bh, j, i: (bh, 0, j)) if has_mask
                                 else (lambda bh, j, i: (0, 0, 0)))
@@ -537,23 +556,29 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                               block_q=block_q, block_k=block_k, n_q=n_q),
             grid=(b * h, n_k, n_q),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
-            + [q_spec2, k_spec2, k_spec2, km_spec2, q_spec2,
+            + [q_spec2, k_spec2, v_spec2, km_spec2, do_spec2,
                stat_spec2, stat_spec2],
-            out_specs=[k_spec2, k_spec2, dqp_spec],
+            out_specs=[k_spec2, v_spec2, dqp_spec],
             out_shape=[_sds(qf, kf, (b * h, tk_p, d)),
-                       _sds(qf, kf, (b * h, tk_p, d)),
+                       _sds(qf, kf, (b * h, tk_p, d_v)),
                        _sds(qf, kf, (n_k, b * h, tq_p, d), dqp_dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, d_v), jnp.float32)],
             name="tpudl_flash_bwd_merged",
+            compiler_params=_wide_heads(d, d_v),
             interpret=interpret,
         )(qoff, koff, klen, qf, kf, vf, kmaskf, dof, lsef, deltaf)
         dq = jnp.sum(dqp.astype(jnp.float32), axis=0)
         dq = dq[:, :tq].reshape(b, h, tq, d)
         dk = dk[:, :tk].reshape(b, h, tk, d)
-        dv = dv[:, :tk].reshape(b, h, tk, d)
+        dv = dv[:, :tk].reshape(b, h, tk, d_v)
         return dq, dk, dv
 
+    if d_v != d:
+        raise ValueError(
+            "flash_attention_block_bwd(merged=False) takes q, k and v of one "
+            f"head size, got {d} and {d_v}: only the merged kernel takes a "
+            "value head size of its own")
     smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     stat_spec = pl.BlockSpec((1, block_q, 128), lambda bh, i, j: (bh, i, 0))
@@ -647,7 +672,8 @@ _mha_core.defvjp(_mha_fwd, _mha_bwd)
 def flash_attention(q, k, v, *, n_heads: int, causal: bool = False,
                     key_mask=None, block_q: int = 1024, block_k: int = 1024,
                     interpret: bool | None = None):
-    """Full single-device flash attention: [B, T, H*D] → [B, T, H*D].
+    """Full single-device flash attention: [B, T, H*D] → [B, T, H*D]
+    (``v`` [B, T, H*Dv] with a head size of its own gives [B, T, H*Dv]).
     Normalized output (softmax(QKᵀ/√d)·V) with no [T,T] materialization —
     the libnd4j ``multi_head_dot_product_attention`` replacement for long
     sequences on one chip.  Differentiable: ``jax.grad`` routes through
@@ -657,11 +683,13 @@ def flash_attention(q, k, v, *, n_heads: int, causal: bool = False,
     b, t, dm = q.shape
     tk = k.shape[1]
     dh = dm // n_heads
+    dv = v.shape[2] // n_heads
     qh = q.reshape(b, t, n_heads, dh).transpose(0, 2, 1, 3)
     kh = k.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
-    vh = v.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
+    vh = v.reshape(b, tk, n_heads, dv).transpose(0, 2, 1, 3)
     if key_mask is not None:
         key_mask = jnp.asarray(key_mask, jnp.float32)
     out = _mha_core(qh, kh, vh, key_mask, 1.0 / (dh ** 0.5), causal,
                     block_q, block_k, interpret)
-    return out.transpose(0, 2, 1, 3).reshape(b, t, dm).astype(q.dtype)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, n_heads * dv).astype(
+        q.dtype)

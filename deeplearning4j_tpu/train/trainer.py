@@ -272,6 +272,12 @@ class Trainer:
         # and inside listeners; step_batch zeroes and observes them
         self._dispatch_s = self._read_s = 0.0
         self._step_metrics = None   # (registry, handles): see _metrics()
+        # layers whose state holds the last step's counters as device
+        # scalars (RoutedExperts' routing load): {vertex: STEP_COUNTERS}
+        self._step_counters = {
+            spec.name: spec.obj.STEP_COUNTERS
+            for spec in getattr(net, "_topo", ())
+            if getattr(spec.obj, "STEP_COUNTERS", None)}
         # process-level step-cache identity; None (per-layer updaters,
         # frozen layers, unserializable conf) = build per instance
         self._cache_sig = None
@@ -760,6 +766,26 @@ class Trainer:
             self._step_metrics = (reg, train_loop_metrics(reg))
         return self._step_metrics[1]
 
+    def _fold_step_counters(self, loss) -> None:
+        """Add the step's device-side counters (``_step_counters``) to the
+        registry, but only where it costs no wait: on a step whose loss a
+        listener has converted, the program that wrote them is done.  A
+        step nobody read is skipped, so the totals are of sampled steps."""
+        ready = getattr(loss, "is_ready", None)
+        if ready is None or not ready():
+            return
+        state = self.net.state_
+        read = jax.device_get({name: {key: state[name][key] for key in keys}
+                               for name, keys in self._step_counters.items()})
+        totals: dict = {}
+        for name, keys in self._step_counters.items():
+            for key, metric in keys.items():
+                totals[metric] = totals.get(metric, 0.0) + float(
+                    read[name][key])
+        registry = get_registry()
+        for metric, value in totals.items():
+            registry.counter(metric).inc(value)
+
     def step_batch(self, batch, rng):
         """One training iteration with full semantics: tBPTT routing,
         score tracking, listener dispatch, iteration counter.  Used by
@@ -862,6 +888,8 @@ class Trainer:
                         listener.record_batch(n_examples)
                 self.bus.dispatch("iteration_done", net, net.iteration,
                                   net.epoch, loss)
+                if self._step_counters:
+                    self._fold_step_counters(loss)
             net.iteration += 1
         if retraced == 0:
             # the three together, so that their sums subtract: iteration

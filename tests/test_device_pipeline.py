@@ -313,8 +313,15 @@ def test_feeder_spans_cover_their_work_on_the_thread_that_does_it(registry):
     for s in waits:
         assert s.thread == me and s.parent_id == epoch.span_id
         assert s.end_ns > s.start_ns
-    # the consumer waited for the first batch: source + stage at least
-    assert waits[0].duration_s >= 0.05
+    # the consumer waited for the first batch: from inside its draw (the
+    # producer's thread gets a few ms of head start under a busy
+    # interpreter, so not from the draw's first instant) to the end of
+    # its staging, so the stage's 30 ms at least
+    first_source = min(sources, key=lambda s: s.start_ns)
+    first_stage = min(stages, key=lambda s: s.start_ns)
+    assert waits[0].start_ns < first_source.end_ns
+    assert waits[0].end_ns >= first_stage.end_ns
+    assert waits[0].duration_s >= 0.03
     assert waits[0].attributes["wait_ms"] == pytest.approx(
         waits[0].duration_s * 1e3, abs=1.0)
     for name, least in (("tpudl_data_source_seconds", 0.06),

@@ -353,6 +353,27 @@ LAYER_CASES = {
                                lambda: _cnn_batch(6, 6, 4, 3)),
     # generous capacity: no token drops, so routing is locally constant
     # and the loss is differentiable at the sampled inputs
+    # ---- decoder-only language model layers (nn/layers/decoder.py) -------
+    "rms_norm": ([DenseLayer(n_out=6, activation="tanh"), RMSNorm(),
+                  FF_OUT()],
+                 InputType.feed_forward(4), lambda: _ff_batch(4, 3)),
+    "gated_feed_forward": ([GatedFeedForward(hidden=6, init_std=0.5),
+                            FF_OUT()],
+                           InputType.feed_forward(4), lambda: _ff_batch(4, 3)),
+    "latent_attention": ([LatentAttention(
+        n_heads=2, q_lora_rank=4, kv_lora_rank=4, qk_nope_head_dim=2,
+        qk_rope_head_dim=2, v_head_dim=3, rope_theta=100.0, init_std=0.5),
+        RNN_OUT()],
+        InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
+    "routed_experts": ([RoutedExperts(
+        n_routed_experts=4, experts_held=2, first_expert=1, top_k=2, hidden=6,
+        shared_hidden=6, routed_scaling_factor=2.5, init_std=0.5), FF_OUT()],
+        InputType.feed_forward(4), lambda: _ff_batch(4, 3)),
+    "causal_lm_output": ([EmbeddingSequenceLayer(n_in=7, n_out=5),
+                          CausalLMOutput(n_out=7, init_std=0.5)],
+                         InputType.recurrent(1, 5),
+                         lambda: (lambda ids: DataSet(ids[..., None], ids))(
+                             _r().integers(0, 7, (3, 5)).astype(np.float64))),
     "mixture_of_experts": ([MixtureOfExperts(n_experts=3, hidden=6, top_k=2,
                                              capacity_factor=3.0,
                                              activation="tanh"),
