@@ -64,7 +64,8 @@ class ComputationGraphConfiguration:
     tbptt_back_length: int = 20
     # [[first, last], ...]: runs of vertices (inclusive, contiguous in the
     # topological order) whose activations the backward pass recomputes
-    # from the run's inputs instead of keeping (GraphBuilder.remat)
+    # from the run's inputs instead of keeping; a flash kernel's output and
+    # row statistics are kept all the same (GraphBuilder.remat)
     remat_segments: list = dataclasses.field(default_factory=list)
 
     # ---------------------------------------------------------- topo/types
@@ -177,6 +178,14 @@ class ComputationGraphConfiguration:
         return ComputationGraphConfiguration.from_dict(json.loads(s))
 
 
+def _remat_keeps() -> tuple:
+    """The names a rematerialised run keeps beside its inputs: the flash
+    kernel's two residuals (imported here: a graph without runs never
+    loads Pallas)."""
+    from deeplearning4j_tpu.ops.pallas.flash_attention import REMAT_KEEPS
+    return REMAT_KEEPS
+
+
 class GraphBuilder:
     """``ComputationGraphConfiguration.GraphBuilder`` parity."""
 
@@ -192,9 +201,14 @@ class GraphBuilder:
 
     def remat(self, first: str, last: str) -> "GraphBuilder":
         """Rematerialise the vertices ``first`` .. ``last`` (as added, both
-        included) in the backward pass: only the run's inputs are kept
-        through the forward pass, and ``jax.checkpoint`` recomputes the
-        rest.  One run a decoder block is what lets 8k tokens fit a chip."""
+        included) in the backward pass: the run's inputs are kept through
+        the forward pass and ``jax.checkpoint`` recomputes the rest, but
+        for what only a kernel launch can rebuild: a ``flash_attention``
+        call inside the run keeps its output and its rows' logsumexp
+        (``[B,H,T,Dv]`` and ``[B,H,T]``, 68 MB at 32 heads of 8k tokens),
+        so the kernel runs once a step and not twice.  A run with no such
+        call keeps its inputs alone.  One run a decoder block is what lets
+        8k tokens fit a chip."""
         self._remat.append([first, last])
         return self
 
@@ -391,7 +405,9 @@ class ComputationGraph:
                 return ({n: local[n] for n in keeps},
                         {n: local_masks[n] for n in keeps}, states)
 
-            kept, kept_masks, states = jax.checkpoint(segment)(
+            kept, kept_masks, states = jax.checkpoint(
+                segment, policy=jax.checkpoint_policies.save_only_these_names(
+                    *_remat_keeps()))(
                 {n: params[n] for n in names}, {n: state[n] for n in names},
                 {n: acts[n] for n in reads},
                 {n: act_masks.get(n) for n in reads}, rng)
@@ -431,10 +447,13 @@ class ComputationGraph:
     def trace_attrs(self) -> dict:
         """Model identity attached to the trainer's ``fit`` span
         (``obs.tracing``) — what a trace viewer shows for this run."""
+        runs = len(self.conf.remat_segments)
         return {"model": "ComputationGraph",
                 "vertices": len(self._topo),
                 "layers": len(self.layers),
-                "params": self.num_params() if self.params_ is not None else 0}
+                "params": self.num_params() if self.params_ is not None else 0,
+                "remat_runs": runs,
+                "remat_keeps": list(_remat_keeps()) if runs else []}
 
     def evaluate(self, iterator, top_n: int = 1):
         from deeplearning4j_tpu.evaluation.classification import Evaluation

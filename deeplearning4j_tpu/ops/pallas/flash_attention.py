@@ -24,10 +24,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# the two residuals of ``flash_attention`` that only the forward kernel can
+# make, by the names ``_mha_fwd`` gives them: what a ``jax.checkpoint`` around
+# a call keeps (``nn.graph``'s rematerialised runs), so that the backward pass
+# does not run the kernel a second time.  Outside a checkpoint a name is the
+# identity.
+REMAT_KEEPS = ("tpudl_flash_out", "tpudl_flash_lse")
 
 
 def _kernel(qoff_ref, koff_ref, klen_ref, q_ref, k_ref, v_ref, kmask_ref,
@@ -651,8 +658,11 @@ def _mha_fwd(qh, kh, vh, key_mask, scale, causal, block_q, block_k,
     o, m, l = flash_attention_block(qh, kh, vh, scale=scale, causal=causal,
                                     key_mask=key_mask, block_q=block_q,
                                     block_k=block_k, interpret=interpret)
-    out = (o / jnp.maximum(l[..., None], 1e-20)).astype(qh.dtype)
-    return out, (qh, kh, vh, key_mask, out, flash_lse(m, l))
+    out = checkpoint_name(
+        (o / jnp.maximum(l[..., None], 1e-20)).astype(qh.dtype),
+        REMAT_KEEPS[0])
+    lse = checkpoint_name(flash_lse(m, l), REMAT_KEEPS[1])
+    return out, (qh, kh, vh, key_mask, out, lse)
 
 
 def _mha_bwd(scale, causal, block_q, block_k, interpret, res, dout):
@@ -677,7 +687,12 @@ def flash_attention(q, k, v, *, n_heads: int, causal: bool = False,
     Normalized output (softmax(QKᵀ/√d)·V) with no [T,T] materialization —
     the libnd4j ``multi_head_dot_product_attention`` replacement for long
     sequences on one chip.  Differentiable: ``jax.grad`` routes through
-    the Pallas backward kernels (``flash_attention_block_bwd``).
+    the Pallas backward kernels (``flash_attention_block_bwd``), which
+    read q, k, v, the output and the rows' logsumexp.  Under a
+    ``jax.checkpoint`` whose policy saves ``REMAT_KEEPS`` (a
+    ``GraphBuilder.remat`` run) the last two, ``[B,H,T,Dv]`` in q's dtype
+    and ``[B,H,T]`` float32, are kept through the forward pass and the
+    kernel runs once; under a bare one it runs again in the backward pass.
     ``key_mask``: optional [B, Tk] padding mask (1 = attend).  Cross
     attention (Tk != Tq) is supported."""
     b, t, dm = q.shape

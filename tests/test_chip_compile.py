@@ -95,7 +95,10 @@ def test_joyai_train_step_whole_program(one_chip):
     8,192 tokens, bf16 policy, Adam, lowered from ``make_train_step``.  It
     has to hold the flash kernels and the grouped products (Mosaic calls)
     and fit the chip beside the harness's own copy of the weights: 8.17 GB
-    of parameters and moments and 3.33 GB of temporaries when written."""
+    of parameters and moments and 3.75 GB of temporaries (3.33 GB until
+    PR 39: each of the six rematerialised runs now keeps its flash call's
+    output and row statistics, 69.7 MB, and the compiled step calls the
+    forward kernel 6 times, not 12)."""
     import json
     import os
 
@@ -141,10 +144,13 @@ def test_joyai_train_step_whole_program(one_chip):
     n_params = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
     assert round(n_params / 1e6, 1) == 680.4
     text = compiled.as_text()
-    assert "tpudl_flash_fwd" in text and "tpudl_flash_bwd_merged" in text
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    blocks = config["num_hidden_layers"] + config["num_nextn_predict_layers"]
+    for kernel in ("tpudl_flash_fwd", "tpudl_flash_bwd_merged"):
+        assert sum(kernel in line for line in calls) == blocks == 6, kernel
     assert "ragged-dot" in text
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 3.6e9, mem
+    assert mem.temp_size_in_bytes < 4.0e9, mem
     resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # the harness keeps its own 2.72 GB copy of the weights beside it

@@ -17,9 +17,11 @@ reads (1e-2 and up).
 """
 
 import copy
+import hashlib
 import importlib.util
 import json
 import os
+import re
 import sys
 
 import jax
@@ -96,9 +98,9 @@ def _entry(config, weights):
     return entry
 
 
-def _tokens(config, seed=SEED, batch=BATCH):
+def _tokens(config, seed=SEED, batch=BATCH, seq=SEQ):
     return np.random.default_rng(seed).integers(
-        0, config["vocab_size"], (batch, SEQ), dtype=np.int32)
+        0, config["vocab_size"], (batch, seq), dtype=np.int32)
 
 
 # ---- (a) logits and both losses ---------------------------------------------
@@ -385,18 +387,63 @@ def test_fit_folds_the_routing_counters_only_on_read_steps(float32_policy):
     assert 0 < busiest <= pairs <= (unread + tokens_seen) * 4
 
 
-def test_remat_runs_change_no_number(float32_policy):
+FLASH_SEQ = 1024       # where ``_auto_flash`` takes the kernel (interpret mode)
+RUNS = 4               # 3 blocks + MTP
+
+
+def _loss_and_args(seq, batch):
     config = small_config()
-    net = joyai_llm_flash(config, SEQ, seed=SEED)
+    net = joyai_llm_flash(config, seq, seed=SEED)
     net.init()
-    assert len(net.conf.remat_segments) == 4           # 3 blocks + MTP
-    tokens = jnp.asarray(_tokens(config))
-    loss = make_loss_fn(net)
-    args = (net.params_, net.state_, tokens, tokens, None, None,
-            jax.random.key(0))
-    (with_remat, _), grads = jax.value_and_grad(loss, has_aux=True)(*args)
+    tokens = jnp.asarray(_tokens(config, batch=batch, seq=seq))
+    return net, make_loss_fn(net), (net.params_, net.state_, tokens, tokens,
+                                    None, None, jax.random.key(0))
+
+
+def _bare_checkpoint(monkeypatch):
+    """``jax.checkpoint`` without a policy, as the runs had it until PR 39:
+    a run keeps its inputs and nothing else."""
+    real = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint",
+                        lambda fn, policy=None, **kw: real(fn, **kw))
+
+
+def _lowered_grad(loss, args) -> str:
+    return jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        *args).as_text()
+
+
+def _program(text: str) -> str:
+    """A lowered module as one digest: every function named by its own
+    text with its callees named so first, ``main`` last.  Which inner
+    jitted functions a module shares (``@tril`` once, or ``@tril`` and
+    ``@tril_89`` with one body) follows jax's tracing caches and whether a
+    checkpoint has a policy, and is no difference in the program."""
+    _, *funcs = re.split(r"\n  func\.func ", text)
+    bodies = {re.match(r"(?:public |private )?@(\w+)", f).group(1): f
+              for f in funcs}
+    digest = {}
+    while "main" not in digest:
+        for name, body in bodies.items():
+            if name not in digest and set(re.findall(
+                    r"call @(\w+)", body)) <= digest.keys():
+                body = re.sub(r"call @(\w+)",
+                              lambda m: "call @" + digest[m.group(1)], body)
+                digest[name] = hashlib.sha1(body.replace(
+                    f"@{name}", "@", 1).encode()).hexdigest()
+    return digest["main"]
+
+
+@pytest.mark.parametrize("seq, batch", [(SEQ, BATCH), (FLASH_SEQ, 1)],
+                         ids=["einsum_chain", "flash_kernel"])
+def test_remat_runs_change_no_number(float32_policy, seq, batch):
+    net, loss, args = _loss_and_args(seq, batch)
+    assert len(net.conf.remat_segments) == RUNS
+    (with_remat, _), grads = jax.jit(
+        jax.value_and_grad(loss, has_aux=True))(*args)
     segments, net.conf.remat_segments = net.conf.remat_segments, []
-    (without, _), plain = jax.value_and_grad(loss, has_aux=True)(*args)
+    (without, _), plain = jax.jit(
+        jax.value_and_grad(loss, has_aux=True))(*args)
     net.conf.remat_segments = segments
     assert float(with_remat) == pytest.approx(float(without), rel=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(grads),
@@ -406,6 +453,84 @@ def test_remat_runs_change_no_number(float32_policy):
     from deeplearning4j_tpu.nn.graph import ComputationGraphConfiguration
     again = ComputationGraphConfiguration.from_json(net.conf.to_json())
     assert again.remat_segments == segments
+
+
+@pytest.mark.parametrize("bare, forward_calls", [(False, RUNS),
+                                                 (True, 2 * RUNS)],
+                         ids=["keeps_out_and_lse", "bare_checkpoint"])
+def test_a_remat_run_calls_the_flash_forward_once(float32_policy,
+                                                  monkeypatch, bare,
+                                                  forward_calls):
+    """The count that says the policy engaged: the lowered gradient calls
+    the forward kernel once a run and the merged backward once a run.
+    Under a bare ``jax.checkpoint`` the backward pass rebuilds the kernel's
+    output and row statistics by calling it again."""
+    if bare:
+        _bare_checkpoint(monkeypatch)
+    _, loss, args = _loss_and_args(FLASH_SEQ, 1)
+    text = _lowered_grad(loss, args)
+    calls = re.findall(r"call @flash_attention_block(_bwd)?(?:_\d+)?\(", text)
+    assert calls.count("") == forward_calls
+    assert calls.count("_bwd") == RUNS
+
+
+def test_a_run_without_a_flash_call_lowers_as_under_a_bare_checkpoint(
+        float32_policy, monkeypatch):
+    """Short sequences take the einsum chain: no name is in the run, the
+    policy keeps nothing, and the step is the one a bare checkpoint gave."""
+    net, loss, args = _loss_and_args(SEQ, BATCH)
+    with_policy = _lowered_grad(loss, args)
+    _bare_checkpoint(monkeypatch)
+    assert "flash_attention_block" not in with_policy
+    assert _program(with_policy) == _program(_lowered_grad(loss, args))
+    net.conf.remat_segments = []           # and the digest tells programs apart
+    assert _program(with_policy) != _program(_lowered_grad(loss, args))
+
+
+def test_a_run_keeps_the_flash_output_and_row_statistics_only(
+        float32_policy, monkeypatch, capsys):
+    """What each run saves for its backward pass, beyond its own inputs
+    and constants: the kernel's output ``[B,H,T,v_head_dim]`` and its rows'
+    logsumexp ``[B,H,T]``.  Traced, not run."""
+    from jax.ad_checkpoint import print_saved_residuals
+    net, _, (params, state, tokens, *_) = _loss_and_args(FLASH_SEQ, 1)
+    real = jax.checkpoint
+
+    def listing(fn, **kw):
+        run = real(fn, **kw)
+
+        def call(p, s, acts, masks, rng):
+            print_saved_residuals(lambda p, acts: run(p, s, acts, masks,
+                                                      rng)[0], p, acts)
+            print("run ends")
+            return run(p, s, acts, masks, rng)
+        return call if fn.__name__ == "segment" else run
+
+    monkeypatch.setattr(jax, "checkpoint", listing)
+    jax.eval_shape(lambda p: net._forward(
+        p, state, tokens, train=True, rng=jax.random.key(0),
+        labels=tokens)[2], params)
+    *runs, rest = capsys.readouterr().out.split("run ends\n")
+    assert len(runs) == RUNS and not rest
+    config = small_config()
+    heads, dv = config["num_attention_heads"], config["v_head_dim"]
+    for run in runs:
+        kept = [line.split()[0] for line in run.splitlines() if not re.search(
+            r" from (the argument|a constant|a literal)", line)]
+        assert sorted(kept) == sorted([f"f32[1,{heads},{FLASH_SEQ},{dv}]",
+                                       f"f32[1,{heads},{FLASH_SEQ}]"]), run
+
+
+def test_trace_attrs_carry_the_runs_and_what_they_keep():
+    """The ``fit`` span's attributes say how many runs the step
+    rematerialises and which names their checkpoints keep."""
+    from deeplearning4j_tpu.models import resnet50
+    from deeplearning4j_tpu.ops.pallas.flash_attention import REMAT_KEEPS
+    attrs = joyai_llm_flash(small_config(), SEQ, seed=SEED).trace_attrs()
+    assert attrs["remat_runs"] == RUNS
+    assert attrs["remat_keeps"] == list(REMAT_KEEPS)
+    attrs = resnet50(height=32, width=32, num_classes=10).trace_attrs()
+    assert attrs["remat_runs"] == 0 and attrs["remat_keeps"] == []
 
 
 def test_a_graph_without_runs_or_experts_keeps_its_configuration():
