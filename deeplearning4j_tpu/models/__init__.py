@@ -18,6 +18,7 @@ from deeplearning4j_tpu.models.zoo import (
     vgg19,
     resnet50,
     joyai_llm_flash,
+    kimi_linear,
     lstm_classifier,
     text_gen_lstm,
 )
@@ -35,7 +36,7 @@ from deeplearning4j_tpu.models import bert
 
 __all__ = [
     "mlp_mnist", "lenet", "simple_cnn", "alexnet", "vgg16", "vgg19",
-    "resnet50", "joyai_llm_flash",
+    "resnet50", "joyai_llm_flash", "kimi_linear",
     "lstm_classifier", "text_gen_lstm", "bert",
     "squeezenet", "darknet19", "tiny_yolo", "yolo2", "unet", "xception",
     "inception_resnet_v1", "nasnet_mobile",

@@ -16,6 +16,10 @@ Parity notes per model (reference classes under
   of DeepSeek-V3's kind (latent attention, sigmoid-routed dropless experts
   beside a shared expert, a multi-token-prediction module) from the keys of
   its published ``config.json``, as a ComputationGraph of ``nn.layers.decoder``.
+- ``kimi_linear`` has none either: Kimi-Linear's hybrid of Kimi Delta
+  Attention (a gated delta rule run as a chunked scan) in three blocks of
+  four and latent attention without positions in the fourth, the same
+  routed experts, from its published ``config.json``.
 - ``mlp_mnist`` / ``lstm_classifier`` → dl4j-examples workloads named in
   BASELINE.json (MLPMnistTwoLayerExample; UCI HAR sequence classification).
 
@@ -37,8 +41,8 @@ from deeplearning4j_tpu.nn.layers import (
     ZeroPaddingLayer,
 )
 from deeplearning4j_tpu.nn.layers import (
-    CausalLMOutput, EmbeddingSequenceLayer, GatedFeedForward, LatentAttention,
-    RMSNorm, RoutedExperts,
+    CausalLMOutput, DeltaAttention, EmbeddingSequenceLayer, GatedFeedForward,
+    LatentAttention, RMSNorm, RoutedExperts,
 )
 from deeplearning4j_tpu.nn.weights import distribution
 from deeplearning4j_tpu.nn.vertices import (
@@ -254,38 +258,72 @@ def resnet50(seed: int = 123, num_classes: int = 1000, height: int = 224,
 
 
 # ------------------------------------------------------- decoder-only LMs
-def _decoder_block(gb, name, x, c, *, routed: bool, std: float):
-    """One pre-norm block under ``name``: RMS norm, latent attention, add;
-    RMS norm, gated feed-forward or routed experts, add.  Rematerialised
-    as one run.  Returns the name of its output vertex."""
-    eps = c["rms_norm_eps"]
+def _decoder_block(gb, name, x, *, attention, ffn, eps: float,
+                   split_run: bool = False):
+    """One pre-norm block under ``name``: RMS norm, ``attention`` (the
+    layer the model places there), add; RMS norm, ``ffn`` (gated
+    feed-forward or routed experts), add.  Rematerialised as one run, or
+    with ``split_run`` as two, the attention's half and the
+    feed-forward's: the backward pass then holds one half's recomputed
+    activations at a time and keeps one more ``[B, T, hidden]`` array a
+    block.  Returns the name of its output vertex."""
     gb.add_layer(f"{name}_attn_norm", RMSNorm(eps=eps), x)
-    gb.add_layer(f"{name}_attn", LatentAttention(
-        n_heads=c["num_attention_heads"], q_lora_rank=c["q_lora_rank"],
-        kv_lora_rank=c["kv_lora_rank"],
-        qk_nope_head_dim=c["qk_nope_head_dim"],
-        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
-        rope_theta=float(c["rope_theta"]), eps=eps, init_std=std),
-        f"{name}_attn_norm")
+    gb.add_layer(f"{name}_attn", attention, f"{name}_attn_norm")
     gb.add_vertex(f"{name}_attn_add", ElementWiseVertex(op="add"), x,
                   f"{name}_attn")
     gb.add_layer(f"{name}_ffn_norm", RMSNorm(eps=eps), f"{name}_attn_add")
-    if routed:
-        ffn = RoutedExperts(
-            n_routed_experts=c["n_routed_experts"],
-            experts_held=c.get("experts_held", 0),
-            first_expert=c.get("first_expert", 0),
-            top_k=c["num_experts_per_tok"], hidden=c["moe_intermediate_size"],
-            shared_hidden=c["moe_intermediate_size"] * c["n_shared_experts"],
-            routed_scaling_factor=c["routed_scaling_factor"],
-            norm_topk_prob=c["norm_topk_prob"], init_std=std)
-    else:
-        ffn = GatedFeedForward(hidden=c["intermediate_size"], init_std=std)
     gb.add_layer(f"{name}_ffn", ffn, f"{name}_ffn_norm")
     gb.add_vertex(f"{name}_out", ElementWiseVertex(op="add"),
                   f"{name}_attn_add", f"{name}_ffn")
-    gb.remat(f"{name}_attn_norm", f"{name}_out")
+    if split_run:
+        gb.remat(f"{name}_attn_norm", f"{name}_attn_add")
+        gb.remat(f"{name}_ffn_norm", f"{name}_out")
+    else:
+        gb.remat(f"{name}_attn_norm", f"{name}_out")
     return f"{name}_out"
+
+
+def _lm_graph(c: dict, seq_len: int, seed: int, updater, std: float):
+    """The builder of a decoder-only language model's graph with its
+    embedding placed: one input of ``[B, seq_len]`` ids, vertex ``embed``."""
+    gb = (NeuralNetConfiguration.builder()
+          .seed(seed)
+          .updater(updater or Adam(1e-4))
+          .graph()
+          .add_inputs("tokens")
+          .set_input_types(InputType.recurrent(1, seq_len)))
+    gb.add_layer("embed", EmbeddingSequenceLayer(
+        n_in=c["vocab_size"], n_out=c["hidden_size"], has_bias=False,
+        weight_init=distribution("normal", std=std)), "tokens")
+    return gb
+
+
+def _latent_attention(c: dict, std: float, rotary: bool = True):
+    """Latent attention from the keys DeepSeek-V3's family shares; a
+    published ``q_lora_rank`` of null is one query matrix."""
+    return LatentAttention(
+        n_heads=c["num_attention_heads"], q_lora_rank=c["q_lora_rank"] or 0,
+        kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_theta=float(c["rope_theta"]), rotary=rotary,
+        eps=c["rms_norm_eps"], init_std=std)
+
+
+def _feed_forward(c: dict, std: float, *, routed: bool, experts: int,
+                  top_k: int, shared: int, normalize: bool):
+    """A block's feed-forward: routed experts (the chip's share from the
+    keys ``experts_held`` and ``first_expert``) or the dense SwiGLU.  What
+    the two published families name differently comes as arguments."""
+    if not routed:
+        return GatedFeedForward(hidden=c["intermediate_size"], init_std=std)
+    return RoutedExperts(
+        n_routed_experts=experts, experts_held=c.get("experts_held", 0),
+        first_expert=c.get("first_expert", 0), top_k=top_k,
+        hidden=c["moe_intermediate_size"],
+        shared_hidden=c["moe_intermediate_size"] * shared,
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=normalize, init_std=std)
 
 
 def joyai_llm_flash(config: dict, seq_len: int, seed: int = 123,
@@ -305,26 +343,26 @@ def joyai_llm_flash(config: dict, seq_len: int, seed: int = 123,
     ``first_expert`` on; ``vocab_size`` is the slice held.  Input and
     labels are the same ``[B, seq_len]`` int32 ids: ``net.fit`` over
     ``DataSet(ids, ids)``."""
-    c, h = config, config["hidden_size"]
+    c, h, eps = config, config["hidden_size"], config["rms_norm_eps"]
     if c["num_nextn_predict_layers"] not in (0, 1):
         raise ValueError("one multi-token-prediction module at most")
     if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
         raise ValueError("group-limited routing is not built: n_group and "
                          "topk_group have to be 1")
-    gb = (NeuralNetConfiguration.builder()
-          .seed(seed)
-          .updater(updater or Adam(1e-4))
-          .graph()
-          .add_inputs("tokens")
-          .set_input_types(InputType.recurrent(1, seq_len)))
-    gb.add_layer("embed", EmbeddingSequenceLayer(
-        n_in=c["vocab_size"], n_out=h, has_bias=False,
-        weight_init=distribution("normal", std=init_std)), "tokens")
+
+    def block(name, x, routed):
+        return _decoder_block(
+            gb, name, x, attention=_latent_attention(c, init_std),
+            ffn=_feed_forward(c, init_std, routed=routed,
+                              experts=c["n_routed_experts"],
+                              top_k=c["num_experts_per_tok"],
+                              shared=c["n_shared_experts"],
+                              normalize=c["norm_topk_prob"]), eps=eps)
+
+    gb = _lm_graph(c, seq_len, seed, updater, init_std)
     x = "embed"
     for n in range(c["num_hidden_layers"]):
-        x = _decoder_block(gb, f"l{n}", x, c, std=init_std,
-                           routed=n >= c["first_k_dense_replace"])
-    eps = c["rms_norm_eps"]
+        x = block(f"l{n}", x, n >= c["first_k_dense_replace"])
     gb.add_layer("final_norm", RMSNorm(eps=eps), x)
     streams = ["final_norm"]
     if c["num_nextn_predict_layers"]:
@@ -335,14 +373,74 @@ def joyai_llm_flash(config: dict, seq_len: int, seed: int = 123,
         gb.add_layer("mtp_proj", DenseLayer(
             n_out=h, has_bias=False, activation="identity",
             weight_init=distribution("normal", std=init_std)), "mtp_merge")
-        y = _decoder_block(gb, "mtp", "mtp_proj", c, routed=True,
-                           std=init_std)
+        y = block("mtp", "mtp_proj", True)
         gb.add_layer("mtp_final_norm", RMSNorm(eps=eps), y)
         streams.append("mtp_final_norm")
     gb.add_vertex("streams", StackVertex(), *streams)
     gb.add_layer("lm_head", CausalLMOutput(
         n_out=c["vocab_size"], n_streams=len(streams), mtp_weight=mtp_weight,
         init_std=init_std), "streams")
+    gb.set_outputs("lm_head")
+    return ComputationGraph(gb.build())
+
+
+def kimi_linear(config: dict, seq_len: int, seed: int = 123, updater=None,
+                init_std: float = 0.02, kda_chunk: int = 64
+                ) -> ComputationGraph:
+    """Kimi-Linear (``model_type`` ``kimi_linear``, 48B-A3B) from the keys
+    of its published ``config.json``.  ``linear_attn_config`` says, layer
+    by layer and counting from 1, which attention a block holds: Kimi
+    Delta Attention (``kda_layers``: ``num_heads`` heads of ``head_dim``
+    behind a short convolution of ``short_conv_kernel_size`` taps, run in
+    chunks of ``kda_chunk``) or latent attention (``full_attn_layers``:
+    ``q_lora_rank`` null is one query matrix, ``mla_use_nope`` no rotary
+    positions: the KDA layers carry the order).  The first
+    ``first_k_dense_replace`` blocks have a dense SwiGLU, the others
+    ``num_experts`` sigmoid-routed experts (``num_experts_per_token``,
+    ``moe_renormalize``, ``routed_scaling_factor``) beside
+    ``num_shared_experts`` shared ones; an untied head, no multi-token
+    prediction.  Blocks are ``l1`` .. ``l<num_hidden_layers>``, as the
+    config counts them.
+
+    A chip's share of an expert-parallel deployment is two more keys, as
+    for :func:`joyai_llm_flash`: ``experts_held`` from ``first_expert``
+    on, and ``vocab_size`` the slice held.  ``net.fit`` over
+    ``DataSet(ids, ids)``."""
+    c, eps = config, config["rms_norm_eps"]
+    linear = c["linear_attn_config"]
+    if c["moe_router_activation_func"] != "sigmoid":
+        raise ValueError("only sigmoid router scores are built, the config "
+                         f"states {c['moe_router_activation_func']!r}")
+    if c.get("num_expert_group", 1) != 1 or c.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing is not built: "
+                         "num_expert_group and topk_group have to be 1")
+    if c.get("num_nextn_predict_layers", 0):
+        raise ValueError("kimi_linear has no multi-token-prediction module")
+    gb = _lm_graph(c, seq_len, seed, updater, init_std)
+    x = "embed"
+    for n in range(1, c["num_hidden_layers"] + 1):
+        if n in linear["kda_layers"]:
+            attention = DeltaAttention(
+                n_heads=linear["num_heads"], head_dim=linear["head_dim"],
+                conv_taps=linear["short_conv_kernel_size"], chunk=kda_chunk,
+                eps=eps, init_std=init_std)
+        elif n in linear["full_attn_layers"]:
+            attention = _latent_attention(c, init_std,
+                                          rotary=not c["mla_use_nope"])
+        else:
+            raise ValueError(f"linear_attn_config names layer {n} in neither "
+                             f"kda_layers nor full_attn_layers")
+        ffn = _feed_forward(c, init_std,
+                            routed=n > c["first_k_dense_replace"],
+                            experts=c["num_experts"],
+                            top_k=c["num_experts_per_token"],
+                            shared=c["num_shared_experts"],
+                            normalize=c["moe_renormalize"])
+        x = _decoder_block(gb, f"l{n}", x, attention=attention, ffn=ffn,
+                           eps=eps, split_run=True)
+    gb.add_layer("final_norm", RMSNorm(eps=eps), x)
+    gb.add_layer("lm_head", CausalLMOutput(
+        n_out=c["vocab_size"], init_std=init_std), "final_norm")
     gb.set_outputs("lm_head")
     return ComputationGraph(gb.build())
 

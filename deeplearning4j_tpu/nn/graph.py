@@ -448,12 +448,25 @@ class ComputationGraph:
         """Model identity attached to the trainer's ``fit`` span
         (``obs.tracing``) — what a trace viewer shows for this run."""
         runs = len(self.conf.remat_segments)
-        return {"model": "ComputationGraph",
-                "vertices": len(self._topo),
-                "layers": len(self.layers),
-                "params": self.num_params() if self.params_ is not None else 0,
-                "remat_runs": runs,
-                "remat_keeps": list(_remat_keeps()) if runs else []}
+        attrs = {"model": "ComputationGraph",
+                 "vertices": len(self._topo),
+                 "layers": len(self.layers),
+                 "params": self.num_params() if self.params_ is not None
+                 else 0,
+                 "remat_runs": runs,
+                 "remat_keeps": list(_remat_keeps()) if runs else []}
+        # a decoder's attention layers name their kind, in order; a model
+        # without any carries neither key
+        attention = [layer for layer in self.layers
+                     if hasattr(layer, "ATTENTION_KIND")]
+        if attention:
+            attrs["attention_kinds"] = [layer.ATTENTION_KIND
+                                        for layer in attention]
+        chunks = sorted({layer.chunk for layer in attention
+                         if layer.ATTENTION_KIND == "kda"})
+        if chunks:
+            attrs["kda_chunk"] = chunks[0] if len(chunks) == 1 else chunks
+        return attrs
 
     def evaluate(self, iterator, top_n: int = 1):
         from deeplearning4j_tpu.evaluation.classification import Evaluation

@@ -66,6 +66,7 @@ from deeplearning4j_tpu.nn.layers.decoder import (
     RMSNorm,
     GatedFeedForward,
     LatentAttention,
+    DeltaAttention,
     RoutedExperts,
     CausalLMOutput,
 )
@@ -114,7 +115,8 @@ __all__ = [
     "TimeDistributed", "RnnOutputLayer", "RnnLossLayer",
     "SelfAttentionLayer", "LearnedSelfAttentionLayer",
     "LayerNormalization", "PReLULayer",
-    "RMSNorm", "GatedFeedForward", "LatentAttention", "RoutedExperts",
+    "RMSNorm", "GatedFeedForward", "LatentAttention", "DeltaAttention",
+    "RoutedExperts",
     "CausalLMOutput",
     "ZeroPadding1DLayer", "Cropping1DLayer", "Upsampling1DLayer",
     "ZeroPadding3DLayer", "Cropping3DLayer", "Upsampling3DLayer",

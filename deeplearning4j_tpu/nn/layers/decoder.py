@@ -3,17 +3,21 @@
 No reference parity: deeplearning4j stops at 2018's encoder.  These are
 the blocks of DeepSeek-V3's family as its published configurations name
 them: RMS norm, the gated (SwiGLU) feed-forward, latent attention (MLA)
-with rotary positions inside it, routed experts beside a shared expert
-with a layer that is told which experts it holds, and the next-token
-output layer that owns the head once for the main and the
-multi-token-prediction stream.  ``models.zoo.joyai_llm_flash`` wires them
-into a ``ComputationGraph``.
+with rotary positions inside it or none at all, routed experts beside a
+shared expert with a layer that is told which experts it holds, and the
+next-token output layer that owns the head once for the main and the
+multi-token-prediction stream; and Kimi Linear's delta attention (KDA,
+arXiv:2510.26692): a gated delta rule whose state is carried along the
+sequence, run as a chunked scan.  ``models.zoo.joyai_llm_flash`` and
+``models.zoo.kimi_linear`` wire them into a ``ComputationGraph``.
 
 Precision follows the dtype policy as ``DenseLayer`` does (float32
 parameters cast to the compute dtype at use, outputs in the output
 dtype); norm reductions, rotary angles, the router's logits and gates,
 and the loss are float32 at least, whatever the policy (float64 under the
-gradient checks' float64 policy).
+gradient checks' float64 policy); so are delta attention's decay, its
+cumulative sums and exponentials, ``beta``, the inversion inside a chunk
+and the state.
 """
 
 from __future__ import annotations
@@ -123,9 +127,14 @@ class LatentAttention(Layer):
     sqrt(nope + rope)) v``; ``W_o``.  Keys and queries are ``nope + rope``
     wide and values ``v_head_dim``: ``ops.attention`` (the flash kernel
     from 1,024 tokens on, the einsum chain below) takes the two sizes
-    apart."""
+    apart.
+
+    ``q_lora_rank`` 0 (a published ``null``) is one ``W_q`` and no query
+    norm; ``rotary`` False (``mla_use_nope``) rotates nothing: the layer
+    then knows no position, and layers beside it carry the order."""
 
     INPUT_KIND = "rnn"
+    ATTENTION_KIND = "mla"            # ``ComputationGraph.trace_attrs``
 
     n_heads: int = 1
     q_lora_rank: int = 0
@@ -134,15 +143,18 @@ class LatentAttention(Layer):
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_theta: float = 10000.0
+    rotary: bool = True
     eps: float = 1e-6
     init_std: float = 0.02
 
     def init_params(self, key, input_type):
         d, dt, h = input_type.size, self._param_dtype(), self.n_heads
         qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        queries = ({"W_qa": (d, self.q_lora_rank),
+                    "W_qb": (self.q_lora_rank, h * qk)}
+                   if self.q_lora_rank else {"W_q": (d, h * qk)})
         shapes = {
-            "W_qa": (d, self.q_lora_rank),
-            "W_qb": (self.q_lora_rank, h * qk),
+            **queries,
             "W_kva": (d, self.kv_lora_rank + self.qk_rope_head_dim),
             "W_kvb": (self.kv_lora_rank,
                       h * (self.qk_nope_head_dim + self.v_head_dim)),
@@ -151,7 +163,8 @@ class LatentAttention(Layer):
         keys = jax.random.split(key, len(shapes))
         params = {name: _normal(k, shape, self.init_std, dt)
                   for k, (name, shape) in zip(keys, shapes.items())}
-        params["q_norm"] = jnp.ones((self.q_lora_rank,), dt)
+        if self.q_lora_rank:
+            params["q_norm"] = jnp.ones((self.q_lora_rank,), dt)
         params["kv_norm"] = jnp.ones((self.kv_lora_rank,), dt)
         return params
 
@@ -160,19 +173,26 @@ class LatentAttention(Layer):
         h, nope, rope = (self.n_heads, self.qk_nope_head_dim,
                          self.qk_rope_head_dim)
         with jax.named_scope("mla"):
-            c_q = rms_norm(_linear(x, params["W_qa"]), params["q_norm"],
-                           self.eps)
-            q = _linear(c_q, params["W_qb"]).reshape(b, t, h, nope + rope)
+            if self.q_lora_rank:
+                c_q = rms_norm(_linear(x, params["W_qa"]), params["q_norm"],
+                               self.eps)
+                q = _linear(c_q, params["W_qb"])
+            else:
+                q = _linear(x, params["W_q"])
+            q = q.reshape(b, t, h, nope + rope)
             kv = _linear(x, params["W_kva"])
             c_kv = rms_norm(kv[..., :self.kv_lora_rank], params["kv_norm"],
                             self.eps)
-            k_r = rotate_interleaved(kv[..., self.kv_lora_rank:],
-                                     self.rope_theta)
+            k_r = kv[..., self.kv_lora_rank:]
+            if self.rotary:
+                k_r = rotate_interleaved(k_r, self.rope_theta)
             kvh = _linear(c_kv, params["W_kvb"]).reshape(
                 b, t, h, nope + self.v_head_dim)
-            q = jnp.concatenate(
-                [q[..., :nope],
-                 rotate_interleaved(q[..., nope:], self.rope_theta)], axis=-1)
+            if self.rotary:
+                q = jnp.concatenate(
+                    [q[..., :nope],
+                     rotate_interleaved(q[..., nope:], self.rope_theta)],
+                    axis=-1)
             k = jnp.concatenate(
                 [kvh[..., :nope],
                  jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, rope))],
@@ -181,6 +201,326 @@ class LatentAttention(Layer):
                 q.reshape(b, t, -1), k.reshape(b, t, -1),
                 kvh[..., nope:].reshape(b, t, -1), n_heads=h, causal=True)
             return _linear(ctx, params["W_o"]), state
+
+
+def short_conv(x, w):
+    """Causal depthwise convolution along axis 1, then SiLU: ``y[t, c] =
+    silu(sum_j w[c, j] * x[t - (K - 1) + j, c])`` with zeros before ``t =
+    0``, so position ``t`` reads ``t - K + 1 .. t``.  ``x`` ``[B, T, P]``,
+    ``w`` ``[P, K]``; summed in float32 and handed on so."""
+    taps, t, wide = w.shape[-1], x.shape[1], _wide(x.dtype)
+    padded = jnp.pad(x.astype(wide), ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(wide)
+    return jax.nn.silu(sum(padded[:, j:j + t] * w[:, j]
+                           for j in range(taps)))
+
+
+def _compute_dot(spec: str, a, b):
+    """An einsum with its operands in the compute dtype, summed in float32."""
+    cd = dtype_policy().compute_dtype
+    return jnp.einsum(spec, a.astype(cd), b.astype(cd),
+                      preferred_element_type=_wide(cd))
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for strictly lower-triangular ``a`` ``[..., C, C]``,
+    ``C`` a power of two, by doubling: the inverse of ``[[P, 0], [R, S]]``
+    is ``[[P^-1, 0], [-S^-1 R P^-1, S^-1]]``, from 1x1 blocks up.  Forward
+    substitution in block form: no power of ``a`` is ever taken, so equal
+    keys without decay (``a`` all ones, whose 32nd power holds 1e17)
+    invert as exactly as anything else.  In ``a``'s dtype, products at
+    ``Precision.HIGHEST``."""
+    c, lead = a.shape[-1], a.shape[:-2]
+    hi = jax.lax.Precision.HIGHEST
+    inv, s = jnp.ones(lead + (c, 1, 1), a.dtype), 1
+    while s < c:
+        n = c // (2 * s)
+        blocks = a.reshape(lead + (n, 2, s, n, 2, s))
+        # block (2p + 1, 2p) of every pair p: the diagonal over the two n
+        r = jnp.moveaxis(jnp.diagonal(blocks[..., :, 1, :, :, 0, :],
+                                      axis1=-4, axis2=-2), -1, -3)
+        pair = inv.reshape(lead + (n, 2, s, s))
+        upper, lower = pair[..., 0, :, :], pair[..., 1, :, :]
+        corner = -jnp.einsum("...ij,...jk,...kl->...il", lower, r, upper,
+                             precision=hi)
+        inv = jnp.concatenate(
+            [jnp.concatenate([upper, jnp.zeros_like(upper)], axis=-1),
+             jnp.concatenate([corner, lower], axis=-1)], axis=-2)
+        s *= 2
+    return inv[..., 0, :, :]
+
+
+# A chunk's rows are split four ways, and each part four ways again,
+# down to PAIRWISE rows (chunked_delta_rule, _decayed_scores)
+SPLIT, PAIRWISE = 4, 4
+
+
+@jax.checkpoint
+def _pairwise_scores(rows, k, g_sum):
+    """:func:`_decayed_scores` for a few rows, the decays taken pairwise in
+    float32: column ``j`` is ``sum_d rows[i, d] k[j, d] exp(G[i, d] - G[j,
+    d])`` for ``i >= j``, one pass over ``[..., P, d]`` a column, so that
+    no ``[P, P, d]`` array is ever formed; rematerialised, so that the
+    backward pass keeps no exponential either."""
+    size = k.shape[-2]
+    later = jnp.arange(size)[:, None] >= jnp.arange(size)[None, :]
+    return jnp.stack(
+        [jnp.sum(rows * k[..., j:j + 1, :] * jnp.exp(jnp.where(
+            later[:, j:j + 1], g_sum - g_sum[..., j:j + 1, :], -jnp.inf)),
+            axis=-1) for j in range(size)], axis=-1)
+
+
+def _decayed_scores(rows, k, g_sum):
+    """``sum_d rows[i, d] k[j, d] exp(G[i, d] - G[j, d])`` for ``j <= i``,
+    zero above the diagonal.  ``rows`` ``[n, ..., P, d]`` (``n`` sets of
+    rows scored against the same keys), ``k`` and ``g_sum`` (``G``, the
+    decay's running sum, falling along ``P``) ``[..., P, d]`` -> ``[n,
+    ..., P, P]`` float32.
+
+    ``exp(G_i - G_j)`` is never split into ``exp(G_i) exp(-G_j)`` over
+    all the rows: the second factor overflows float32 at decays a layer
+    starts with.  The rows are split into ``SPLIT`` parts.  A later part
+    against the rows before it is split about the later part's first row
+    ``a``: ``exp(G_i - G_a)`` and ``exp(G_a - G_j)``, both exponents <= 0,
+    each a matrix product's operand.  A part against itself is this
+    function again, down to ``PAIRWISE`` rows, where the difference is
+    taken as it stands."""
+    size, d = k.shape[-2:]
+    if size <= PAIRWISE:
+        return _pairwise_scores(rows, k, g_sum)
+    sub = size // SPLIT
+    tiled = k.shape[:-2] + (SPLIT, sub, d)
+    rows_t = rows.reshape(rows.shape[:1] + tiled)
+    g_t = g_sum.reshape(tiled)
+    within = _decayed_scores(rows_t, k.reshape(tiled), g_t)
+    left = rows_t * jnp.exp(g_t - g_t[..., :1, :])
+    out = []
+    for a in range(SPLIT):
+        before, parts = a * sub, [within[..., a, :, :]]
+        if before:
+            right = k[..., :before, :] * jnp.exp(
+                g_t[..., a, :1, :] - g_sum[..., :before, :])
+            parts.insert(0, _compute_dot("n...id,...jd->n...ij",
+                                         left[..., a, :, :], right))
+        if size - before - sub:
+            parts.append(jnp.zeros(within.shape[:-3]
+                                   + (sub, size - before - sub),
+                                   within.dtype))
+        out.append(jnp.concatenate(parts, axis=-1))
+    return jnp.concatenate(out, axis=-2)
+
+
+HEAD_GROUP = 8      # heads whose chunk phase is live at once
+
+
+def _map_head_groups(fn, arrays, head_axes, head_group: int):
+    """``fn`` over groups of ``head_group`` heads, one group after the
+    other and each rematerialised: ``arrays[i]`` has its heads on axis
+    ``head_axes[i]``, and ``fn`` gets every array with that axis cut to
+    one group.  Results come stacked, the groups first.  Of the two dozen
+    ``[T, h d]`` float32 arrays a group's phase makes only one group's
+    are ever live (8,192 tokens of 32 heads of 128 are 134 MB an array).
+    Heads that do not divide into such groups are one group."""
+    heads = arrays[0].shape[head_axes[0]]
+    if heads % head_group:
+        head_group = heads
+
+    def grouped(x, axis):
+        x = x.reshape(x.shape[:axis] + (x.shape[axis] // head_group,
+                                        head_group) + x.shape[axis + 1:])
+        return jnp.moveaxis(x, axis, 0)
+
+    return jax.lax.map(jax.checkpoint(lambda xs: fn(*xs)), tuple(
+        grouped(x, axis) for x, axis in zip(arrays, head_axes)))
+
+
+def _chunk_phase(q, k, v, g, beta, *, chunk: int):
+    """What :func:`chunked_delta_rule` computes of every chunk without the
+    state, for a group of heads: ``[B, T, h, ...]`` -> ``W``, ``U~``,
+    ``K exp(G_C - G)``, ``exp(G_C)`` (the scan's inputs) and ``Q exp(G)``,
+    ``B`` (the read's), each ``[B, h, n, chunk, ...]``.  What only ever
+    is a matrix product's operand leaves in the compute dtype."""
+    if chunk & (chunk - 1) or chunk < PAIRWISE:
+        raise ValueError(f"chunk has to be a power of two of at least "
+                         f"{PAIRWISE}, got {chunk}")
+    b, t = k.shape[:2]
+    n, wide, cd = -(-t // chunk), g.dtype, dtype_policy().compute_dtype
+
+    def chunks(x):
+        """[B, T, h, ...] -> [B, h, n, chunk, ...], zero-padded, wide."""
+        x = jnp.pad(x.astype(wide), ((0, 0), (0, n * chunk - t))
+                    + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 3, 1)
+
+    with jax.named_scope("kda.chunk"):
+        q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+        beta = chunks(beta)[..., None]                   # [B, h, n, C, 1]
+        g_sum = jnp.cumsum(g, axis=-2)
+        g_end = g_sum[..., -1:, :]
+        b_qk, a_kk = _decayed_scores(jnp.stack([q, k]), k, g_sum)
+        strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+        solve = _unit_lower_inverse(jnp.where(strict, beta * a_kk, 0.0))
+        u_free = _compute_dot("...ij,...jd->...id", solve, beta * v)
+        w = _compute_dot("...ij,...jd->...id", solve,
+                         beta * k * jnp.exp(g_sum))
+        k_left = k * jnp.exp(g_end - g_sum)     # what is left at the end
+        decay = jnp.exp(g_end[..., 0, :])[..., None]     # [B, h, n, dk, 1]
+        return (w.astype(cd), u_free, k_left.astype(cd), decay,
+                (q * jnp.exp(g_sum)).astype(cd), b_qk.astype(cd))
+
+
+def _scan_and_read(w, u_free, k_left, decay, q_decayed, b_qk, *, t: int):
+    """The state through the chunks, then every chunk's outputs, from
+    :func:`_chunk_phase`'s results stacked ``[groups, B, h, n, chunk,
+    ...]`` -> ``o`` ``[B, t, H, dv]`` and the last state ``[B, H, dk,
+    dv]``."""
+    groups, b, hg, n, chunk, dk = w.shape
+    dv, wide, cd = u_free.shape[-1], u_free.dtype, w.dtype
+    with jax.named_scope("kda.scan"):
+        def step(state, chunk_in):
+            w_c, u_c, k_c, decay_c = chunk_in
+            entry = state.astype(cd)        # the state as products read it
+            u = u_c - _compute_dot("...id,...de->...ie", w_c, entry)
+            return (decay_c * state
+                    + _compute_dot("...id,...ie->...de", k_c, u)), (entry, u)
+
+        last, (entry, u) = jax.lax.scan(
+            step, jnp.zeros((groups, b, hg, dk, dv), wide),
+            tuple(jnp.moveaxis(x, 3, 0) for x in (w, u_free, k_left, decay)))
+
+    with jax.named_scope("kda.read"):
+        entry, u = jnp.moveaxis(entry, 0, 3), jnp.moveaxis(u, 0, 3)
+        o = _compute_dot("...id,...de->...ie", q_decayed, entry) \
+            + _compute_dot("...ij,...je->...ie", b_qk, u)
+        # [groups, B, h, n, C, dv] -> [B, n, C, groups, h, dv]
+        o = jnp.transpose(o, (1, 3, 4, 0, 2, 5)).reshape(
+            b, n * chunk, groups * hg, dv)[:, :t]
+    return o, jnp.moveaxis(last, 0, 1).reshape(b, groups * hg, dk, dv)
+
+
+def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
+                       head_group: int = HEAD_GROUP):
+    """The gated delta rule with a decay a key channel (Kimi Delta
+    Attention, arXiv:2510.26692), in chunks.  A head's state ``S``
+    ``[d_k, d_v]`` starts at zero and follows ``S'_t = Diag(exp(g_t))
+    S_{t-1}``, ``S_t = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T``, ``o_t =
+    S_t^T q_t``.
+
+    ``q``, ``k``, ``g`` ``[B, T, H, d_k]`` (``g`` the log decay, <= 0,
+    float32 or wider), ``v`` ``[B, T, H, d_v]``, ``beta`` ``[B, T, H]``
+    -> ``o`` ``[B, T, H, d_v]`` and the last state ``[B, H, d_k, d_v]``,
+    in ``g``'s dtype.
+
+    With ``G`` the running sum of ``g`` inside a chunk, ``A_ij = beta_i
+    sum_d k_id k_jd exp(G_id - G_jd)`` (``j < i``) and ``B_ij`` the same
+    with ``q_i`` and no ``beta`` (``j <= i``): ``T = (I + A)^-1``, ``U~ =
+    T (beta V)``, ``W = T (beta K exp(G))``, for all chunks at once
+    (:func:`_chunk_phase`, ``head_group`` heads at a time); then chunk by
+    chunk, ``S`` the state on entry, ``U = U~ - W S`` and ``S <-
+    Diag(exp(G_C)) S + (K exp(G_C - G))^T U`` (the only sequential part:
+    ``T / chunk`` steps of two products); and again for all chunks at
+    once ``O = (Q exp(G)) S + B U`` (:func:`_scan_and_read`).  Every
+    exponent is <= 0 as written.  Matrix products take their operands in
+    the compute dtype and sum in float32; the inversion, the decays and
+    the state stay float32.  A length that is no multiple of ``chunk`` is
+    padded with tokens that change nothing (``k = 0``, ``g = 0``)."""
+    return _scan_and_read(*_map_head_groups(
+        functools.partial(_chunk_phase, chunk=chunk), (q, k, v, g, beta),
+        (2,) * 5, head_group), t=k.shape[1])
+
+
+@register_layer("delta_attention")
+@dataclasses.dataclass
+class DeltaAttention(Layer):
+    """Kimi Delta Attention (KDA): linear attention whose per-head state
+    ``[head_dim, head_dim]`` follows a gated delta rule along the
+    sequence, causal by construction and with no other position signal.
+
+    ``q, k, v = short_conv(x W_q), short_conv(x W_k), short_conv(x W_v)``
+    (depthwise, causal, ``conv_taps`` taps, SiLU); per head ``q = q /
+    ||q|| / sqrt(head_dim)``, ``k = k / ||k||``; the log decay a key
+    channel ``g = -exp(A_log) softplus(x W_fa W_fb + dt_bias)``; ``beta =
+    sigmoid(x W_beta)`` a head; :func:`chunked_delta_rule`; then a per-head
+    RMS norm (one ``head_dim``-wide scale for all heads) gated by
+    ``sigmoid(x W_ga W_gb)``, and ``W_o``.  Both low-rank gates have rank
+    ``head_dim``."""
+
+    INPUT_KIND = "rnn"
+    ATTENTION_KIND = "kda"            # ``ComputationGraph.trace_attrs``
+
+    n_heads: int = 1
+    head_dim: int = 0
+    conv_taps: int = 4
+    chunk: int = 64
+    eps: float = 1e-5
+    init_std: float = 0.02
+
+    def init_params(self, key, input_type):
+        d, dt, h = input_type.size, self._param_dtype(), self.n_heads
+        p, rank = h * self.head_dim, self.head_dim
+        shapes = {"W_q": (d, p), "W_k": (d, p), "W_v": (d, p),
+                  "conv_q": (p, self.conv_taps), "conv_k": (p, self.conv_taps),
+                  "conv_v": (p, self.conv_taps),
+                  "W_fa": (d, rank), "W_fb": (rank, p), "W_beta": (d, h),
+                  "W_ga": (d, rank), "W_gb": (rank, p), "W_o": (p, d)}
+        keys = jax.random.split(key, len(shapes) + 2)
+        params = {name: _normal(k, shape, self.init_std, dt)
+                  for k, (name, shape) in zip(keys, shapes.items())}
+        # a step's log decay starts in about [-1.6, -0.001]
+        params["A_log"] = jnp.log(jax.random.uniform(
+            keys[-2], (h,), jnp.float32, 1.0, 16.0)).astype(dt)
+        step = jnp.exp(jax.random.uniform(
+            keys[-1], (p,), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+        params["dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(dt)
+        params["o_norm"] = jnp.ones((self.head_dim,), dt)
+        return params
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        b, t, _ = x.shape
+        h, dh, wide = self.n_heads, self.head_dim, _wide(x.dtype)
+
+        def heads(y):
+            return y.reshape(b, t, h, dh)
+
+        def unit(y):                  # a head's L2 norm, eps under the root
+            return y * jax.lax.rsqrt(
+                jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+
+        def conv(y, taps):            # a group's [B, T, g, dh], [g, dh, K]
+            width = y.shape[2] * dh
+            return short_conv(y.reshape(b, t, width),
+                              taps.reshape(width, -1)).reshape(y.shape)
+
+        def group(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
+            """A group of heads from the projections' outputs on: short
+            convolutions, norms and gates in float32, the chunk phase."""
+            with jax.named_scope("kda.conv"):
+                q = unit(conv(q, conv_q)) * dh ** -0.5
+                k, v = unit(conv(k, conv_k)), conv(v, conv_v)
+            with jax.named_scope("kda.gate"):
+                g = -jnp.exp(a_log.astype(wide))[:, None] * jax.nn.softplus(
+                    f.astype(wide) + dt_bias.astype(wide))
+                beta = jax.nn.sigmoid(beta.astype(wide))
+            return _chunk_phase(q, k, v, g, beta, chunk=self.chunk)
+
+        with jax.named_scope("kda"):
+            with jax.named_scope("kda.proj"):
+                q, k, v = (heads(_linear(x, params[name]))
+                           for name in ("W_q", "W_k", "W_v"))
+                f = heads(_linear(_linear(x, params["W_fa"]), params["W_fb"]))
+                beta = _linear(x, params["W_beta"])
+                gate = _linear(_linear(x, params["W_ga"]), params["W_gb"])
+            o, _ = _scan_and_read(*_map_head_groups(
+                group,
+                (q, k, v, f, beta,
+                 *(params[name].reshape(h, dh, self.conv_taps)
+                   for name in ("conv_q", "conv_k", "conv_v")),
+                 params["A_log"], params["dt_bias"].reshape(h, dh)),
+                (2,) * 5 + (0,) * 5, HEAD_GROUP), t=t)
+            with jax.named_scope("kda.out"):
+                o = rms_norm(o, params["o_norm"], self.eps).astype(wide) \
+                    * jax.nn.sigmoid(heads(gate).astype(wide))
+                return _linear(o.reshape(b, t, h * dh), params["W_o"]), state
 
 
 def route(logits, bias, *, top_k: int, scale: float, normalize: bool = True):

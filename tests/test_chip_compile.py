@@ -157,6 +157,43 @@ def test_joyai_train_step_whole_program(one_chip):
     assert resident + 4 * n_params < V5E_HBM_BYTES, mem
 
 
+def test_delta_attention_layer_seq8192(one_chip):
+    """One Kimi Delta Attention layer at the widths of
+    ``kimi_linear_48b_a3b.clm_s8192_b1`` (32 heads of 128 behind 4 taps,
+    hidden 2304, 8,192 tokens, bf16 policy), forward and ``jax.grad``: the
+    chunked scan has to compile for the chip with its head groups
+    rematerialised (2.8 GB of temporaries; all 32 heads at once ask for
+    5.6, and the whole step then no longer fits beside its 9.64 GB of
+    weights, moments and the harness's copy) and hold its loops: a ``while``
+    for the head groups and one for the scan, each way."""
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    from deeplearning4j_tpu.nn.input_type import InputType
+    from deeplearning4j_tpu.nn.layers.decoder import DeltaAttention
+    layer = DeltaAttention(n_heads=32, head_dim=128, chunk=64, eps=1e-5)
+    was = dtype_policy()
+    set_dtype_policy(DTypePolicy.bf16())
+    try:
+        params = jax.eval_shape(lambda: layer.init_params(
+            jax.random.key(0), InputType.recurrent(2304, 8192)))
+
+        def loss(params, x):
+            return jnp.sum(layer.apply(params, {}, x)[0].astype(jnp.float32))
+
+        args = jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=one_chip),
+            (params, jax.ShapeDtypeStruct((1, 8192, 2304), jnp.bfloat16)))
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            *args).compile()
+    finally:
+        set_dtype_policy(was)
+    n_params = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
+    assert round(n_params / 1e6, 2) == 39.51
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
 def test_int8_matmul(one_chip):
     fn = functools.partial(int8_matmul_pallas, interpret=False)
     compiled = _compile(fn, one_chip, ((64, 2048), jnp.bfloat16),

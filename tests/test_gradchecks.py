@@ -365,6 +365,10 @@ LAYER_CASES = {
         qk_rope_head_dim=2, v_head_dim=3, rope_theta=100.0, init_std=0.5),
         RNN_OUT()],
         InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
+    # 5 tokens in chunks of 4: two chunks, the second padded
+    "delta_attention": ([DeltaAttention(n_heads=2, head_dim=3, chunk=4,
+                                        init_std=0.5), RNN_OUT()],
+                        InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
     "routed_experts": ([RoutedExperts(
         n_routed_experts=4, experts_held=2, first_expert=1, top_k=2, hidden=6,
         shared_hidden=6, routed_scaling_factor=2.5, init_std=0.5), FF_OUT()],
