@@ -462,10 +462,13 @@ class ComputationGraph:
         if attention:
             attrs["attention_kinds"] = [layer.ATTENTION_KIND
                                         for layer in attention]
-        chunks = sorted({layer.chunk for layer in attention
-                         if layer.ATTENTION_KIND == "kda"})
+        delta = [layer for layer in attention if layer.ATTENTION_KIND == "kda"]
+        chunks = sorted({layer.chunk for layer in delta})
         if chunks:
             attrs["kda_chunk"] = chunks[0] if len(chunks) == 1 else chunks
+        kernels = sorted({layer.kernel for layer in delta} - {None})
+        if kernels:
+            attrs["kda_kernel"] = kernels[0]
         return attrs
 
     def evaluate(self, iterator, top_n: int = 1):

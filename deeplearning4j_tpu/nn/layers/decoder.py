@@ -313,6 +313,12 @@ def _decayed_scores(rows, k, g_sum):
 HEAD_GROUP = 8      # heads whose chunk phase is live at once
 
 
+def _group_size(heads: int, head_group: int) -> int:
+    """``head_group``, or all ``heads`` where they do not divide into such
+    groups."""
+    return heads if heads % head_group else head_group
+
+
 def _map_head_groups(fn, arrays, head_axes, head_group: int):
     """``fn`` over groups of ``head_group`` heads, one group after the
     other and each rematerialised: ``arrays[i]`` has its heads on axis
@@ -321,9 +327,7 @@ def _map_head_groups(fn, arrays, head_axes, head_group: int):
     ``[T, h d]`` float32 arrays a group's phase makes only one group's
     are ever live (8,192 tokens of 32 heads of 128 are 134 MB an array).
     Heads that do not divide into such groups are one group."""
-    heads = arrays[0].shape[head_axes[0]]
-    if heads % head_group:
-        head_group = heads
+    head_group = _group_size(arrays[0].shape[head_axes[0]], head_group)
 
     def grouped(x, axis):
         x = x.reshape(x.shape[:axis] + (x.shape[axis] // head_group,
@@ -339,7 +343,14 @@ def _chunk_phase(q, k, v, g, beta, *, chunk: int):
     state, for a group of heads: ``[B, T, h, ...]`` -> ``W``, ``U~``,
     ``K exp(G_C - G)``, ``exp(G_C)`` (the scan's inputs) and ``Q exp(G)``,
     ``B`` (the read's), each ``[B, h, n, chunk, ...]``.  What only ever
-    is a matrix product's operand leaves in the compute dtype."""
+    is a matrix product's operand leaves in the compute dtype.
+
+    In ``jax.numpy``: what :func:`chunked_delta_rule` runs, and what
+    ``DeltaAttention`` runs at any shape for the backward pass and, where
+    ``ops.pallas.kda_chunk`` does not serve its head size and chunk, for
+    the forward too.  Where it does, the forward is that kernel, the same
+    equations at the same precision, with ``G`` a product with the lower
+    triangle of ones (Mosaic lowers no ``cumsum``)."""
     if chunk & (chunk - 1) or chunk < PAIRWISE:
         raise ValueError(f"chunk has to be a power of two of at least "
                          f"{PAIRWISE}, got {chunk}")
@@ -423,10 +434,96 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
     exponent is <= 0 as written.  Matrix products take their operands in
     the compute dtype and sum in float32; the inversion, the decays and
     the state stay float32.  A length that is no multiple of ``chunk`` is
-    padded with tokens that change nothing (``k = 0``, ``g = 0``)."""
+    padded with tokens that change nothing (``k = 0``, ``g = 0``).
+
+    All of it in ``jax.numpy``, at any shape and both ways: the plain form
+    of what ``DeltaAttention`` runs, whose chunk phase goes forward
+    through the ``tpudl_kda_chunk`` kernel where it can."""
     return _scan_and_read(*_map_head_groups(
         functools.partial(_chunk_phase, chunk=chunk), (q, k, v, g, beta),
         (2,) * 5, head_group), t=k.shape[1])
+
+
+def _kda_inputs(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
+    """A group of ``h`` heads from the projections' outputs (``q``, ``k``,
+    ``v``, ``f`` ``[B, T, h, d]``, ``beta`` ``[B, T, h]``; taps ``[h, d,
+    K]``, ``a_log`` ``[h]``, ``dt_bias`` ``[h, d]``) to the chunk phase's
+    inputs: short convolutions, norms and gates, in float32."""
+    b, t, h, dh = q.shape
+    wide = _wide(q.dtype)
+
+    def unit(y):                      # a head's L2 norm, eps under the root
+        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    def conv(y, taps):
+        return short_conv(y.reshape(b, t, h * dh),
+                          taps.reshape(h * dh, -1)).reshape(y.shape)
+
+    with jax.named_scope("kda.conv"):
+        q = unit(conv(q, conv_q)) * dh ** -0.5
+        k, v = unit(conv(k, conv_k)), conv(v, conv_v)
+    with jax.named_scope("kda.gate"):
+        g = -jnp.exp(a_log.astype(wide))[:, None] * jax.nn.softplus(
+            f.astype(wide) + dt_bias.astype(wide))
+        beta = jax.nn.sigmoid(beta.astype(wide))
+    return q, k, v, g, beta
+
+
+# where each argument of _kda_inputs has its heads
+_KDA_HEAD_AXES = (2,) * 5 + (0,) * 5
+
+
+def _kda_grouped(xs, chunk: int):
+    """The chunk phase from the projections' outputs in ``jax.numpy``, by
+    groups of ``HEAD_GROUP`` heads, each rematerialised."""
+    return _map_head_groups(
+        lambda *group: _chunk_phase(*_kda_inputs(*group), chunk=chunk),
+        xs, _KDA_HEAD_AXES, HEAD_GROUP)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "compute_dtype"))
+def _kda_grouped_vjp(xs, cts, *, chunk: int, compute_dtype):
+    """The cotangents of :func:`_kda_grouped`'s inputs: the backward pass
+    of the kernel's chunk phase, traced once for every layer of a shape
+    (``compute_dtype`` keys the trace: the policy is read while tracing).
+    The primal outputs ``jax.vjp`` makes are never read, so the compiler
+    drops them."""
+    return jax.vjp(lambda *xs: _kda_grouped(xs, chunk), *xs)[1](cts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _kda_chunks(chunk, compute_dtype, interpret, *xs):
+    return _kda_chunks_fwd(chunk, compute_dtype, interpret, *xs)[0]
+
+
+def _kda_chunks_fwd(chunk, compute_dtype, interpret, *xs):
+    from deeplearning4j_tpu.ops.pallas.kda_chunk import kda_chunk
+    q, k, v, g, beta = _kda_inputs(*xs)
+    with jax.named_scope("kda.chunk"):
+        chunked = kda_chunk(q, k, v, g, beta, chunk=chunk,
+                            head_group=_group_size(q.shape[2], HEAD_GROUP),
+                            compute_dtype=compute_dtype, interpret=interpret)
+    return chunked, xs
+
+
+def _kda_chunks_bwd(chunk, compute_dtype, interpret, xs, cts):
+    return _kda_grouped_vjp(xs, cts, chunk=chunk, compute_dtype=compute_dtype)
+
+
+_kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "compute_dtype",
+                                             "interpret"))
+def _kda_kernel_chunks(xs, *, chunk: int, compute_dtype, interpret: bool):
+    """:func:`_kda_grouped`'s results, forward through the
+    ``tpudl_kda_chunk`` kernel for all heads at once (short convolutions,
+    norms and gates in ``jax.numpy`` before it), backward by
+    :func:`_kda_grouped_vjp` from the same inputs, which are all the
+    backward pass keeps.  One jit for every layer of a shape: the step
+    traces and lowers it once."""
+    return _kda_chunks(chunk, compute_dtype, interpret, *xs)
 
 
 @register_layer("delta_attention")
@@ -443,7 +540,18 @@ class DeltaAttention(Layer):
     sigmoid(x W_beta)`` a head; :func:`chunked_delta_rule`; then a per-head
     RMS norm (one ``head_dim``-wide scale for all heads) gated by
     ``sigmoid(x W_ga W_gb)``, and ``W_o``.  Both low-rank gates have rank
-    ``head_dim``."""
+    ``head_dim``.
+
+    Where the chunk phase runs: where ``head_dim`` fills the lanes and
+    ``chunk`` the float32 sublanes (``ops.pallas.kda_chunk.takes``; the
+    Kimi cell's 128 and 64), its forward, and the rematerialised run's
+    recomputed forward, is the ``tpudl_kda_chunk`` kernel for all heads
+    at once after the convolutions and gates in ``jax.numpy``; its
+    backward pass is the ``jax.numpy`` path's, by groups of
+    ``HEAD_GROUP`` heads each rematerialised, from the projections'
+    outputs it keeps.  At any other shape both ways are that grouped
+    ``jax.numpy`` path.  The scan and the read are ``jax.numpy`` at every
+    shape."""
 
     INPUT_KIND = "rnn"
     ATTENTION_KIND = "kda"            # ``ComputationGraph.trace_attrs``
@@ -475,33 +583,21 @@ class DeltaAttention(Layer):
         params["o_norm"] = jnp.ones((self.head_dim,), dt)
         return params
 
+    @property
+    def kernel(self) -> str | None:
+        """The kernel the chunk phase runs forward as, where the shapes
+        allow one (``ComputationGraph.trace_attrs``).  Imported here: a
+        model without delta attention never loads Pallas."""
+        from deeplearning4j_tpu.ops.pallas import kda_chunk
+        return kda_chunk.KERNEL_NAME if kda_chunk.takes(
+            self.head_dim, self.chunk) else None
+
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         b, t, _ = x.shape
         h, dh, wide = self.n_heads, self.head_dim, _wide(x.dtype)
 
         def heads(y):
             return y.reshape(b, t, h, dh)
-
-        def unit(y):                  # a head's L2 norm, eps under the root
-            return y * jax.lax.rsqrt(
-                jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
-
-        def conv(y, taps):            # a group's [B, T, g, dh], [g, dh, K]
-            width = y.shape[2] * dh
-            return short_conv(y.reshape(b, t, width),
-                              taps.reshape(width, -1)).reshape(y.shape)
-
-        def group(q, k, v, f, beta, conv_q, conv_k, conv_v, a_log, dt_bias):
-            """A group of heads from the projections' outputs on: short
-            convolutions, norms and gates in float32, the chunk phase."""
-            with jax.named_scope("kda.conv"):
-                q = unit(conv(q, conv_q)) * dh ** -0.5
-                k, v = unit(conv(k, conv_k)), conv(v, conv_v)
-            with jax.named_scope("kda.gate"):
-                g = -jnp.exp(a_log.astype(wide))[:, None] * jax.nn.softplus(
-                    f.astype(wide) + dt_bias.astype(wide))
-                beta = jax.nn.sigmoid(beta.astype(wide))
-            return _chunk_phase(q, k, v, g, beta, chunk=self.chunk)
 
         with jax.named_scope("kda"):
             with jax.named_scope("kda.proj"):
@@ -510,13 +606,18 @@ class DeltaAttention(Layer):
                 f = heads(_linear(_linear(x, params["W_fa"]), params["W_fb"]))
                 beta = _linear(x, params["W_beta"])
                 gate = _linear(_linear(x, params["W_ga"]), params["W_gb"])
-            o, _ = _scan_and_read(*_map_head_groups(
-                group,
-                (q, k, v, f, beta,
-                 *(params[name].reshape(h, dh, self.conv_taps)
-                   for name in ("conv_q", "conv_k", "conv_v")),
-                 params["A_log"], params["dt_bias"].reshape(h, dh)),
-                (2,) * 5 + (0,) * 5, HEAD_GROUP), t=t)
+            xs = (q, k, v, f, beta,
+                  *(params[name].reshape(h, dh, self.conv_taps)
+                    for name in ("conv_q", "conv_k", "conv_v")),
+                  params["A_log"], params["dt_bias"].reshape(h, dh))
+            if self.kernel:
+                chunked = _kda_kernel_chunks(
+                    xs, chunk=self.chunk,
+                    compute_dtype=jnp.dtype(dtype_policy().compute_dtype),
+                    interpret=jax.default_backend() != "tpu")
+            else:
+                chunked = _kda_grouped(xs, self.chunk)
+            o, _ = _scan_and_read(*chunked, t=t)
             with jax.named_scope("kda.out"):
                 o = rms_norm(o, params["o_norm"], self.eps).astype(wide) \
                     * jax.nn.sigmoid(heads(gate).astype(wide))

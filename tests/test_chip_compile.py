@@ -161,11 +161,13 @@ def test_delta_attention_layer_seq8192(one_chip):
     """One Kimi Delta Attention layer at the widths of
     ``kimi_linear_48b_a3b.clm_s8192_b1`` (32 heads of 128 behind 4 taps,
     hidden 2304, 8,192 tokens, bf16 policy), forward and ``jax.grad``: the
-    chunked scan has to compile for the chip with its head groups
-    rematerialised (2.8 GB of temporaries; all 32 heads at once ask for
-    5.6, and the whole step then no longer fits beside its 9.64 GB of
-    weights, moments and the harness's copy) and hold its loops: a ``while``
-    for the head groups and one for the scan, each way."""
+    chunk phase's forward is the ``tpudl_kda_chunk`` kernel, and its
+    backward the ``jax.numpy`` phase with its head groups rematerialised
+    (2.8 GB of temporaries; all 32 heads at once ask for 5.6, and the whole
+    step then no longer fits beside its 9.64 GB of weights, moments and the
+    harness's copy); it holds its loops: the head groups' in the backward
+    pass (the forward's grouped loop is gone with the kernel, and so is the
+    backward's unread primal one) and the scan's, each way."""
     from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
                                            set_dtype_policy)
     from deeplearning4j_tpu.nn.input_type import InputType
@@ -184,14 +186,84 @@ def test_delta_attention_layer_seq8192(one_chip):
             lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
                                               sharding=one_chip),
             (params, jax.ShapeDtypeStruct((1, 8192, 2304), jnp.bfloat16)))
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-            *args).compile()
+        real_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+        try:                 # the kernel's compiled branch, not interpret
+            compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+                *args).compile()
+        finally:
+            jax.default_backend = real_backend
     finally:
         set_dtype_policy(was)
     n_params = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
     assert round(n_params / 1e6, 2) == 39.51
-    assert len(re.findall(r" while\(", compiled.as_text())) >= 4
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) >= 3
+    assert any("tpudl_kda_chunk" in line for line in text.splitlines()
+               if "custom-call(" in line)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def test_kimi_train_step_lowers_the_kda_kernel_once(one_chip):
+    """The program ``kimi_linear_48b_a3b.clm_s8192_b1`` runs, from the
+    cell's own configuration file (602.4 M parameters, 8,192 tokens, bf16
+    policy, Adam), lowered from ``make_train_step`` and not compiled: four
+    KDA layers, each run forward and again in its block's rematerialised
+    run, make 8 calls of the chunk-phase kernel, and the module holds ONE
+    kernel body that they all call, lowered once a step (what the set-up
+    of every run of the cell pays, even where the compile is cached)."""
+    import json
+    import os
+
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    from deeplearning4j_tpu.models import kimi_linear
+    from deeplearning4j_tpu.train import Adam
+    from deeplearning4j_tpu.train.trainer import Trainer, make_train_step
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "kimi_linear_48b_a3b.json")) as f:
+        config = json.load(f)
+    seq = 8192
+    was = dtype_policy()
+    set_dtype_policy(DTypePolicy.bf16())
+    try:
+        net = kimi_linear(config, seq, updater=Adam(1e-4),
+                          kda_chunk=config["kda_chunk"])
+
+        def shapes():                      # net.init traced, never run
+            net.init()
+            return net.params_, net.state_
+        params, state = jax.eval_shape(shapes)
+        net.params_ = params
+        tx = Trainer(net).tx
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                                  sharding=one_chip), tree)
+
+        ids = on_chip(jax.ShapeDtypeStruct((1, seq), jnp.int32))
+        args = (on_chip(params), on_chip(state),
+                on_chip(jax.eval_shape(tx.init, params)), ids, ids, None,
+                on_chip(jax.ShapeDtypeStruct((1,), jnp.float32)),
+                on_chip(jax.eval_shape(lambda: jax.random.key(0))))
+        real_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+        try:
+            text = make_train_step(net, tx).lower(*args).as_text()
+        finally:
+            jax.default_backend = real_backend
+    finally:
+        set_dtype_policy(was)
+    n_params = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
+    assert round(n_params / 1e6, 1) == 602.4
+    assert net.trace_attrs()["kda_kernel"] == "tpudl_kda_chunk"
+    _, *funcs = re.split(r"\n  func\.func ", text)
+    bodies = [f for f in funcs if re.search(
+        r"tpu_custom_call.*tpudl_kda_chunk", f)]
+    assert len(bodies) == 1
+    assert len(re.findall(r"tpu_custom_call.*tpudl_kda_chunk", text)) == 1
+    name = re.match(r"(?:public |private )?@(\w+)", bodies[0]).group(1)
+    assert len(re.findall(rf"call @{name}\(", text)) == 8
 
 
 def test_int8_matmul(one_chip):

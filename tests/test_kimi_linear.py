@@ -38,8 +38,11 @@ from deeplearning4j_tpu.models import kimi_linear, resnet50
 from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn.layers.decoder import (DeltaAttention,
                                                   LatentAttention,
+                                                  _chunk_phase,
+                                                  _scan_and_read,
                                                   chunked_delta_rule,
                                                   short_conv)
+from deeplearning4j_tpu.ops.pallas.kda_chunk import kda_chunk
 from deeplearning4j_tpu.train.trainer import make_loss_fn
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
@@ -208,17 +211,58 @@ def _both_rules(chunk):
         values_and_grads(reference.delta_rule))
 
 
+@functools.lru_cache(maxsize=None)
+def _kernel_rule(chunk):
+    """(the rule with its chunk phase run by ``tpudl_kda_chunk``, interpret
+    mode here: the phase's six results and the rule's outputs and last
+    state; ``_chunk_phase``; the recurrence), each jitted once."""
+    def rule(q, k, v, g, beta):
+        phase = kda_chunk(q, k, v, g, beta, chunk=chunk,
+                          head_group=q.shape[2], compute_dtype=jnp.float32)
+        return phase, _scan_and_read(*phase, t=k.shape[1])
+    return (jax.jit(rule), jax.jit(functools.partial(_chunk_phase,
+                                                     chunk=chunk)),
+            jax.jit(_load("reference", "kimi_linear").delta_rule))
+
+
+def _close(got, want):
+    """Finite, and equal to 1e-5 of the array's largest entry (of 1 where
+    that is smaller)."""
+    assert bool(jnp.all(jnp.isfinite(got)))
+    scale = max(1.0, float(jnp.max(jnp.abs(want))))
+    np.testing.assert_allclose(got, want, atol=1e-5 * scale, rtol=0)
+
+
 @pytest.mark.parametrize("decay", [-0.001, -1.6, -16.0])
 @pytest.mark.parametrize("t", [128, 100], ids=["whole_chunks", "ragged"])
 @pytest.mark.parametrize("chunk", [16, 64])
-def test_chunked_scan_equals_the_token_recurrence(float32_policy, chunk, t,
-                                                  decay):
+@pytest.mark.parametrize("phase", ["jnp", "kernel"])
+def test_chunked_scan_equals_the_token_recurrence(float32_policy, phase,
+                                                  chunk, t, decay):
     """Outputs, the last state and ``jax.grad`` of every input, at decays
     from next to none to 16 nats a step (64 steps of which no
     factorised ``exp(-G)`` survives in float32): finite, and equal to
     1e-5 of the array's largest entry (of 1 where that is smaller).  A
     length that is no multiple of the chunk is padded with tokens that
-    change nothing."""
+    change nothing.
+
+    ``kernel``: the chunk phase as ``tpudl_kda_chunk`` at a head size of
+    128, its six results against ``_chunk_phase``'s and the outputs and
+    last state through the scan against the recurrence (its gradient is
+    the ``jax.numpy`` phase's: ``test_layer_with_the_kernel_equals_...``)."""
+    if phase == "kernel":
+        x = _scan_inputs(t, decay, d=128)
+        rule, reference_phase, recurrence = _kernel_rule(chunk)
+        got_phase, got_out = rule(*x)
+        for got, want in zip(got_phase, reference_phase(*x)):
+            assert got.shape == (1,) + want.shape and got.dtype == want.dtype
+            _close(got[0], want)
+        want_out = recurrence(*x)
+        # unit keys of 128 overlap less than of 16: smaller outputs
+        assert float(jnp.max(jnp.abs(want_out[0]))) > 0.01
+        for got, want in zip(got_out, want_out):
+            _close(got, want)
+        return
     x = _scan_inputs(t, decay)
     weight = jax.random.normal(jax.random.key(9), x[2].shape)
     chunked, recurrence = _both_rules(chunk)
@@ -226,9 +270,7 @@ def test_chunked_scan_equals_the_token_recurrence(float32_policy, chunk, t,
     (_, want_out), want_grads = recurrence(*x, weight)
     assert float(jnp.max(jnp.abs(want_out[0]))) > 0.1
     for got, want in zip((*got_out, *got_grads), (*want_out, *want_grads)):
-        assert bool(jnp.all(jnp.isfinite(got)))
-        scale = max(1.0, float(jnp.max(jnp.abs(want))))
-        np.testing.assert_allclose(got, want, atol=1e-5 * scale, rtol=0)
+        _close(got, want)
 
 
 def test_chunk_has_to_be_a_power_of_two():
@@ -236,17 +278,53 @@ def test_chunk_has_to_be_a_power_of_two():
         chunked_delta_rule(*_scan_inputs(48, -0.1), chunk=48)
 
 
-def test_equal_keys_without_decay_invert_exactly(float32_policy):
+@pytest.mark.parametrize("phase", ["jnp", "kernel"])
+def test_equal_keys_without_decay_invert_exactly(float32_policy, phase):
     """``A`` all ones below the diagonal, whose powers reach 1e17: the
     inversion by doubling takes none, and the outputs stay the
     recurrence's.  With ``beta`` 1 and no decay every token overwrites
-    what the one key holds, so ``o_t = v_t (k . q)``."""
-    t, d = 64, 16
+    what the one key holds, so ``o_t = v_t (k . q)``.  ``kernel``: the
+    same through ``tpudl_kda_chunk``'s doubling on the whole tile, at a
+    head size of 128."""
+    t, d = 64, 16 if phase == "jnp" else 128
     k = jnp.zeros((1, t, 1, d)).at[..., 0].set(1.0)
     v = jax.random.normal(jax.random.key(0), (1, t, 1, d))
-    o, _ = jax.jit(functools.partial(chunked_delta_rule, chunk=64))(
-        0.5 * k, k, v, jnp.zeros_like(k), jnp.ones((1, t, 1)))
+    x = (0.5 * k, k, v, jnp.zeros_like(k), jnp.ones((1, t, 1)))
+    if phase == "jnp":
+        o, _ = jax.jit(functools.partial(chunked_delta_rule, chunk=64))(*x)
+    else:
+        _, (o, _) = _kernel_rule(64)[0](*x)
     np.testing.assert_allclose(o, 0.5 * v, atol=1e-6)
+
+
+def test_layer_with_the_kernel_equals_the_jnp_path(float32_policy,
+                                                   monkeypatch):
+    """``DeltaAttention`` at a head size of 128 takes the kernel (its
+    forward; the backward is the grouped ``jax.numpy`` path from the
+    projections' outputs): its outputs and ``jax.grad`` of every parameter
+    and of the input equal the ``jax.numpy`` path's, a ragged length in
+    chunks of 16, two heads, one group."""
+    layer = DeltaAttention(n_heads=2, head_dim=128, chunk=16)
+    t = 100
+    params = layer.init_params(jax.random.key(0), InputType.recurrent(64, t))
+    x = jax.random.normal(jax.random.key(1), (BATCH, t, 64))
+    weight = jax.random.normal(jax.random.key(2), (BATCH, t, 64))
+
+    def run():
+        def total(params, x):
+            out = layer.apply(params, {}, x)[0]
+            return jnp.sum(out * weight), out
+        return jax.jit(jax.value_and_grad(total, argnums=(0, 1),
+                                          has_aux=True))(params, x)
+
+    assert layer.kernel == "tpudl_kda_chunk"
+    (_, got_out), got_grads = run()
+    monkeypatch.setattr(DeltaAttention, "kernel", property(lambda _: None))
+    (_, want_out), want_grads = run()
+    assert float(jnp.max(jnp.abs(want_out))) > 1e-3
+    for got, want in zip(jax.tree_util.tree_leaves((got_out, got_grads)),
+                         jax.tree_util.tree_leaves((want_out, want_grads))):
+        _close(got, want)
 
 
 # ---- (d) the short convolution, and the block's causality ----------------------
@@ -340,8 +418,15 @@ def test_trace_attrs_carry_the_attention_kinds_and_the_chunk():
     assert attrs["attention_kinds"] == KINDS
     assert attrs["kda_chunk"] == 32
     assert attrs["remat_runs"] == 10       # a block is two runs
+    assert "kda_kernel" not in attrs       # heads of 16: the jnp path
+    config = small_config()
+    config["linear_attn_config"] = dict(config["linear_attn_config"],
+                                        head_dim=128)
+    attrs = kimi_linear(config, SEQ, seed=SEED).trace_attrs()
+    assert attrs["kda_kernel"] == "tpudl_kda_chunk"
     attrs = resnet50(height=32, width=32, num_classes=10).trace_attrs()
     assert "attention_kinds" not in attrs and "kda_chunk" not in attrs
+    assert "kda_kernel" not in attrs
 
 
 def test_the_layer_starts_inside_the_stated_decays():
