@@ -472,7 +472,6 @@ class BertForMaskedLM:
             if retraced > 0:
                 sp.set_attribute("compile", True)
                 metrics.recompiles.inc(retraced)
-                metrics.compile_seconds.set(t2 - t0)
             metrics.steps.inc()
             metrics.examples.inc(fed.n_examples)
             t3 = time.perf_counter()

@@ -44,7 +44,8 @@ import weakref
 from typing import Any, Optional
 
 from deeplearning4j_tpu.config import get_config
-from deeplearning4j_tpu.obs.registry import get_registry
+from deeplearning4j_tpu.obs import tracing
+from deeplearning4j_tpu.obs.registry import get_registry, setup_metrics
 
 # ------------------------------------------------------------ peak table
 # Public per-chip peaks: (bf16 dense FLOP/s, HBM bytes/s).  The CPU row
@@ -306,19 +307,36 @@ def _total_cost(compiled) -> tuple[float, float]:
 
 
 def analyze_jitted(fn: Any, abstract_args: Any, kind: Optional[str] = None,
-                   device=None, sig=None) -> Optional[ProgramCost]:
+                   device=None, sig=None, tracer=None,
+                   parent=None) -> Optional[ProgramCost]:
     """Pull cost_analysis from the compiled program behind ``fn`` for
-    the given abstract call signature.  ``fn.lower().compile()`` is a
-    REAL second XLA compilation under the default config (the AOT path
-    has no in-memory executable cache) — a persistent-cache hit once the
-    entry point has called ``config.place_compile_cache``; use
-    :func:`schedule_analysis` to keep the cost off the step/dispatch
-    path entirely.  Never raises
-    — telemetry must not break a training step."""
+    the given abstract call signature.  Once ``fn`` has run,
+    ``fn.lower().compile()`` reuses its lowering and executable (jax 0.9:
+    one trace event of about 0 s, no lowering or backend compile; 0.05-0.09
+    s a program on a v5e); before, it is a real compile.  Use
+    :func:`schedule_analysis` to keep it off the step/dispatch path
+    entirely.  Never raises — telemetry must not break a training step.
+
+    Its wall time is one observation of ``tpudl_perf_analysis_seconds``,
+    and its compiles go into none of the ``tpudl_compile_*`` histograms
+    (``tracing.owned_compiles``); where ``tracer`` (default: the global
+    one) is on, a ``costmodel.analyze`` span under ``parent`` (default:
+    the current span) holds their ``compile.*`` spans."""
     if fn is None or not enabled():
         return None
-    key = (id(fn), sig)
     kind = kind or program_kind(fn) or getattr(fn, "__name__", "program")
+    t0 = time.perf_counter()
+    try:
+        with tracing.owned_compiles("costmodel.analyze", tracer=tracer,
+                                    parent=parent, program=kind):
+            return _analyze(fn, abstract_args, kind, device, sig)
+    finally:
+        setup_metrics().analysis.observe(time.perf_counter() - t0)
+
+
+def _analyze(fn: Any, abstract_args: Any, kind: str, device,
+             sig) -> Optional[ProgramCost]:
+    key = (id(fn), sig)
     try:
         compiled = fn.lower(*abstract_args).compile()
         flops, bytes_accessed = _total_cost(compiled)
@@ -372,9 +390,10 @@ def _worker_loop(q) -> None:
     import logging
     log = logging.getLogger("deeplearning4j_tpu")
     while True:
-        fn, abstract_args, kind, sig = q.get()
+        fn, abstract_args, kind, sig, tracer, parent = q.get()
         try:
-            analyze_jitted(fn, abstract_args, kind=kind, sig=sig)
+            analyze_jitted(fn, abstract_args, kind=kind, sig=sig,
+                           tracer=tracer, parent=parent)
         except Exception:
             log.warning("cost-model analysis failed for program %r",
                         kind, exc_info=True)
@@ -388,7 +407,9 @@ def schedule_analysis(fn: Any, abstract_args: Any,
                       kind: Optional[str] = None, sig=None) -> None:
     """Queue :func:`analyze_jitted` on the background worker (idempotent
     per (fn, sig); the queue holds a strong ref to ``fn`` until the
-    analysis runs)."""
+    analysis runs).  Its ``costmodel.analyze`` span goes to the tracer
+    active here, under the current span, so that a traced run shows
+    where the analysis overlapped the steps."""
     global _ANALYSIS_QUEUE, _WORKER
     if fn is None or not enabled():
         return
@@ -405,7 +426,8 @@ def schedule_analysis(fn: Any, abstract_args: Any,
                 target=_worker_loop, args=(_ANALYSIS_QUEUE,), daemon=True,
                 name="tpudl-costmodel-analyzer")
             _WORKER.start()
-    _ANALYSIS_QUEUE.put((fn, abstract_args, kind, sig))
+    _ANALYSIS_QUEUE.put((fn, abstract_args, kind, sig, tracing.get_tracer(),
+                         tracing.current_context()))
 
 
 def drain(timeout_s: float = 60.0) -> bool:

@@ -479,9 +479,6 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
         r.histogram("tpudl_train_epoch_seconds",
                     "Wall time per completed epoch (fit loop, feed "
                     "included)"),
-        r.gauge("tpudl_train_compile_seconds",
-                "Wall time of the most recent first-call (trace+compile) "
-                "step through a jit boundary"),
         r.gauge("tpudl_train_last_score",
                 "Most recent loss a ScoreIterationListener read"),
         r.counter("tpudl_train_recompiles_total",
@@ -519,6 +516,7 @@ def install_standard_metrics(registry: Optional[MetricsRegistry] = None) -> dict
         r.counter("tpudl_compile_artifacts_loaded_total",
                   "Serialized executables deserialized into the "
                   "process warm pool"),
+        *setup_metrics(r),
         r.histogram("tpudl_compile_bake_seconds",
                     "Wall time to AOT-lower, compile and serialize one "
                     "program into the artifact store"),
@@ -875,7 +873,6 @@ class TrainLoopMetrics(NamedTuple):
     steps: Counter
     examples: Counter
     recompiles: Counter
-    compile_seconds: Gauge
     iteration: Histogram
     dispatch: Histogram
     read: Histogram
@@ -888,10 +885,46 @@ def train_loop_metrics(
         r.counter("tpudl_train_steps_total"),
         r.counter("tpudl_train_examples_total"),
         r.counter("tpudl_train_recompiles_total"),
-        r.gauge("tpudl_train_compile_seconds"),
         r.histogram("tpudl_train_iteration_seconds"),
         r.histogram("tpudl_train_dispatch_seconds"),
         r.histogram("tpudl_train_read_seconds"))
+
+
+class SetupMetrics(NamedTuple):
+    """Where a process's compiles went, from ``jax.monitoring``'s events
+    (``obs.tracing``'s listener) and the cost model's analyses: what a
+    restart pays before its first useful step."""
+
+    trace: Histogram
+    lower: Histogram
+    xla: Histogram
+    cache_load: Histogram
+    analysis: Histogram
+
+
+def setup_metrics(registry: Optional[MetricsRegistry] = None) -> SetupMetrics:
+    r = registry or get_registry()
+    return SetupMetrics(
+        r.histogram("tpudl_compile_trace_seconds",
+                    "Tracing a jitted function to a jaxpr: one observation "
+                    "per outermost trace on a thread, the inner jits' "
+                    "traces inside it (the compile.trace span)"),
+        r.histogram("tpudl_compile_lower_seconds",
+                    "Lowering a jaxpr to an MLIR module (the compile.lower "
+                    "span)"),
+        r.histogram("tpudl_compile_xla_seconds",
+                    "Backend compile less the cache load inside it: XLA's "
+                    "compile on a persistent-cache miss, the cache key and "
+                    "lookup on a hit (the compile.xla span's own time)"),
+        r.histogram("tpudl_compile_cache_load_seconds",
+                    "Reading, deserializing and loading one executable "
+                    "from jax's persistent compile cache (the "
+                    "compile.cache_load span)"),
+        r.histogram("tpudl_perf_analysis_seconds",
+                    "Wall time of one cost-model analysis, its AOT lower "
+                    "and compile included; those compiles go into none of "
+                    "the tpudl_compile_* histograms (the costmodel.analyze "
+                    "span)"))
 
 
 def record_device_memory(registry: Optional[MetricsRegistry] = None,

@@ -20,6 +20,11 @@ survives a process boundary.  This module is the TPU-native upgrade:
   ``DL4J_TPU_TRACE_CONTEXT`` environment variable, so spans emitted by
   multiprocess/multislice workers (``parallel/launcher.py``,
   ``parallel/dcn_trainer.py``) join the parent trace.
+- jax's own compile events (``jax.monitoring``: trace, lowering, backend
+  compile, persistent-cache load) become the set-up histograms of
+  ``registry.setup_metrics``, always, and ``compile.*`` spans under the
+  current span when tracing is on; the listener is installed when this
+  module is imported.
 - Finished spans export as append-only jsonl
   (:meth:`Tracer.export_jsonl`) and as Chrome-trace JSON
   (:meth:`Tracer.export_chrome_trace`) loadable in ``chrome://tracing``
@@ -42,6 +47,7 @@ from contextlib import contextmanager
 from typing import Any, Iterator, Optional
 
 from deeplearning4j_tpu.config import get_config
+from deeplearning4j_tpu.obs.registry import setup_metrics
 
 TRACE_CONTEXT_ENV = "DL4J_TPU_TRACE_CONTEXT"
 
@@ -203,6 +209,21 @@ class Tracer:
     def finish_span(self, s: Span) -> None:
         s.end_ns = s.start_ns + (time.perf_counter_ns() - s._t0)
         s.end_s = s.end_ns / 1e9
+        self._keep(s)
+
+    def record_span(self, name: str, start_ns: int, end_ns: int,
+                    parent: Optional[SpanContext] = None,
+                    **attributes: Any) -> Span:
+        """Keep a span whose bounds someone else measured (Unix
+        nanoseconds): jax's compile events.  ``parent`` as for
+        :meth:`start_span`."""
+        s = self.start_span(name, parent=parent, attributes=attributes)
+        s.start_ns, s.end_ns = start_ns, end_ns
+        s.start_s, s.end_s = start_ns / 1e9, end_ns / 1e9
+        self._keep(s)
+        return s
+
+    def _keep(self, s: Span) -> None:
         with self._lock:
             if len(self.spans) < self.MAX_SPANS:
                 self.spans.append(s)
@@ -359,6 +380,139 @@ def self_intervals(spans) -> list:
             if cur < s["end_ns"]:
                 out.append((cur, s["end_ns"], s["thread"], name))
     return out
+
+
+# ------------------------------------------------------ compile events
+# jax reports each part of a compile as it happens (``jax.monitoring``):
+# a scalar holding the start time when it starts tracing a jitted
+# function to a jaxpr, lowering one to a module, or compiling a module
+# (or fetching it from the persistent cache), and a time span of
+# ``time.time()`` bounds when that ends, each with the function's name;
+# and a cached executable's read, deserialize and load as a duration,
+# inside the compile's span.  One listener turns them into the set-up
+# histograms of ``registry.setup_metrics`` (always) and ``compile.*``
+# spans (tracing on).  A steady step compiles nothing and fires nothing.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_XLA_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_COMPILE_SPANS = {_TRACE_EVENT: "compile.trace",
+                  _LOWER_EVENT: "compile.lower",
+                  _XLA_EVENT: "compile.xla"}
+
+
+class _CompileState(threading.local):
+    """One thread's open compile events, outermost first; the cache loads
+    inside its outermost backend compile; and, while the thread runs work
+    that owns its compiles (:func:`owned_compiles`), that work's tracer."""
+
+    def __init__(self):
+        self.open: list = []
+        self.loads: list = []            # (end_ns, seconds)
+        self.owner: Optional[Tracer] = None
+
+
+_compiles = _CompileState()
+
+
+def _compile_started(event: str, _start: float, **_) -> None:
+    if event in _COMPILE_SPANS:
+        _compiles.open.append(event)
+
+
+def _cache_loaded(event: str, seconds: float, **_) -> None:
+    # jax fetches from its cache only inside a backend compile's event; in
+    # a nested one the load's time is the outer event's
+    if event == _CACHE_LOAD_EVENT and _compiles.open == [_XLA_EVENT]:
+        _compiles.loads.append((time.time_ns(), seconds))
+
+
+def _compile_ended(event: str, start: float, end: float,
+                   fun_name: str = "", **_) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    state = _compiles
+    if state.open and state.open[-1] == event:
+        state.open.pop()
+    if state.open:
+        # inside another compile event of this thread (an inner jit's
+        # trace inside the outer one's): its time is the outer event's
+        return
+    loads, state.loads = state.loads, []
+    _compile_done(name, int(start * 1e9), int(end * 1e9), fun_name, loads)
+
+
+def _compile_done(name: str, start_ns: int, end_ns: int, program: str,
+                  loads: list) -> None:
+    """One outermost compile event: observed in its histogram unless the
+    thread's work owns its compiles, and kept as a span (its cache loads
+    as children, so that the span's own time is the observation) where
+    the thread's tracer is on."""
+    state = _compiles
+    if state.owner is None:
+        metrics = setup_metrics()
+        loaded = sum(seconds for _, seconds in loads)
+        own = getattr(metrics, name[len("compile."):])   # trace, lower, xla
+        own.observe(max((end_ns - start_ns) / 1e9 - loaded, 0.0))
+        for _, seconds in loads:
+            metrics.cache_load.observe(seconds)
+    tracer = state.owner if state.owner is not None else _global_tracer
+    if not tracer.enabled or end_ns <= start_ns:
+        return
+    attributes = {"program": program} if program else {}
+    s = tracer.record_span(name, start_ns, end_ns, **attributes)
+    for load_end, seconds in loads:
+        tracer.record_span("compile.cache_load",
+                           max(load_end - int(seconds * 1e9), start_ns),
+                           min(load_end, end_ns), parent=s.context(),
+                           **attributes)
+
+
+@contextmanager
+def owned_compiles(name: str, tracer: Optional[Tracer] = None,
+                   parent: Optional[SpanContext] = None,
+                   **attributes: Any) -> Iterator[Any]:
+    """Run the block as work whose compiles are its own cost, not the
+    process's set-up (the cost model's analysis): jax's compile events on
+    this thread inside it go into none of the compile histograms and,
+    where ``tracer`` (default: the global one) is on, become children of a
+    span ``name`` kept there under ``parent`` (default: the current
+    span).  Yields that span, or a no-op one."""
+    state = _compiles
+    tracer = tracer if tracer is not None else _global_tracer
+    s, token = None, None
+    if tracer.enabled:
+        s = tracer.start_span(name, parent=parent, attributes=attributes)
+        token = _current_span.set(s)
+    prev, state.owner = state.owner, tracer
+    try:
+        yield s if s is not None else NULL_SPAN
+    finally:
+        state.owner = prev
+        if s is not None:
+            _current_span.reset(token)
+            tracer.finish_span(s)
+
+
+_listening = False
+
+
+def _install_compile_listener() -> None:
+    """Register the compile-event listeners with ``jax.monitoring`` once
+    a process (done when this module is imported)."""
+    global _listening
+    import jax.monitoring as monitoring
+    with _tracer_lock:
+        if _listening:
+            return
+        _listening = True
+        monitoring.register_scalar_listener(_compile_started)
+        monitoring.register_event_duration_secs_listener(_cache_loaded)
+        monitoring.register_event_time_span_listener(_compile_ended)
+
+
+_install_compile_listener()
 
 
 # ------------------------------------------------------ wire propagation

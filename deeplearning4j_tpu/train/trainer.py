@@ -842,7 +842,6 @@ class Trainer:
                         - traces_before)
             if retraced > 0:
                 metrics.recompiles.inc(retraced)
-                metrics.compile_seconds.set(dt)
             else:
                 # steady-state step: self-report MFU / HBM utilization
                 # against the program's cost_analysis facts (compile steps
