@@ -466,9 +466,12 @@ class ComputationGraph:
         chunks = sorted({layer.chunk for layer in delta})
         if chunks:
             attrs["kda_chunk"] = chunks[0] if len(chunks) == 1 else chunks
-        kernels = sorted({layer.kernel for layer in delta} - {None})
-        if kernels:
-            attrs["kda_kernel"] = kernels[0]
+        for key, kind in (("kda_kernel", "kernel"),
+                          ("kda_bwd_kernel", "bwd_kernel")):
+            kernels = sorted({getattr(layer, kind) for layer in delta}
+                             - {None})
+            if kernels:
+                attrs[key] = kernels[0]
         return attrs
 
     def evaluate(self, iterator, top_n: int = 1):

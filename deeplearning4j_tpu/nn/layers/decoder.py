@@ -346,11 +346,11 @@ def _chunk_phase(q, k, v, g, beta, *, chunk: int):
     is a matrix product's operand leaves in the compute dtype.
 
     In ``jax.numpy``: what :func:`chunked_delta_rule` runs, and what
-    ``DeltaAttention`` runs at any shape for the backward pass and, where
-    ``ops.pallas.kda_chunk`` does not serve its head size and chunk, for
-    the forward too.  Where it does, the forward is that kernel, the same
-    equations at the same precision, with ``G`` a product with the lower
-    triangle of ones (Mosaic lowers no ``cumsum``)."""
+    ``DeltaAttention`` runs both ways where ``ops.pallas.kda_chunk`` does
+    not serve its head size and chunk.  Where it does, the forward and the
+    backward are that module's two kernels, the same equations at the same
+    precision, with ``G`` a product with the lower triangle of ones
+    (Mosaic lowers no ``cumsum``)."""
     if chunk & (chunk - 1) or chunk < PAIRWISE:
         raise ValueError(f"chunk has to be a power of two of at least "
                          f"{PAIRWISE}, got {chunk}")
@@ -438,7 +438,8 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
 
     All of it in ``jax.numpy``, at any shape and both ways: the plain form
     of what ``DeltaAttention`` runs, whose chunk phase goes forward
-    through the ``tpudl_kda_chunk`` kernel where it can."""
+    through the ``tpudl_kda_chunk`` kernel and backward through
+    ``tpudl_kda_chunk_bwd`` where they can."""
     return _scan_and_read(*_map_head_groups(
         functools.partial(_chunk_phase, chunk=chunk), (q, k, v, g, beta),
         (2,) * 5, head_group), t=k.shape[1])
@@ -482,16 +483,6 @@ def _kda_grouped(xs, chunk: int):
         xs, _KDA_HEAD_AXES, HEAD_GROUP)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "compute_dtype"))
-def _kda_grouped_vjp(xs, cts, *, chunk: int, compute_dtype):
-    """The cotangents of :func:`_kda_grouped`'s inputs: the backward pass
-    of the kernel's chunk phase, traced once for every layer of a shape
-    (``compute_dtype`` keys the trace: the policy is read while tracing).
-    The primal outputs ``jax.vjp`` makes are never read, so the compiler
-    drops them."""
-    return jax.vjp(lambda *xs: _kda_grouped(xs, chunk), *xs)[1](cts)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _kda_chunks(chunk, compute_dtype, interpret, *xs):
     return _kda_chunks_fwd(chunk, compute_dtype, interpret, *xs)[0]
@@ -508,7 +499,8 @@ def _kda_chunks_fwd(chunk, compute_dtype, interpret, *xs):
 
 
 def _kda_chunks_bwd(chunk, compute_dtype, interpret, xs, cts):
-    return _kda_grouped_vjp(xs, cts, chunk=chunk, compute_dtype=compute_dtype)
+    return _kda_kernel_vjp(xs, cts, chunk=chunk, compute_dtype=compute_dtype,
+                           interpret=interpret)
 
 
 _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
@@ -516,13 +508,38 @@ _kda_chunks.defvjp(_kda_chunks_fwd, _kda_chunks_bwd)
 
 @functools.partial(jax.jit, static_argnames=("chunk", "compute_dtype",
                                              "interpret"))
+def _kda_kernel_vjp(xs, cts, *, chunk: int, compute_dtype, interpret: bool):
+    """The cotangents of the projections' outputs from those of the chunk
+    phase's six results: the ``tpudl_kda_chunk_bwd`` kernel for all heads
+    at once, then back through the convolutions, norms and gates
+    (``jax.vjp`` of :func:`_kda_inputs`, recomputed from ``xs``).  One jit
+    for every layer of a shape: the step traces and lowers it once.
+
+    Memory: the kernel's float32 inputs (four ``[T, H d]`` arrays, 537 MB
+    at the Kimi cell's shapes) are made only once the cotangents are
+    there (the barrier: else the compiler computes them from ``xs`` early,
+    beside the scan's backward, and the step's temporaries grow by half a
+    gigabyte), and the convolutions' and gates' own residuals are
+    recomputed in their backward rather than kept beside the kernel."""
+    from deeplearning4j_tpu.ops.pallas.kda_chunk import kda_chunk_bwd
+    xs, cts = jax.lax.optimization_barrier((xs, cts))
+    inputs, pull = jax.vjp(jax.checkpoint(_kda_inputs), *xs)
+    with jax.named_scope("kda.chunk"):
+        grads = kda_chunk_bwd(
+            *inputs, cts, chunk=chunk,
+            head_group=_group_size(inputs[0].shape[2], HEAD_GROUP),
+            compute_dtype=compute_dtype, interpret=interpret)
+    return pull(grads)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "compute_dtype",
+                                             "interpret"))
 def _kda_kernel_chunks(xs, *, chunk: int, compute_dtype, interpret: bool):
-    """:func:`_kda_grouped`'s results, forward through the
-    ``tpudl_kda_chunk`` kernel for all heads at once (short convolutions,
-    norms and gates in ``jax.numpy`` before it), backward by
-    :func:`_kda_grouped_vjp` from the same inputs, which are all the
-    backward pass keeps.  One jit for every layer of a shape: the step
-    traces and lowers it once."""
+    """:func:`_kda_grouped`'s results through the ``tpudl_kda_chunk``
+    kernel for all heads at once (short convolutions, norms and gates in
+    ``jax.numpy`` before it), backward by :func:`_kda_kernel_vjp` from
+    the same inputs, which are all the backward pass keeps.  One jit for
+    every layer of a shape: the step traces and lowers it once."""
     return _kda_chunks(chunk, compute_dtype, interpret, *xs)
 
 
@@ -547,11 +564,13 @@ class DeltaAttention(Layer):
     Kimi cell's 128 and 64), its forward, and the rematerialised run's
     recomputed forward, is the ``tpudl_kda_chunk`` kernel for all heads
     at once after the convolutions and gates in ``jax.numpy``; its
-    backward pass is the ``jax.numpy`` path's, by groups of
-    ``HEAD_GROUP`` heads each rematerialised, from the projections'
-    outputs it keeps.  At any other shape both ways are that grouped
-    ``jax.numpy`` path.  The scan and the read are ``jax.numpy`` at every
-    shape."""
+    backward pass is the ``tpudl_kda_chunk_bwd`` kernel for all heads at
+    once, from the projections' outputs it keeps, then the convolutions'
+    and gates' own backward in ``jax.numpy`` (rematerialised: their
+    residuals would not fit beside the kernel's operands).  At any other
+    shape both ways are the ``jax.numpy`` path, by groups of
+    ``HEAD_GROUP`` heads each rematerialised.  The scan and the read are
+    ``jax.numpy`` at every shape."""
 
     INPUT_KIND = "rnn"
     ATTENTION_KIND = "kda"            # ``ComputationGraph.trace_attrs``
@@ -591,6 +610,13 @@ class DeltaAttention(Layer):
         from deeplearning4j_tpu.ops.pallas import kda_chunk
         return kda_chunk.KERNEL_NAME if kda_chunk.takes(
             self.head_dim, self.chunk) else None
+
+    @property
+    def bwd_kernel(self) -> str | None:
+        """The kernel the chunk phase's backward pass runs as, wherever
+        its forward runs as :attr:`kernel`."""
+        from deeplearning4j_tpu.ops.pallas import kda_chunk
+        return kda_chunk.BWD_KERNEL_NAME if self.kernel else None
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         b, t, _ = x.shape

@@ -39,10 +39,12 @@ from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn.layers.decoder import (DeltaAttention,
                                                   LatentAttention,
                                                   _chunk_phase,
+                                                  _map_head_groups,
                                                   _scan_and_read,
                                                   chunked_delta_rule,
                                                   short_conv)
-from deeplearning4j_tpu.ops.pallas.kda_chunk import kda_chunk
+from deeplearning4j_tpu.ops.pallas.kda_chunk import (kda_chunk,
+                                                     kda_chunk_bwd)
 from deeplearning4j_tpu.train.trainer import make_loss_fn
 
 BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
@@ -180,15 +182,15 @@ def test_three_fit_steps_match_first_steps(reference, float32_policy):
 
 
 # ---- (c) the chunked scan against the token recurrence -------------------------
-def _scan_inputs(t, decay, heads=2, d=16):
+def _scan_inputs(t, decay, heads=2, d=16, least=0.05):
     """Unit keys, queries over sqrt(d), a per-step log decay between
-    ``decay`` and a twentieth of it."""
+    ``decay`` and ``least`` of it (a twentieth)."""
     ks = jax.random.split(jax.random.key(t), 5)
     q, k = (jax.random.normal(kk, (1, t, heads, d)) for kk in ks[:2])
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     v = jax.random.normal(ks[2], (1, t, heads, d))
-    g = decay * jax.random.uniform(ks[3], (1, t, heads, d), minval=0.05)
+    g = decay * jax.random.uniform(ks[3], (1, t, heads, d), minval=least)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, heads)))
     return q, k, v, g, beta
 
@@ -248,8 +250,8 @@ def test_chunked_scan_equals_the_token_recurrence(float32_policy, phase,
 
     ``kernel``: the chunk phase as ``tpudl_kda_chunk`` at a head size of
     128, its six results against ``_chunk_phase``'s and the outputs and
-    last state through the scan against the recurrence (its gradient is
-    the ``jax.numpy`` phase's: ``test_layer_with_the_kernel_equals_...``)."""
+    last state through the scan against the recurrence (its gradient,
+    ``tpudl_kda_chunk_bwd``: ``test_kernel_backward_equals_the_jnp_...``)."""
     if phase == "kernel":
         x = _scan_inputs(t, decay, d=128)
         rule, reference_phase, recurrence = _kernel_rule(chunk)
@@ -278,6 +280,14 @@ def test_chunk_has_to_be_a_power_of_two():
         chunked_delta_rule(*_scan_inputs(48, -0.1), chunk=48)
 
 
+def _equal_keys(t=64, d=128):
+    """One key for every token, the query half of it, no decay, ``beta``
+    1: ``A`` all ones below the diagonal, whose powers reach 1e17."""
+    k = jnp.zeros((1, t, 1, d)).at[..., 0].set(1.0)
+    v = jax.random.normal(jax.random.key(0), (1, t, 1, d))
+    return 0.5 * k, k, v, jnp.zeros_like(k), jnp.ones((1, t, 1))
+
+
 @pytest.mark.parametrize("phase", ["jnp", "kernel"])
 def test_equal_keys_without_decay_invert_exactly(float32_policy, phase):
     """``A`` all ones below the diagonal, whose powers reach 1e17: the
@@ -286,24 +296,91 @@ def test_equal_keys_without_decay_invert_exactly(float32_policy, phase):
     what the one key holds, so ``o_t = v_t (k . q)``.  ``kernel``: the
     same through ``tpudl_kda_chunk``'s doubling on the whole tile, at a
     head size of 128."""
-    t, d = 64, 16 if phase == "jnp" else 128
-    k = jnp.zeros((1, t, 1, d)).at[..., 0].set(1.0)
-    v = jax.random.normal(jax.random.key(0), (1, t, 1, d))
-    x = (0.5 * k, k, v, jnp.zeros_like(k), jnp.ones((1, t, 1)))
+    x = _equal_keys(d=16 if phase == "jnp" else 128)
     if phase == "jnp":
         o, _ = jax.jit(functools.partial(chunked_delta_rule, chunk=64))(*x)
     else:
         _, (o, _) = _kernel_rule(64)[0](*x)
-    np.testing.assert_allclose(o, 0.5 * v, atol=1e-6)
+    np.testing.assert_allclose(o, 0.5 * x[2], atol=1e-6)
+
+
+# (inputs, chunk, heads a group) for the backward kernel
+_BWD_CASES = {
+    "random": (lambda: _scan_inputs(128, -0.1, d=128), 64, 2),
+    "equal_keys_no_decay": (_equal_keys, 64, 1),
+    # -1.44 to -1.6 a step: exp(-G) overflows float32 within a chunk of 64
+    "strongest_decay": (lambda: _scan_inputs(128, -1.6, d=128, least=0.9),
+                        64, 2),
+    "ragged": (lambda: _scan_inputs(100, -0.1, d=128), 16, 2),
+    "head_groups": (lambda: _scan_inputs(128, -0.1, heads=4, d=128), 64, 2),
+}
+
+
+def _phase_vjp(x, cts, chunk, head_group, policy):
+    """``jax.vjp`` of ``_chunk_phase`` by groups of ``head_group`` heads
+    under ``policy``, and of ``tpudl_kda_chunk_bwd`` (interpret mode),
+    from the cotangents ``cts`` (cast to each result's dtype)."""
+    was = dtype_policy()
+    set_dtype_policy(policy)
+    try:
+        results, pull = jax.vjp(lambda *x: _map_head_groups(
+            functools.partial(_chunk_phase, chunk=chunk), x, (2,) * 5,
+            head_group), *x)
+        cts = tuple(c.astype(r.dtype) for c, r in zip(cts, results))
+        return jax.jit(pull)(cts), jax.jit(functools.partial(
+            kda_chunk_bwd, chunk=chunk, head_group=head_group,
+            compute_dtype=policy.compute_dtype))(*x, cts)
+    finally:
+        set_dtype_policy(was)
+
+
+@pytest.mark.parametrize("policy", ["f32", "bf16"])
+@pytest.mark.parametrize("case", list(_BWD_CASES))
+def test_kernel_backward_equals_the_jnp_phase(case, policy):
+    """``tpudl_kda_chunk_bwd`` against ``jax.vjp`` of ``_chunk_phase`` by
+    the same groups of heads, from the same random cotangents of the six
+    results (drawn in bfloat16, so that both policies read the same
+    values): the cotangents of ``q``, ``k``, ``v``, ``g`` and ``beta`` are
+    finite and, under the float32 policy, equal to 1e-5 of each array's
+    largest entry (read when written: 4e-6 at most).  Under the bfloat16
+    policy both round the products' operands: the kernel's distance from
+    the float32 ``jax.numpy`` result, as a norm, is at most half that
+    path's own under bfloat16 (read: 0.22 at most; with its products'
+    operands rounded once, as the forward's, ``g``'s read 1.3-2.0), and
+    under 1e-2 of the largest entry."""
+    make, chunk, head_group = _BWD_CASES[case]
+    x = make()
+    shapes = jax.eval_shape(lambda *x: _map_head_groups(
+        functools.partial(_chunk_phase, chunk=chunk), x, (2,) * 5,
+        head_group), *x)
+    cts = tuple(jax.random.normal(jax.random.key(20 + i), r.shape)
+                .astype(jnp.bfloat16) for i, r in enumerate(shapes))
+    want, got = _phase_vjp(x, cts, chunk, head_group, DTypePolicy.f32())
+    if policy == "bf16":
+        jnp_bf16, got = _phase_vjp(x, cts, chunk, head_group,
+                                   DTypePolicy.bf16())
+    for i, name in enumerate(("q", "k", "v", "g", "beta")):
+        assert got[i].shape == want[i].shape, name
+        assert got[i].dtype == jnp.float32, name
+        assert bool(jnp.all(jnp.isfinite(got[i]))), name
+        scale = max(1.0, float(jnp.max(jnp.abs(want[i]))))
+        gap = float(jnp.max(jnp.abs(got[i] - want[i])))
+        if policy == "f32":
+            assert gap <= 1e-5 * scale, (name, gap, scale)
+        else:
+            assert gap < 1e-2 * scale, (name, gap, scale)
+            norm, own = (float(jnp.linalg.norm(y[i] - want[i]))
+                         for y in (got, jnp_bf16))
+            assert norm <= 0.5 * own, (name, norm, own)
 
 
 def test_layer_with_the_kernel_equals_the_jnp_path(float32_policy,
                                                    monkeypatch):
-    """``DeltaAttention`` at a head size of 128 takes the kernel (its
-    forward; the backward is the grouped ``jax.numpy`` path from the
-    projections' outputs): its outputs and ``jax.grad`` of every parameter
-    and of the input equal the ``jax.numpy`` path's, a ragged length in
-    chunks of 16, two heads, one group."""
+    """``DeltaAttention`` at a head size of 128 takes both kernels (the
+    forward, and the backward from the projections' outputs): its outputs
+    and ``jax.grad`` of every parameter, each by name, and of the input
+    equal the ``jax.numpy`` path's, a ragged length in chunks of 16, two
+    heads, one group."""
     layer = DeltaAttention(n_heads=2, head_dim=128, chunk=16)
     t = 100
     params = layer.init_params(jax.random.key(0), InputType.recurrent(64, t))
@@ -318,13 +395,19 @@ def test_layer_with_the_kernel_equals_the_jnp_path(float32_policy,
                                           has_aux=True))(params, x)
 
     assert layer.kernel == "tpudl_kda_chunk"
-    (_, got_out), got_grads = run()
+    assert layer.bwd_kernel == "tpudl_kda_chunk_bwd"
+    (_, got_out), (got_params, got_x) = run()
     monkeypatch.setattr(DeltaAttention, "kernel", property(lambda _: None))
-    (_, want_out), want_grads = run()
+    assert layer.bwd_kernel is None
+    (_, want_out), (want_params, want_x) = run()
     assert float(jnp.max(jnp.abs(want_out))) > 1e-3
-    for got, want in zip(jax.tree_util.tree_leaves((got_out, got_grads)),
-                         jax.tree_util.tree_leaves((want_out, want_grads))):
-        _close(got, want)
+    _close(got_out, want_out)
+    _close(got_x, want_x)
+    assert sorted(got_params) == sorted(want_params) == sorted(params)
+    for name in params:
+        # every parameter's gradient is there to compare
+        assert float(jnp.max(jnp.abs(want_params[name]))) > 0, name
+        _close(got_params[name], want_params[name])
 
 
 # ---- (d) the short convolution, and the block's causality ----------------------
@@ -419,14 +502,16 @@ def test_trace_attrs_carry_the_attention_kinds_and_the_chunk():
     assert attrs["kda_chunk"] == 32
     assert attrs["remat_runs"] == 10       # a block is two runs
     assert "kda_kernel" not in attrs       # heads of 16: the jnp path
+    assert "kda_bwd_kernel" not in attrs
     config = small_config()
     config["linear_attn_config"] = dict(config["linear_attn_config"],
                                         head_dim=128)
     attrs = kimi_linear(config, SEQ, seed=SEED).trace_attrs()
     assert attrs["kda_kernel"] == "tpudl_kda_chunk"
+    assert attrs["kda_bwd_kernel"] == "tpudl_kda_chunk_bwd"
     attrs = resnet50(height=32, width=32, num_classes=10).trace_attrs()
     assert "attention_kinds" not in attrs and "kda_chunk" not in attrs
-    assert "kda_kernel" not in attrs
+    assert "kda_kernel" not in attrs and "kda_bwd_kernel" not in attrs
 
 
 def test_the_layer_starts_inside_the_stated_decays():
