@@ -19,6 +19,7 @@ from deeplearning4j_tpu.models.zoo import (
     resnet50,
     joyai_llm_flash,
     kimi_linear,
+    lfm2_moe,
     lstm_classifier,
     text_gen_lstm,
 )
@@ -36,7 +37,7 @@ from deeplearning4j_tpu.models import bert
 
 __all__ = [
     "mlp_mnist", "lenet", "simple_cnn", "alexnet", "vgg16", "vgg19",
-    "resnet50", "joyai_llm_flash", "kimi_linear",
+    "resnet50", "joyai_llm_flash", "kimi_linear", "lfm2_moe",
     "lstm_classifier", "text_gen_lstm", "bert",
     "squeezenet", "darknet19", "tiny_yolo", "yolo2", "unet", "xception",
     "inception_resnet_v1", "nasnet_mobile",

@@ -20,6 +20,10 @@ Parity notes per model (reference classes under
   Attention (a gated delta rule run as a chunked scan) in three blocks of
   four and latent attention without positions in the fourth, the same
   routed experts, from its published ``config.json``.
+- ``lfm2_moe`` has none either: LFM2-8B-A1B's hybrid of double-gated
+  short convolutions and grouped-query attention, the same routed experts
+  without a shared one, a head tied to the embedding, from its published
+  ``config.json``.
 - ``mlp_mnist`` / ``lstm_classifier`` → dl4j-examples workloads named in
   BASELINE.json (MLPMnistTwoLayerExample; UCI HAR sequence classification).
 
@@ -42,7 +46,8 @@ from deeplearning4j_tpu.nn.layers import (
 )
 from deeplearning4j_tpu.nn.layers import (
     CausalLMOutput, DeltaAttention, EmbeddingSequenceLayer, GatedFeedForward,
-    LatentAttention, RMSNorm, RoutedExperts,
+    GatedShortConv, GroupedQueryAttention, LatentAttention, RMSNorm,
+    RoutedExperts,
 )
 from deeplearning4j_tpu.nn.weights import distribution
 from deeplearning4j_tpu.nn.vertices import (
@@ -311,10 +316,11 @@ def _latent_attention(c: dict, std: float, rotary: bool = True):
 
 
 def _feed_forward(c: dict, std: float, *, routed: bool, experts: int,
-                  top_k: int, shared: int, normalize: bool):
+                  top_k: int, shared: int, normalize: bool,
+                  norm_eps: float = 1e-20):
     """A block's feed-forward: routed experts (the chip's share from the
     keys ``experts_held`` and ``first_expert``) or the dense SwiGLU.  What
-    the two published families name differently comes as arguments."""
+    the published families name differently comes as arguments."""
     if not routed:
         return GatedFeedForward(hidden=c["intermediate_size"], init_std=std)
     return RoutedExperts(
@@ -323,7 +329,7 @@ def _feed_forward(c: dict, std: float, *, routed: bool, experts: int,
         hidden=c["moe_intermediate_size"],
         shared_hidden=c["moe_intermediate_size"] * shared,
         routed_scaling_factor=c["routed_scaling_factor"],
-        norm_topk_prob=normalize, init_std=std)
+        norm_topk_prob=normalize, norm_eps=norm_eps, init_std=std)
 
 
 def joyai_llm_flash(config: dict, seq_len: int, seed: int = 123,
@@ -441,6 +447,69 @@ def kimi_linear(config: dict, seq_len: int, seed: int = 123, updater=None,
     gb.add_layer("final_norm", RMSNorm(eps=eps), x)
     gb.add_layer("lm_head", CausalLMOutput(
         n_out=c["vocab_size"], init_std=init_std), "final_norm")
+    gb.set_outputs("lm_head")
+    return ComputationGraph(gb.build())
+
+
+def lfm2_moe(config: dict, seq_len: int, seed: int = 123, updater=None,
+             init_std: float = 0.02) -> ComputationGraph:
+    """LFM2's mixture of experts (``model_type`` ``lfm2_moe``, LFM2-8B-A1B)
+    from the keys of its published ``config.json``.  ``layer_types`` says,
+    layer by layer and counting from 0, which token mixer a block holds:
+    the double-gated short convolution (``conv``: ``conv_L_cache`` taps, no
+    bias) or grouped-query attention (``full_attention``:
+    ``num_attention_heads`` query heads and ``num_key_value_heads`` K/V
+    heads of ``hidden_size / num_attention_heads``, norms on the query and
+    key heads, rotary positions at ``rope_theta``).  The first
+    ``num_dense_layers`` blocks held have a dense SwiGLU, the others
+    ``num_experts`` sigmoid-routed experts (``num_experts_per_tok``,
+    ``norm_topk_prob`` with ``norm_topk_eps`` under the sum,
+    ``routed_scaling_factor``, a selection bias) and no shared one; every
+    norm's eps is ``norm_eps``; the head is tied to the embedding.
+
+    A chip's share of a deployment is three more keys: ``first_layer``,
+    the published index of the first layer held (blocks are ``l<index>``,
+    ``num_hidden_layers`` of them); ``experts_held`` from ``first_expert``
+    on, as for :func:`joyai_llm_flash`; and ``vocab_size`` the slice held.
+    ``net.fit`` over ``DataSet(ids, ids)``."""
+    c, eps = config, config["norm_eps"]
+    if c.get("conv_bias"):
+        raise ValueError("lfm2_moe builds the convolution without a bias, "
+                         "the config states conv_bias true")
+    if c.get("tie_word_embeddings") is False:
+        raise ValueError("lfm2_moe ties its head to the embedding, the "
+                         "config states tie_word_embeddings false")
+    first = c.get("first_layer", 0)
+    kinds = c["layer_types"][first:first + c["num_hidden_layers"]]
+    if len(kinds) != c["num_hidden_layers"]:
+        raise ValueError(f"layer_types holds no layers {first} .. "
+                         f"{first + c['num_hidden_layers'] - 1}")
+    heads = c["num_attention_heads"]
+    gb = _lm_graph(c, seq_len, seed, updater, init_std)
+    x = "embed"
+    for held, kind in enumerate(kinds):
+        if kind == "conv":
+            mixer = GatedShortConv(taps=c["conv_L_cache"], init_std=init_std)
+        elif kind == "full_attention":
+            mixer = GroupedQueryAttention(
+                n_heads=heads, n_kv_heads=c["num_key_value_heads"],
+                head_dim=c["hidden_size"] // heads,
+                rope_theta=float(c["rope_theta"]), eps=eps,
+                init_std=init_std)
+        else:
+            raise ValueError(f"layer_types names a layer {kind!r}: "
+                             f"lfm2_moe knows conv and full_attention")
+        ffn = _feed_forward(c, init_std, routed=held >= c["num_dense_layers"],
+                            experts=c["num_experts"],
+                            top_k=c["num_experts_per_tok"], shared=0,
+                            normalize=c["norm_topk_prob"],
+                            norm_eps=c["norm_topk_eps"])
+        x = _decoder_block(gb, f"l{first + held}", x, attention=mixer,
+                           ffn=ffn, eps=eps)
+    gb.add_layer("final_norm", RMSNorm(eps=eps), x)
+    gb.add_layer("lm_head", CausalLMOutput(
+        n_out=c["vocab_size"], init_std=init_std, tie_to="embed"),
+        "final_norm")
     gb.set_outputs("lm_head")
     return ComputationGraph(gb.build())
 

@@ -300,6 +300,13 @@ class ComputationGraph:
     def num_params(self) -> int:
         return param_count(self.params_)
 
+    @staticmethod
+    def _owner(spec: VertexSpec) -> str:
+        """The vertex whose parameters ``spec`` runs on: its own, or for a
+        layer tied to another (``tie_to``, a tied output head) that one's,
+        so that one leaf serves both and its gradient sums their uses."""
+        return getattr(spec.obj, "tie_to", "") or spec.name
+
     def params(self) -> jnp.ndarray:
         return flat_param_vector(self.params_)
 
@@ -355,20 +362,19 @@ class ComputationGraph:
                     layer_rng = jax.random.fold_in(rng, vi) if rng is not None else None
                     itype = known_types[spec.inputs[0]]
                     x = preprocessors.adapt_array(in_acts[0], itype, spec.obj)
+                    own = params[self._owner(spec)]
                     if (labels is not None and spec.name in self.conf.outputs
                             and hasattr(spec.obj, "compute_score_array")):
                         out_idx = self.conf.outputs.index(spec.name)
                         # same noised weights as apply(): IWeightNoise applies
                         # to the loss path too (DL4J BaseLayer.getParamWithNoise)
                         score_arrays.append(spec.obj.compute_score_array(
-                            spec.obj.noised_params(params[spec.name], train,
-                                                   layer_rng),
+                            spec.obj.noised_params(own, train, layer_rng),
                             state[spec.name], x,
                             label_list[out_idx], train=train, rng=layer_rng,
                             mask=in_mask))
                     y, s = spec.obj.apply(
-                        spec.obj.noised_params(params[spec.name], train,
-                                               layer_rng),
+                        spec.obj.noised_params(own, train, layer_rng),
                         state[spec.name], x,
                         train=train, rng=layer_rng, mask=in_mask)
                     known_types[spec.name] = spec.obj.get_output_type(
@@ -396,6 +402,8 @@ class ComputationGraph:
             last, reads, keeps = segments[vi]
             specs = list(enumerate(self._topo[vi:last + 1], vi))
             names = [sp.name for _, sp in specs]
+            owners = names + sorted({self._owner(sp) for _, sp in specs}
+                                    - set(names))
 
             def segment(seg_params, seg_state, seg_acts, seg_masks, seg_rng):
                 local, local_masks, states = dict(seg_acts), dict(seg_masks), {}
@@ -408,7 +416,7 @@ class ComputationGraph:
             kept, kept_masks, states = jax.checkpoint(
                 segment, policy=jax.checkpoint_policies.save_only_these_names(
                     *_remat_keeps()))(
-                {n: params[n] for n in names}, {n: state[n] for n in names},
+                {n: params[n] for n in owners}, {n: state[n] for n in names},
                 {n: acts[n] for n in reads},
                 {n: act_masks.get(n) for n in reads}, rng)
             acts.update(kept)
@@ -462,6 +470,11 @@ class ComputationGraph:
         if attention:
             attrs["attention_kinds"] = [layer.ATTENTION_KIND
                                         for layer in attention]
+        groups = sorted({layer.n_heads // layer.n_kv_heads
+                         for layer in attention
+                         if getattr(layer, "n_kv_heads", 0)})
+        if groups:          # query heads a K/V head serves
+            attrs["kv_groups"] = groups[0] if len(groups) == 1 else groups
         delta = [layer for layer in attention if layer.ATTENTION_KIND == "kda"]
         chunks = sorted({layer.chunk for layer in delta})
         if chunks:

@@ -67,6 +67,8 @@ from deeplearning4j_tpu.nn.layers.decoder import (
     GatedFeedForward,
     LatentAttention,
     DeltaAttention,
+    GatedShortConv,
+    GroupedQueryAttention,
     RoutedExperts,
     CausalLMOutput,
 )
@@ -116,7 +118,7 @@ __all__ = [
     "SelfAttentionLayer", "LearnedSelfAttentionLayer",
     "LayerNormalization", "PReLULayer",
     "RMSNorm", "GatedFeedForward", "LatentAttention", "DeltaAttention",
-    "RoutedExperts",
+    "GatedShortConv", "GroupedQueryAttention", "RoutedExperts",
     "CausalLMOutput",
     "ZeroPadding1DLayer", "Cropping1DLayer", "Upsampling1DLayer",
     "ZeroPadding3DLayer", "Cropping3DLayer", "Upsampling3DLayer",

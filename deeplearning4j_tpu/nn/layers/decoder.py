@@ -6,10 +6,14 @@ them: RMS norm, the gated (SwiGLU) feed-forward, latent attention (MLA)
 with rotary positions inside it or none at all, routed experts beside a
 shared expert with a layer that is told which experts it holds, and the
 next-token output layer that owns the head once for the main and the
-multi-token-prediction stream; and Kimi Linear's delta attention (KDA,
+multi-token-prediction stream, or reads the embedding's matrix as its
+own (a tied head); Kimi Linear's delta attention (KDA,
 arXiv:2510.26692): a gated delta rule whose state is carried along the
-sequence, run as a chunked scan.  ``models.zoo.joyai_llm_flash`` and
-``models.zoo.kimi_linear`` wire them into a ``ComputationGraph``.
+sequence, run as a chunked scan; and LFM2's two token mixers: the
+double-gated short convolution and grouped-query attention with a norm on
+each query and key head and rotary positions on half-split pairs.
+``models.zoo.joyai_llm_flash``, ``models.zoo.kimi_linear`` and
+``models.zoo.lfm2_moe`` wire them into a ``ComputationGraph``.
 
 Precision follows the dtype policy as ``DenseLayer`` does (float32
 parameters cast to the compute dtype at use, outputs in the output
@@ -69,6 +73,21 @@ def rotate_interleaved(x, theta: float):
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotate_half(x, theta: float):
+    """Rotary positions on the pairs (i, i + d/2) of the last axis (the
+    ``rotate_half`` form); axis 1 is the position.  angle = position *
+    theta**(-2i / d), in float32."""
+    t, d, wide = x.shape[1], x.shape[-1], _wide(x.dtype)
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=wide) / d)
+    angle = jnp.arange(t, dtype=wide)[:, None] * freq[None, :]
+    angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (d // 2,))
+    first, second = jnp.split(x.astype(wide), 2, axis=-1)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return jnp.concatenate([first * cos - second * sin,
+                            second * cos + first * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -203,16 +222,110 @@ class LatentAttention(Layer):
             return _linear(ctx, params["W_o"]), state
 
 
-def short_conv(x, w):
-    """Causal depthwise convolution along axis 1, then SiLU: ``y[t, c] =
-    silu(sum_j w[c, j] * x[t - (K - 1) + j, c])`` with zeros before ``t =
-    0``, so position ``t`` reads ``t - K + 1 .. t``.  ``x`` ``[B, T, P]``,
-    ``w`` ``[P, K]``; summed in float32 and handed on so."""
+def causal_taps(x, w):
+    """Causal depthwise convolution along axis 1: ``y[t, c] = sum_j w[c,
+    j] * x[t - (K - 1) + j, c]`` with zeros before ``t = 0``, so position
+    ``t`` reads ``t - K + 1 .. t`` and rows never mix.  ``x`` ``[B, T,
+    P]``, ``w`` ``[P, K]``; summed in float32 and handed on so."""
     taps, t, wide = w.shape[-1], x.shape[1], _wide(x.dtype)
     padded = jnp.pad(x.astype(wide), ((0, 0), (taps - 1, 0), (0, 0)))
     w = w.astype(wide)
-    return jax.nn.silu(sum(padded[:, j:j + t] * w[:, j]
-                           for j in range(taps)))
+    return sum(padded[:, j:j + t] * w[:, j] for j in range(taps))
+
+
+def short_conv(x, w):
+    """:func:`causal_taps`, then SiLU (KDA's short convolution)."""
+    return jax.nn.silu(causal_taps(x, w))
+
+
+@register_layer("gated_short_conv")
+@dataclasses.dataclass
+class GatedShortConv(Layer):
+    """LFM2's double-gated short convolution, a token mixer: ``[B~ ; C~ ;
+    x~] = x W_in`` (three ``d``-wide parts), ``y = C~ * causal_taps(B~ *
+    x~)`` over ``taps`` positions with no activation and no bias, then
+    ``W_out``.  The gates' products and the tap sum in float32."""
+
+    INPUT_KIND = "rnn"
+    ATTENTION_KIND = "conv"           # ``ComputationGraph.trace_attrs``
+
+    taps: int = 3
+    init_std: float = 0.02
+
+    def init_params(self, key, input_type):
+        d, dt = input_type.size, self._param_dtype()
+        shapes = {"W_in": (d, 3 * d), "conv_w": (d, self.taps),
+                  "W_out": (d, d)}
+        keys = jax.random.split(key, len(shapes))
+        return {name: _normal(k, shape, self.init_std, dt)
+                for k, (name, shape) in zip(keys, shapes.items())}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        wide = _wide(x.dtype)
+        with jax.named_scope("conv"):
+            with jax.named_scope("conv.proj"):
+                gate_b, gate_c, u = jnp.split(_linear(x, params["W_in"]), 3,
+                                              axis=-1)
+            with jax.named_scope("conv.taps"):
+                z = causal_taps(gate_b.astype(wide) * u.astype(wide),
+                                params["conv_w"])
+            with jax.named_scope("conv.out"):
+                return _linear(gate_c.astype(wide) * z, params["W_out"]), \
+                    state
+
+
+@register_layer("grouped_query_attention")
+@dataclasses.dataclass
+class GroupedQueryAttention(Layer):
+    """Causal grouped-query attention as LFM2 has it: ``n_heads`` query
+    heads and ``n_kv_heads`` K/V heads of ``head_dim``; an RMS norm on
+    every query and key head (one ``head_dim``-wide scale each, shared by
+    the heads); rotary positions on the half-split pairs (``rotate_half``)
+    of both; ``softmax(q k^T / sqrt(head_dim)) v`` with query head ``h``
+    reading K/V head ``h // (n_heads / n_kv_heads)``; ``W_o``.  No bias.
+    ``ops.attention`` takes the flash kernels from 1,024 tokens on, the
+    einsum chain below, with the same grouping and no K/V head copied."""
+
+    INPUT_KIND = "rnn"
+    ATTENTION_KIND = "gqa"            # ``ComputationGraph.trace_attrs``
+
+    n_heads: int = 1
+    n_kv_heads: int = 1
+    head_dim: int = 0
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    init_std: float = 0.02
+
+    def init_params(self, key, input_type):
+        d, dt = input_type.size, self._param_dtype()
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        shapes = {"W_q": (d, q), "W_k": (d, kv), "W_v": (d, kv),
+                  "W_o": (q, d)}
+        keys = jax.random.split(key, len(shapes))
+        params = {name: _normal(k, shape, self.init_std, dt)
+                  for k, (name, shape) in zip(keys, shapes.items())}
+        params["q_norm"] = jnp.ones((self.head_dim,), dt)
+        params["k_norm"] = jnp.ones((self.head_dim,), dt)
+        return params
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        b, t, _ = x.shape
+        h, kv, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        with jax.named_scope("gqa"):
+            q = _linear(x, params["W_q"]).reshape(b, t, h, dh)
+            k = _linear(x, params["W_k"]).reshape(b, t, kv, dh)
+            v = _linear(x, params["W_v"])
+            with jax.named_scope("gqa.qk_norm"):
+                q = rms_norm(q, params["q_norm"], self.eps)
+                k = rms_norm(k, params["k_norm"], self.eps)
+            with jax.named_scope("gqa.rope"):
+                q = rotate_half(q, self.rope_theta)
+                k = rotate_half(k, self.rope_theta)
+            with jax.named_scope("gqa.core"):
+                ctx = multi_head_attention(
+                    q.reshape(b, t, h * dh), k.reshape(b, t, kv * dh), v,
+                    n_heads=h, n_kv_heads=kv, causal=True)
+            return _linear(ctx, params["W_o"]), state
 
 
 def _compute_dot(spec: str, a, b):
@@ -650,17 +763,18 @@ class DeltaAttention(Layer):
                 return _linear(o.reshape(b, t, h * dh), params["W_o"]), state
 
 
-def route(logits, bias, *, top_k: int, scale: float, normalize: bool = True):
+def route(logits, bias, *, top_k: int, scale: float, normalize: bool = True,
+          eps: float = 1e-20):
     """Sigmoid scores, the ``top_k`` experts with the largest ``score +
     bias`` (the ``noaux_tc`` selection bias takes part in the choice and
-    not in the gate), gates ``scale * s_e / sum of the chosen s``.
-    ``logits`` float32 ``[N, E]`` -> (experts ``[N, k]`` int32, gates
-    ``[N, k]`` float32)."""
+    not in the gate), gates ``scale * s_e / (sum of the chosen s +
+    eps)``.  ``logits`` float32 ``[N, E]`` -> (experts ``[N, k]`` int32,
+    gates ``[N, k]`` float32)."""
     scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + bias, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
-        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return chosen, picked * scale
 
 
@@ -732,6 +846,7 @@ class RoutedExperts(Layer):
     shared_hidden: int = 0            # the shared expert's; 0 = none
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    norm_eps: float = 1e-20           # added to the chosen scores' sum
     init_std: float = 0.02
 
     # state key -> registry counter (Trainer._fold_step_counters)
@@ -770,7 +885,7 @@ class RoutedExperts(Layer):
             chosen, gates = route(
                 logits, jax.lax.stop_gradient(state["bias"]),
                 top_k=self.top_k, scale=self.routed_scaling_factor,
-                normalize=self.norm_topk_prob)
+                normalize=self.norm_topk_prob, eps=self.norm_eps)
         with jax.named_scope("moe.experts"):
             y, sizes = dropless_experts(
                 flat, chosen, gates, params["W_gate"], params["W_up"],
@@ -793,7 +908,7 @@ class RoutedExperts(Layer):
 @register_layer("causal_lm_output")
 @dataclasses.dataclass
 class CausalLMOutput(Layer):
-    """Next-token loss over an untied head that is owned once and read by
+    """Next-token loss over a head that is owned once and read by
     ``n_streams`` streams stacked along the batch axis (a ``StackVertex``
     in front): stream 0 is the model's own, predicting ``t_{i+1}`` at
     position ``i``; stream ``j`` is the ``j``-th multi-token-prediction
@@ -803,7 +918,12 @@ class CausalLMOutput(Layer):
     have no target and are left out of its mean.  Each stream's logits
     are rematerialised in the backward pass, so two ``[B*S, V]`` float32
     sets and their gradients are never live together.  ``apply`` gives
-    stream 0's logits."""
+    stream 0's logits.
+
+    The head is untied (``W`` ``[d, n_out]``, this layer's own), or with
+    ``tie_to`` the ``W`` ``[n_out, d]`` of that vertex, an embedding:
+    ``logits = x E^T``.  A tied head owns no parameter; the graph hands it
+    the one leaf, whose gradient is then the sum of both uses."""
 
     INPUT_KIND = "rnn"
 
@@ -811,16 +931,22 @@ class CausalLMOutput(Layer):
     n_streams: int = 1
     mtp_weight: float = 0.0
     init_std: float = 0.02
+    tie_to: str = ""                  # ComputationGraph's parameter sharing
 
     def get_output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
 
     def init_params(self, key, input_type):
+        if self.tie_to:
+            return {}
         return {"W": _normal(key, (input_type.size, self.n_out),
                              self.init_std, self._param_dtype())}
 
     def _logits(self, w, x):
         cd = dtype_policy().compute_dtype
+        if self.tie_to:
+            return jnp.einsum("...d,vd->...v", x.astype(cd), w.astype(cd),
+                              preferred_element_type=_wide(cd))
         return jnp.dot(x.astype(cd), w.astype(cd),
                        preferred_element_type=_wide(cd))
 

@@ -74,11 +74,16 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          causal: bool = False,
                          use_flash: Optional[bool] = None,
                          flash_block: int = 0, dropout_rate: float = 0.0,
-                         dropout_rng: Optional[jax.Array] = None
+                         dropout_rng: Optional[jax.Array] = None,
+                         n_kv_heads: Optional[int] = None
                          ) -> jnp.ndarray:
     """Multi-head attention on pre-projected q/k/v of shape [B,T,H*Dh].
     ``v`` may have a head size of its own ([B,T,H*Dv], latent attention's
     192-wide keys beside 128-wide values): the output is then [B,T,H*Dv].
+    ``n_kv_heads`` (default ``n_heads``, a divisor of it) is grouped-query
+    attention: ``k`` [B,T,Hkv*Dh] and ``v`` [B,T,Hkv*Dv], and query head
+    ``h`` reads K/V head ``h // (n_heads / n_kv_heads)`` on both routes,
+    with no K/V head repeated in memory.
 
     ``mask``: [B,T] padding mask applied to keys (and zeroing masked query
     outputs, matching DL4J's masked-attention semantics); ``kv_mask`` masks
@@ -102,6 +107,9 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     change.
     """
     b, tq, d = q.shape
+    if n_kv_heads is not None and (n_kv_heads < 1 or n_heads % n_kv_heads):
+        raise ValueError(f"{n_heads} query heads do not divide into groups "
+                         f"over {n_kv_heads} K/V heads")
     drop = dropout_rate > 0.0 and dropout_rng is not None
     if drop and use_flash:
         raise ValueError(
@@ -115,7 +123,8 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # flash_block=0: tuned defaults (1024×1024 — the optimum measured
         # on a v5e before PR 1 at both narrow and BERT-base widths; the
         # earlier 512×1024 default was 1.35-1.5× slower)
-        out = flash_attention(q, k, v, n_heads=n_heads, causal=causal,
+        out = flash_attention(q, k, v, n_heads=n_heads,
+                              n_kv_heads=n_kv_heads, causal=causal,
                               key_mask=key_mask,
                               block_q=flash_block or 1024,
                               block_k=flash_block or 1024)
@@ -123,12 +132,19 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             out = out * mask[:, :, None].astype(out.dtype)
         return out
     tk = k.shape[1]
+    n_kv = n_kv_heads or n_heads
+    group = n_heads // n_kv
     dh = d // n_heads
     qh = q.reshape(b, tq, n_heads, dh).transpose(0, 2, 1, 3)  # [B,H,Tq,Dh]
-    kh = k.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
-    dv = v.shape[-1] // n_heads
-    vh = v.reshape(b, tk, n_heads, dv).transpose(0, 2, 1, 3)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
+    kh = k.reshape(b, tk, n_kv, dh).transpose(0, 2, 1, 3)
+    dv = v.shape[-1] // n_kv
+    vh = v.reshape(b, tk, n_kv, dv).transpose(0, 2, 1, 3)
+    if group == 1:
+        scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
+    else:                   # a group's query heads against their K/V head
+        scores = jnp.einsum("bhgqd,bhkd->bhgqk",
+                            qh.reshape(b, n_kv, group, tq, dh), kh
+                            ).reshape(b, n_heads, tq, tk) / math.sqrt(dh)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :] > 0, scores, NEG_INF)
     if causal:
@@ -138,7 +154,12 @@ def multi_head_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if drop:
         weights = dropout(weights, 1.0 - dropout_rate, dropout_rng,
                           mask_layout=GENERATOR_LAYOUT)
-    out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh)
+    if group == 1:
+        out = jnp.einsum("bhqk,bhkd->bhqd", weights, vh)
+    else:
+        out = jnp.einsum("bhgqk,bhkd->bhgqd",
+                         weights.reshape(b, n_kv, group, tq, tk), vh
+                         ).reshape(b, n_heads, tq, dv)
     out = out.transpose(0, 2, 1, 3).reshape(b, tq, n_heads * dv)
     if mask is not None and tq == tk:
         out = out * mask[:, :, None]

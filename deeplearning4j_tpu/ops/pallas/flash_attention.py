@@ -144,6 +144,15 @@ def _wide_heads(d: int, d_v: int):
     return pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
 
 
+def _group(h: int, h_kv: int) -> int:
+    """Query heads a K/V head serves (grouped-query attention; 1 for
+    multi-head attention)."""
+    if h_kv < 1 or h % h_kv:
+        raise ValueError(f"{h} query heads do not divide into groups over "
+                         f"{h_kv} K/V heads")
+    return h // h_kv
+
+
 def _pad_to(x, axis, multiple):
     n = x.shape[axis]
     pad = (-n) % multiple
@@ -193,29 +202,36 @@ def flash_attention_block(q, k, v, *, scale: float, causal: bool = False,
                           interpret: bool | None = None):
     """One (q-block, kv-block) flash pass.
 
-    q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv] → (o [B,H,Tq,Dv]
+    q [B,H,Tq,D], k [B,Hkv,Tk,D], v [B,Hkv,Tk,Dv] → (o [B,H,Tq,Dv]
     unnormalized, m [B,H,Tq] row max, l [B,H,Tq] row sum-exp) — drop-in
     for the jnp ``_block_attention`` oracle.  ``Dv`` may differ from
-    ``D`` (latent attention: 192-wide keys, 128-wide values).  ``q_offset``/``k_offset``: global
+    ``D`` (latent attention: 192-wide keys, 128-wide values).  ``Hkv``
+    may be a divisor of ``H`` (grouped-query attention): query head ``h``
+    reads K/V head ``h // (H / Hkv)`` through the K/V BlockSpecs' index
+    map, and no K/V head is ever copied.  ``q_offset``/``k_offset``: global
     positions of row/col 0 (ints or traced scalars).  ``key_mask``:
     optional [B, Tk] padding mask (1 = attend), broadcast over heads.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    h_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = _group(h, h_kv)
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k, q.dtype,
                                     interpret)
 
     qf = _pad_to(q.reshape(b * h, tq, d), 1, block_q)
-    kf = _pad_to(k.reshape(b * h, tk, d), 1, block_k)
-    vf = _pad_to(v.reshape(b * h, tk, dv), 1, block_k)
+    kf = _pad_to(k.reshape(b * h_kv, tk, d), 1, block_k)
+    vf = _pad_to(v.reshape(b * h_kv, tk, dv), 1, block_k)
     tq_p, tk_p = qf.shape[1], kf.shape[1]
     n_q, n_k = tq_p // block_q, tk_p // block_k
     has_mask = key_mask is not None
     kmaskf = _key_mask_array(key_mask, b, h, tk, tk_p, block_k)
     km_map = (lambda bh, qi, ki: (bh, 0, ki)) if has_mask \
         else (lambda bh, qi, ki: (0, 0, 0))
+    # the [B*Hkv, ...] K/V row a [B*H, ...] query row reads: itself for
+    # multi-head attention, whose index maps stay what they were
+    kv_row = (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
 
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
@@ -232,8 +248,10 @@ def flash_attention_block(q, k, v, *, scale: float, causal: bool = False,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, qi, ki: (kv_row(bh), ki, 0)),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda bh, qi, ki: (kv_row(bh), ki, 0)),
             pl.BlockSpec((1, 8, block_k), km_map),
         ],
         out_specs=[
@@ -403,17 +421,32 @@ def _bwd_merged_kernel(qoff_ref, koff_ref, klen_ref, q_ref, k_ref, v_ref,
                        kmask_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr,
                        *, scale: float, causal: bool, has_mask: bool,
-                       block_q: int, block_k: int, n_q: int):
+                       block_q: int, block_k: int, n_q: int, group: int = 1):
     """Merged backward (round 5): ONE pass computes dK, dV and a per-
     k-block PARTIAL dQ — the per-tile score/dP recompute happens once
     instead of once per kernel (5 matmuls/tile, not 7), and Q/K/V/dO
     stream from HBM once.  dQ = Σ over k-blocks of the partials (a cheap
     jnp reduction outside); each (k-block, q-block) grid step writes a
-    DISTINCT dq-partial block, so no cross-step output revisiting."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    DISTINCT dq-partial block, so no cross-step output revisiting.
 
-    @pl.when(qi == 0)
+    Grouped-query attention (``group`` query heads a K/V head): the grid
+    is (K/V row, k-block, query head of the group, q-block), the last two
+    sequential, so that dK and dV sum over the group's heads in VMEM and
+    leave once a K/V head."""
+    ki = pl.program_id(1)
+    qi = pl.program_id(2 if group == 1 else 3)
+
+    def first_of_row():
+        if group == 1:
+            return qi == 0
+        return (qi == 0) & (pl.program_id(2) == 0)
+
+    def last_of_row():
+        if group == 1:
+            return qi == n_q - 1
+        return (qi == n_q - 1) & (pl.program_id(2) == group - 1)
+
+    @pl.when(first_of_row())
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -467,7 +500,7 @@ def _bwd_merged_kernel(qoff_ref, koff_ref, klen_ref, q_ref, k_ref, v_ref,
     else:
         _compute()
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(last_of_row())
     def _flush():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -494,22 +527,26 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                               merged: bool = True):
     """Backward of normalized blockwise attention.
 
-    q [B,H,Tq,D], k [B,H,Tk,D], v [B,H,Tk,Dv], out/dout [B,H,Tq,Dv]
+    q [B,H,Tq,D], k [B,Hkv,Tk,D], v [B,Hkv,Tk,Dv], out/dout [B,H,Tq,Dv]
     (normalized output and its cotangent), lse [B,H,Tq] = m + log(l) from
     the forward pass.  Returns (dq, dk, dv) in f32, heads layout, each in
-    its operand's shape.  ``q_offset``/``k_offset``
+    its operand's shape: with ``Hkv`` a divisor of ``H`` (grouped-query
+    attention) dK and dV are summed over each K/V head's query heads
+    inside the kernel.  ``q_offset``/``k_offset``
     give global positions for causal masking inside a sharded ring.
 
     ``merged=True`` (default, round 5): one kernel pass produces dK, dV
     and per-k-block dQ partials (summed outside) — 5 matmuls per tile
     and one HBM stream of the operands, vs 7 matmuls over two kernels
     (measured −22% bwd wall time at seq 4096 on v5e).  ``merged=False``
-    keeps the two-kernel form (the r3 oracle), for ``Dv == D`` only.
+    keeps the two-kernel form (the r3 oracle), for ``Dv == D`` and
+    ``Hkv == H`` only.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, tq, d = q.shape
-    tk, d_v = k.shape[2], v.shape[3]
+    h_kv, tk, d_v = k.shape[1], k.shape[2], v.shape[3]
+    group = _group(h, h_kv)
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k, q.dtype,
                                     interpret)
 
@@ -519,8 +556,8 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
                     axis=-1)                                     # [B,H,Tq]
 
     qf = _pad_to(q.reshape(b * h, tq, d), 1, block_q)
-    kf = _pad_to(k.reshape(b * h, tk, d), 1, block_k)
-    vf = _pad_to(v.reshape(b * h, tk, d_v), 1, block_k)
+    kf = _pad_to(k.reshape(b * h_kv, tk, d), 1, block_k)
+    vf = _pad_to(v.reshape(b * h_kv, tk, d_v), 1, block_k)
     dof = _pad_to(dout.reshape(b * h, tq, d_v), 1, block_q)
     # padded q rows carry lse = -inf → p = 0 in both kernels (no NaNs,
     # no contribution to dk/dv); padded k cols are masked via klen
@@ -540,19 +577,25 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
     klen = jnp.asarray(tk, jnp.int32).reshape(1)
 
     if merged:
-        q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
+        # grid (K/V row r, k-block j, [query head g of the group,] q-block
+        # i): the query row a step reads is r itself, or r * group + g
+        def q_at(r, j, *gi):
+            return (r, gi[-1]) if group == 1 else (r * group + gi[0], gi[-1])
+
+        q_spec2 = pl.BlockSpec((1, block_q, d), lambda *ids: (*q_at(*ids), 0))
         do_spec2 = pl.BlockSpec((1, block_q, d_v),
-                                lambda bh, j, i: (bh, i, 0))
+                                lambda *ids: (*q_at(*ids), 0))
         stat_spec2 = pl.BlockSpec((1, block_q, 128),
-                                  lambda bh, j, i: (bh, i, 0))
-        k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0))
+                                  lambda *ids: (*q_at(*ids), 0))
+        k_spec2 = pl.BlockSpec((1, block_k, d), lambda r, j, *_: (r, j, 0))
         v_spec2 = pl.BlockSpec((1, block_k, d_v),
-                               lambda bh, j, i: (bh, j, 0))
+                               lambda r, j, *_: (r, j, 0))
         km_spec2 = pl.BlockSpec((1, 8, block_k),
-                                (lambda bh, j, i: (bh, 0, j)) if has_mask
-                                else (lambda bh, j, i: (0, 0, 0)))
+                                (lambda *ids: (q_at(*ids)[0], 0, ids[1]))
+                                if has_mask
+                                else (lambda *ids: (0, 0, 0)))
         dqp_spec = pl.BlockSpec((1, 1, block_q, d),
-                                lambda bh, j, i: (j, bh, i, 0))
+                                lambda *ids: (ids[1], *q_at(*ids), 0))
         # partials in the input dtype: callers cast dq to q.dtype anyway
         # (custom_vjp), so bf16 partials only halve the HBM round-trip;
         # the f32 path keeps f32 partials for oracle parity
@@ -560,14 +603,16 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
         dk, dv, dqp = pl.pallas_call(
             functools.partial(_bwd_merged_kernel, scale=float(scale),
                               causal=causal, has_mask=has_mask,
-                              block_q=block_q, block_k=block_k, n_q=n_q),
-            grid=(b * h, n_k, n_q),
+                              block_q=block_q, block_k=block_k, n_q=n_q,
+                              group=group),
+            grid=((b * h, n_k, n_q) if group == 1
+                  else (b * h_kv, n_k, group, n_q)),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
             + [q_spec2, k_spec2, v_spec2, km_spec2, do_spec2,
                stat_spec2, stat_spec2],
             out_specs=[k_spec2, v_spec2, dqp_spec],
-            out_shape=[_sds(qf, kf, (b * h, tk_p, d)),
-                       _sds(qf, kf, (b * h, tk_p, d_v)),
+            out_shape=[_sds(qf, kf, (b * h_kv, tk_p, d)),
+                       _sds(qf, kf, (b * h_kv, tk_p, d_v)),
                        _sds(qf, kf, (n_k, b * h, tq_p, d), dqp_dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d_v), jnp.float32)],
@@ -577,15 +622,16 @@ def flash_attention_block_bwd(q, k, v, out, lse, dout, *, scale: float,
         )(qoff, koff, klen, qf, kf, vf, kmaskf, dof, lsef, deltaf)
         dq = jnp.sum(dqp.astype(jnp.float32), axis=0)
         dq = dq[:, :tq].reshape(b, h, tq, d)
-        dk = dk[:, :tk].reshape(b, h, tk, d)
-        dv = dv[:, :tk].reshape(b, h, tk, d_v)
+        dk = dk[:, :tk].reshape(b, h_kv, tk, d)
+        dv = dv[:, :tk].reshape(b, h_kv, tk, d_v)
         return dq, dk, dv
 
-    if d_v != d:
+    if d_v != d or group != 1:
         raise ValueError(
             "flash_attention_block_bwd(merged=False) takes q, k and v of one "
-            f"head size, got {d} and {d_v}: only the merged kernel takes a "
-            "value head size of its own")
+            f"head size and as many heads, got {d} and {d_v}, {h} and "
+            f"{h_kv}: only the merged kernel takes a value head size of its "
+            "own and grouped K/V heads")
     smem = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
     stat_spec = pl.BlockSpec((1, block_q, 128), lambda bh, i, j: (bh, i, 0))
@@ -679,11 +725,14 @@ def _mha_bwd(scale, causal, block_q, block_k, interpret, res, dout):
 _mha_core.defvjp(_mha_fwd, _mha_bwd)
 
 
-def flash_attention(q, k, v, *, n_heads: int, causal: bool = False,
-                    key_mask=None, block_q: int = 1024, block_k: int = 1024,
-                    interpret: bool | None = None):
+def flash_attention(q, k, v, *, n_heads: int, n_kv_heads: int | None = None,
+                    causal: bool = False, key_mask=None, block_q: int = 1024,
+                    block_k: int = 1024, interpret: bool | None = None):
     """Full single-device flash attention: [B, T, H*D] → [B, T, H*D]
     (``v`` [B, T, H*Dv] with a head size of its own gives [B, T, H*Dv]).
+    ``n_kv_heads`` (default ``n_heads``) K/V heads, ``k`` [B, T, Hkv*D]
+    and ``v`` [B, T, Hkv*Dv], is grouped-query attention: query head ``h``
+    reads K/V head ``h // (n_heads / n_kv_heads)``, in both kernels.
     Normalized output (softmax(QKᵀ/√d)·V) with no [T,T] materialization —
     the libnd4j ``multi_head_dot_product_attention`` replacement for long
     sequences on one chip.  Differentiable: ``jax.grad`` routes through
@@ -697,11 +746,12 @@ def flash_attention(q, k, v, *, n_heads: int, causal: bool = False,
     attention (Tk != Tq) is supported."""
     b, t, dm = q.shape
     tk = k.shape[1]
+    n_kv = n_kv_heads or n_heads
     dh = dm // n_heads
-    dv = v.shape[2] // n_heads
+    dv = v.shape[2] // n_kv
     qh = q.reshape(b, t, n_heads, dh).transpose(0, 2, 1, 3)
-    kh = k.reshape(b, tk, n_heads, dh).transpose(0, 2, 1, 3)
-    vh = v.reshape(b, tk, n_heads, dv).transpose(0, 2, 1, 3)
+    kh = k.reshape(b, tk, n_kv, dh).transpose(0, 2, 1, 3)
+    vh = v.reshape(b, tk, n_kv, dv).transpose(0, 2, 1, 3)
     if key_mask is not None:
         key_mask = jnp.asarray(key_mask, jnp.float32)
     out = _mha_core(qh, kh, vh, key_mask, 1.0 / (dh ** 0.5), causal,

@@ -59,11 +59,15 @@ def net_signature(net) -> Optional[tuple]:
         conf_sha = hashlib.sha1(to_json().encode()).hexdigest()
     except Exception:
         return None
+    import jax.numpy as jnp
     from deeplearning4j_tpu.config import dtype_policy
     pol = dtype_policy()
+    # dtype names: a policy restored from a checkpoint holds dtype objects
+    # where the default holds scalar types, and both must give one key
+    # (or a fresh process never finds the artifacts a resumed one baked)
     return (type(net).__name__, conf_sha,
-            str(pol.param_dtype), str(pol.compute_dtype),
-            str(pol.output_dtype))
+            *(jnp.dtype(d).name for d in (pol.param_dtype, pol.compute_dtype,
+                                          pol.output_dtype)))
 
 
 def updater_signature(conf) -> Optional[str]:

@@ -89,6 +89,107 @@ def test_flash_attention_latent_heads_seq8192(one_chip, grad):
     assert _has_kernel(compiled)
 
 
+def test_flash_attention_grouped_query_heads_seq8192(one_chip):
+    """Grouped-query attention's shapes in ``lfm2_8b_a1b.clm_s8192_b2``: 2
+    rows of 8,192 tokens, 32 query heads and 8 K/V heads of 64, causal,
+    forward and merged backward in one program: the K/V BlockSpecs'
+    index maps read a group's head, the backward's grid runs a group's
+    query heads inside each K/V block and sums dK and dV in VMEM, and
+    both fit the compiler's default VMEM scope (heads of 64 take no
+    ``_wide_heads`` parameters)."""
+    attend = functools.partial(flash_attention, n_heads=32, n_kv_heads=8,
+                               causal=True, interpret=False)
+    fn = jax.value_and_grad(lambda q, k, v: jnp.sum(
+        attend(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+    compiled = _compile(fn, one_chip, ((2, 8192, 32 * 64), jnp.bfloat16),
+                        ((2, 8192, 8 * 64), jnp.bfloat16),
+                        ((2, 8192, 8 * 64), jnp.bfloat16))
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line]
+    for kernel in ("tpudl_flash_fwd", "tpudl_flash_bwd_merged"):
+        assert any(re.search(kernel + r"\b", line) for line in calls), kernel
+    # dK and dV leave the kernel once a K/V head: [2 * 8, 8192, 64] float32
+    assert any("f32[16,8192,64]" in line for line in calls
+               if "tpudl_flash_bwd_merged" in line)
+
+
+def test_lfm2_train_step_lowers_one_flash_pair(one_chip):
+    """The program ``lfm2_8b_a1b.clm_s8192_b2`` runs, from the cell's own
+    configuration file (507.8 M parameters, 2 x 8,192 tokens, bf16 policy,
+    Adam), lowered from ``make_train_step`` and not compiled (the whole
+    step compiles in about 30 s, more than this file should spend): its
+    one attention block calls the flash forward kernel once and the
+    merged backward once a step, the forward's output and row statistics
+    kept through the block's rematerialised run."""
+    import json
+    import os
+
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    from deeplearning4j_tpu.models import lfm2_moe
+    from deeplearning4j_tpu.train import Adam
+    from deeplearning4j_tpu.train.trainer import Trainer, make_train_step
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2_8b_a1b.json")) as f:
+        config = json.load(f)
+    batch, seq = 2, 8192
+    was = dtype_policy()
+    set_dtype_policy(DTypePolicy.bf16())
+    try:
+        net = lfm2_moe(config, seq, updater=Adam(1e-4))
+
+        def shapes():                      # net.init traced, never run
+            net.init()
+            return net.params_, net.state_
+        params, state = jax.eval_shape(shapes)
+        net.params_ = params
+        tx = Trainer(net).tx
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                                  sharding=one_chip), tree)
+
+        ids = on_chip(jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+        args = (on_chip(params), on_chip(state),
+                on_chip(jax.eval_shape(tx.init, params)), ids, ids, None,
+                on_chip(jax.ShapeDtypeStruct((batch,), jnp.float32)),
+                on_chip(jax.eval_shape(lambda: jax.random.key(0))))
+        real_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+        try:
+            text = make_train_step(net, tx).lower(*args).as_text()
+        finally:
+            jax.default_backend = real_backend
+    finally:
+        set_dtype_policy(was)
+    n_params = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
+    assert n_params == 507_820_160
+    assert net.trace_attrs()["attention_kinds"] == ["conv", "gqa", "conv",
+                                                    "conv", "conv"]
+    assert net.trace_attrs()["kv_groups"] == 4
+    _, *funcs = re.split(r"\n  func\.func ", text)
+    names = [re.match(r"(?:public |private )?@(\w+)", f).group(1)
+             for f in funcs]
+
+    @functools.lru_cache(maxsize=None)
+    def runs(name):
+        """How often a step calls the function ``name``, through every
+        chain of calls from ``main``."""
+        if name == "main":
+            return 1
+        calls = [(len(re.findall(rf"call @{name}\(", body)), caller)
+                 for caller, body in zip(names, funcs)]
+        return sum(n * runs(caller) for n, caller in calls if n)
+
+    for kernel in ("tpudl_flash_fwd", "tpudl_flash_bwd_merged"):
+        pattern = r"tpu_custom_call.*" + kernel + r"\b"
+        assert len(re.findall(pattern, text)) == 1, kernel
+        body = [name for name, f in zip(names, funcs)
+                if re.search(pattern, f)]
+        assert len(body) == 1 and runs(body[0]) == 1, kernel
+
+
 def test_joyai_train_step_whole_program(one_chip):
     """The program ``joyai_llm_flash.clm_s8192_b1`` runs, from the cell's
     own configuration file: 680.4 M parameters at the published widths,

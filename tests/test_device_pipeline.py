@@ -390,6 +390,27 @@ def test_step_cache_shared_across_trainers(registry):
     assert t1._step._cache_size() == 1
 
 
+def test_step_cache_key_names_the_policy_dtypes():
+    """A policy of dtype objects (what a checkpoint's restore sets) keys
+    the step as the default policy of scalar types does."""
+    from deeplearning4j_tpu.config import (DTypePolicy, dtype_policy,
+                                           set_dtype_policy)
+    net = MultiLayerNetwork(_mlp_conf(seed=19)).init()
+    was = dtype_policy()
+    try:
+        set_dtype_policy(DTypePolicy.f32())
+        default = step_cache.net_signature(net)
+        set_dtype_policy(DTypePolicy(*(np.dtype("float32"),) * 3))
+        restored = step_cache.net_signature(net)
+        set_dtype_policy(DTypePolicy.bf16())
+        mixed = step_cache.net_signature(net)
+    finally:
+        set_dtype_policy(was)
+    assert default == restored
+    assert default[2:] == ("float32",) * 3
+    assert mixed != default
+
+
 def test_step_cache_distinct_configs_do_not_collide():
     t1 = Trainer(MultiLayerNetwork(_mlp_conf(seed=19)).init())
     t2 = Trainer(MultiLayerNetwork(_mlp_conf(seed=19, n_hidden=32)).init())

@@ -369,6 +369,14 @@ LAYER_CASES = {
     "delta_attention": ([DeltaAttention(n_heads=2, head_dim=3, chunk=4,
                                         init_std=0.5), RNN_OUT()],
                         InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
+    # LFM2's token mixers: 5 tokens through 3 taps; 2 query heads over one
+    # K/V head (the einsum chain at this length)
+    "gated_short_conv": ([GatedShortConv(taps=3, init_std=0.5), RNN_OUT()],
+                         InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
+    "grouped_query_attention": ([GroupedQueryAttention(
+        n_heads=2, n_kv_heads=1, head_dim=2, rope_theta=100.0,
+        init_std=0.5), RNN_OUT()],
+        InputType.recurrent(4, 5), lambda: _rnn_batch(4, 3)),
     "routed_experts": ([RoutedExperts(
         n_routed_experts=4, experts_held=2, first_expert=1, top_k=2, hidden=6,
         shared_hidden=6, routed_scaling_factor=2.5, init_std=0.5), FF_OUT()],
